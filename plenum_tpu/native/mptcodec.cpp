@@ -30,7 +30,7 @@ namespace {
 
 // ---------------------------------------------------------------- keccak
 const uint64_t RC[24] = {
-    0x0000000000000001ULL, 0x0000000000008082ULL, 0x800000000000808aULL,
+    0x0000000000000001ULL, (0x80ULL << 8 | 0x82ULL), 0x800000000000808aULL,
     0x8000000080008000ULL, 0x000000000000808bULL, 0x0000000080000001ULL,
     0x8000000080008081ULL, 0x8000000000008009ULL, 0x000000000000008aULL,
     0x0000000000000088ULL, 0x0000000080008009ULL, 0x000000008000000aULL,

@@ -29,27 +29,6 @@ from plenum_tpu.crypto.ed25519 import JaxEd25519Verifier
 from plenum_tpu.ops import ed25519 as ed_ops
 from plenum_tpu.ops import sha256 as sha_ops
 
-try:  # moved to jax.shard_map in newer releases
-    _shard_map_impl = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def _shard_map(*args, **kwargs):
-    """shard_map across jax versions: the replication checker's flag was
-    renamed check_rep -> check_vma; translate (then drop) rather than pin
-    jax."""
-    try:
-        return _shard_map_impl(*args, **kwargs)
-    except TypeError:
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-            try:
-                return _shard_map_impl(*args, **kwargs)
-            except TypeError:
-                kwargs.pop("check_rep", None)
-        return _shard_map_impl(*args, **kwargs)
-
 
 def _reduce_roots(roots: jax.Array) -> jax.Array:
     """Top of the Merkle tree over per-shard roots; pads a non-power-of-two
@@ -68,9 +47,8 @@ def _reduce_roots(roots: jax.Array) -> jax.Array:
 def _local_step_bytes(s_u8, h_u8, keys_u8, idx, r_u8, leaves):
     """Per-shard body of the COMPRESSED dispatch (the production path):
     raw byte payloads arrive sharded over the grid, the 32 B/key verkey
-    table is REPLICATED (it IS the deduped payload — on multi-host
-    tunneled hardware the link dominates dispatch cost, so the transfer
-    win must survive sharding), and each shard decompresses the keys it
+    table is REPLICATED (it IS the deduped payload — the transfer win
+    must survive sharding), and each shard decompresses the keys it
     needs on device. Key decompression is redundant across shards by
     design: ~0.5 signature-equivalents of compute per distinct key vs
     an all-to-all of 1280 B/key quarter-point rows."""
@@ -93,9 +71,8 @@ def _local_step(s_dig, h_dig, aq_unique, idx, ry, r_sign, leaves):
     """Per-shard body. Signature grid arrives as [I_loc, N_loc, ...]; the
     local grid flattens into one kernel batch. The verkey quarter-point
     table is REPLICATED (it is the deduped host->device payload — the
-    transfer win must survive sharding, since on tunneled multi-chip
-    hardware the link dominates dispatch cost) and gathered per shard by
-    the sharded idx. leaves: uint32[L_loc, 8]."""
+    transfer win must survive sharding) and gathered per shard by the
+    sharded idx. leaves: uint32[L_loc, 8]."""
     i_loc, n_loc = idx.shape[0], idx.shape[1]
     m = i_loc * n_loc
     aq = jnp.take(aq_unique, idx.reshape(m), axis=0)
@@ -135,14 +112,14 @@ class ShardedCryptoPlane:
         # device-invariant constants (the identity point), which the varying-
         # manual-axes checker flags even though the computation is replicated-
         # safe.
-        self._step = jax.jit(_shard_map(
+        self._step = jax.jit(jax.shard_map(
             _local_step, mesh=mesh,
             in_specs=(spec_s, spec_h, spec_aq, spec_idx, spec_ry,
                       spec_scalar, spec_leaf),
             out_specs=(P("inst", "sig"), P(), P()),
             check_vma=False))
         spec_bytes = P("inst", "sig", None)       # u8 payloads [I, N, 32]
-        self._step_bytes = jax.jit(_shard_map(
+        self._step_bytes = jax.jit(jax.shard_map(
             _local_step_bytes, mesh=mesh,
             in_specs=(spec_bytes, spec_bytes, P(None, None), spec_idx,
                       spec_bytes, spec_leaf),
@@ -195,7 +172,7 @@ class ShardedJaxEd25519Verifier(JaxEd25519Verifier):
     def rewarm(self) -> None:
         """Plane-supervisor re-warm hook: drop the staged quarter-point
         key rows so the next dispatch re-uploads the replicated verkey
-        table to every shard (after a mesh/relay restart the device-side
+        table to every shard (after a mesh/runtime restart the device-side
         copies are gone; the compiled SPMD program itself persists in the
         XLA cache, and the supervisor's probe batch re-validates it at a
         compiled shape before traffic is re-admitted)."""

@@ -8,10 +8,11 @@ tests/test_sim_fuzz.py), and against a live CryptoPlaneServer (wrap the
 server's inner verifier).
 
 `FaultyVerifier` wraps any Ed25519Verifier with the failure modes a real
-relay/tunnel exhibits:
+device plane (a local runtime, or a crypto service across a socket)
+exhibits:
 
   wedge    requests are accepted but replies never come (the round-5
-           failure: the relay process alive, the device gone) — in-flight
+           failure: the front process alive, the device gone) — in-flight
            AND subsequent tokens are lost until heal()
   drop     connection refused: submit_batch raises ConnectionError
   corrupt  the connection dies mid-stream: collect_batch raises
@@ -94,9 +95,9 @@ class FaultyVerifier(Ed25519Verifier):
     """Fault-injecting wrapper with the same submit/collect protocol.
 
     Token semantics under each mode (matching how the real service
-    client experiences the relay):
+    client experiences its plane):
       - tokens submitted while wedged are LOST: collect never resolves
-        (a wedged relay restarting does not answer old requests)
+        (a wedged plane restarting does not answer old requests)
       - tokens in flight when the wedge starts are lost too
       - drop refuses at submit; corrupt raises at collect
       - delay withholds the (honest) verdict until ready_at
@@ -163,7 +164,7 @@ class FaultyVerifier(Ed25519Verifier):
         self.rewarms += 1
         if self.mode() == "drop":
             self.faults_served += 1
-            raise ConnectionError("fault: relay refused (drop mode)")
+            raise ConnectionError("fault: plane refused (drop mode)")
         inner_rewarm = getattr(self._inner, "rewarm", None)
         if callable(inner_rewarm):
             inner_rewarm()
@@ -175,7 +176,7 @@ class FaultyVerifier(Ed25519Verifier):
         mode = self.mode()
         if mode == "drop":
             self.faults_served += 1
-            raise ConnectionError("fault: relay refused (drop mode)")
+            raise ConnectionError("fault: plane refused (drop mode)")
         token = {
             "inner": self._inner.submit_batch(items),
             "epoch": self._wedge_epoch,
@@ -199,7 +200,7 @@ class FaultyVerifier(Ed25519Verifier):
             if wait:
                 # what the real client sees: its bounded socket deadline
                 # fires and the connection is torn down
-                raise ConnectionError("fault: relay wedged (reply lost)")
+                raise ConnectionError("fault: plane wedged (reply lost)")
             return None
         if token["ready_at"] is not None and self._now() < token["ready_at"]:
             if wait:

@@ -39,27 +39,18 @@ _initialized = False
 
 def init_multihost(coordinator: Optional[str] = None,
                    num_processes: Optional[int] = None,
-                   process_id: Optional[int] = None) -> dict:
+                   process_id: Optional[int] = None) -> None:
     """Join (or bootstrap) the distributed runtime. Idempotent. With no
     arguments on a single host this is a no-op that marks the process
-    initialized (jax.distributed requires no setup for one process).
-
-    Returns this host's AOT-cache preflight (plenum_tpu.ops): in a
-    heterogeneous multi-host job the persistent compile cache is the
-    classic way to ship another machine's AOT code onto this one (the
-    MULTICHIP r02-r05 `cpu_aot_loader` mismatch); the cache path is
-    host-fingerprint-scoped so that can't happen, and the returned dict
-    says whether THIS host starts warm or pays fresh JIT compiles."""
+    initialized (jax.distributed requires no setup for one process)."""
     global _initialized
-    from plenum_tpu.ops import aot_preflight
     if _initialized:
-        return aot_preflight()
+        return
     if coordinator is not None:
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_processes,
                                    process_id=process_id)
     _initialized = True
-    return aot_preflight()
 
 # NOTE on lanes vs the global mesh: the multi-device pipeline's lanes
 # are per-chip dispatch streams and must be able to device_put from

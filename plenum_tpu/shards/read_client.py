@@ -84,7 +84,7 @@ class ShardMapView:
 
 
 class CrossShardReadStats(ReadClientStats):
-    """Flat read stats + the mapping-proof failure taxonomy."""
+    """Flat read stats + the mapping-proof failure classification."""
 
     def __init__(self):
         super().__init__()

@@ -142,7 +142,7 @@ def test_cache_poisoning_by_field_shift_rejected(service):
 
 
 def test_backend_failure_is_loud_and_worker_survives(tmp_path):
-    """An inner-verifier exception (device tunnel dropping) must surface
+    """An inner-verifier exception (the device dying) must surface
     as an error to waiting clients — never a silent all-False verdict or
     a dead worker thread that wedges every node."""
     from plenum_tpu.crypto.ed25519 import CpuEd25519Verifier
@@ -157,7 +157,7 @@ def test_backend_failure_is_loud_and_worker_survives(tmp_path):
         def verify_batch(self, items):
             if self.fail_next:
                 self.fail_next = False
-                raise RuntimeError("device tunnel dropped")
+                raise RuntimeError("device dropped")
             return super().verify_batch(items)
 
     sock = str(tmp_path / "crypto.sock")
@@ -177,7 +177,7 @@ def test_backend_failure_is_loud_and_worker_survives(tmp_path):
     assert loop_ready.wait(5.0)
     ver = ServiceEd25519Verifier(socket_path=sock)
     items = _make_items(3, tag=b"flaky")
-    with pytest.raises(RuntimeError, match="device tunnel dropped"):
+    with pytest.raises(RuntimeError, match="device dropped"):
         ver.verify_batch(items)
     # the worker survived: the next dispatch succeeds
     assert ver.verify_batch(items).all()
@@ -425,7 +425,7 @@ def test_submit_failure_with_cross_wave_dependency_is_loud(tmp_path):
         def submit_batch(self, items):
             if self.fail_next:
                 self.fail_next = False
-                raise RuntimeError("tunnel dropped")
+                raise RuntimeError("device dropped")
             return super().submit_batch(items)
 
     sock = str(tmp_path / "crypto.sock")
@@ -505,6 +505,33 @@ def test_federated_prewarm_pin_negotiation(service):
     assert server.stats.get("prewarms") == 1
     assert fed.pin()["pinned"] is True
     assert server.stats.get("pinned") == 1
+    fed.close()
+
+
+def test_prewarm_full_key_tables_and_loud_failure(service):
+    """full_keys warms a second, all-distinct-verkey wave per bucket (the
+    full key-table shape a plain client's coalesced waves dispatch); a
+    warm wave the supervised inner answered from the CPU is an ERROR
+    reply, never a bucket reported compiled; stats() names the owner's
+    device (None for a host inner) and its compile counters."""
+    from plenum_tpu.crypto.ed25519 import CpuEd25519Verifier
+    from plenum_tpu.parallel.crypto_service import FederatedEd25519Client
+    from plenum_tpu.parallel.faults import FaultyVerifier
+    from plenum_tpu.parallel.supervisor import supervise
+    server, connect = service
+    faulty = FaultyVerifier(CpuEd25519Verifier())
+    server._inner = supervise(faulty)
+    fed = FederatedEd25519Client(socket_path=connect().socket_path)
+    frames = server.stats.get("wave_frames", 0)
+    assert fed.prewarm([8, 16], full_keys=True)["warmed"] == [8, 16]
+    assert server.stats["wave_frames"] - frames == 4
+    st = fed.stats()
+    assert st["device"] is None and st["compile"]["executables"] >= 0
+    assert st["plane"]["device_batches"] == 4
+
+    faulty.drop()                       # the device now refuses dispatches
+    with pytest.raises(RuntimeError, match="not answered by the device"):
+        fed.prewarm([8])
     fed.close()
 
 

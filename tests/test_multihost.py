@@ -39,7 +39,7 @@ assert mesh.devices.size == 8, mesh.devices.shape
 local = np.arange(4 * 16, dtype=np.float32).reshape(4, 16) + rank * 64
 garr = shard_host_batch(mesh, local, P(("inst", "sig"), None))
 
-from plenum_tpu.parallel.crypto_plane import _shard_map as shard_map
+shard_map = jax.shard_map
 
 def step(x):
     # the plane's collective pattern: per-shard reduction, all_gather of

@@ -298,7 +298,7 @@ def run_scenario(seed: int) -> None:
 
 
 # --- scenario kind `device_flap`: the crypto plane is the fault -------------
-# A seed-driven relay wedge/drop/corrupt hits the pool's SHARED device
+# A seed-driven plane wedge/drop/corrupt hits the pool's SHARED device
 # verifier mid-consensus. The plane supervisor must degrade every node to
 # hedged CPU verdicts (no request stalls past its per-batch deadline
 # budget — measured from the supervisor's stall accounting, not asserted
@@ -353,7 +353,7 @@ def run_device_flap_scenario(seed: int) -> None:
     assert pre is not None, f"seed {seed}: healthy pool failed to order"
     assert sup.stats["device_batches"] >= 1, "traffic never hit the device"
 
-    # fault the plane MID-consensus: request in flight, then the relay
+    # fault the plane MID-consensus: request in flight, then the plane
     # wedges (replies lost) / drops (refuses) / corrupts (dies mid-read)
     kind = ("wedge", "drop", "corrupt")[rng.integer(0, 2)]
     pool.submit(reqs[1])

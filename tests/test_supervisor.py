@@ -157,7 +157,7 @@ def test_flap_hysteresis_doubles_cooldown_and_decays():
     flap_once()
     after_three = sup.breaker.cooldown
     # every RE-open (an open before the decay window passed) doubles the
-    # probe cooldown: a flapping relay faces exponentially rarer probes,
+    # probe cooldown: a flapping device faces exponentially rarer probes,
     # not a thrash loop
     assert after_one == base
     assert after_two == base * 2
@@ -374,7 +374,7 @@ def live_service(tmp_path):
 
 
 def test_service_client_wedge_costs_one_bounded_miss(live_service):
-    """The satellite fix for the flat request_timeout=300: a wedged relay
+    """The satellite fix for the flat request_timeout=300: a wedged service
     costs ONE per-request deadline budget (a few seconds warm), measured
     on the wall clock — not a 5-minute stall per batch."""
     from plenum_tpu.parallel.crypto_service import ServiceEd25519Verifier
