@@ -36,7 +36,9 @@ No fallback may hide the device: over each traffic window device batches
 must grow while every fallback / hedge / deadline / error / breaker /
 verdict-fork / unpinned-shape / commit-wave-host-fallback counter and the
 number of executables obtained stay flat. Set-up (compile + prewarm) is
-timed apart from the window.
+timed apart from the window, and each phase's set-up line carries
+ops.compile_stats(): on a machine whose executable store (ops/aot.py)
+already holds a verify shape, tracing that shape again fails the phase.
 
 Exit 0 and a last stdout line {"ok": true, "device": {...}} only when
 every phase passed on a TPU. No TPU -> non-zero before any work.
@@ -123,6 +125,39 @@ def window_failures(label: str, before: list, after: list,
     return problems
 
 
+def store_failures(label: str, compile_stats: dict, held_before: int,
+                   shapes: int) -> list[str]:
+    """The executable-store rule over one phase's set-up. `held_before`
+    of the phase's `shapes` verify programs were in the store when it
+    began; each of those must have been LOADED, so at most the others
+    may have been traced, and no entry may have been found damaged."""
+    problems = []
+    if compile_stats["aot_rejected"]:
+        problems.append(f"{label}: {compile_stats['aot_rejected']} store "
+                        f"entries rejected and compiled again")
+    if compile_stats["traces"] > shapes - held_before:
+        problems.append(
+            f"{label}: {compile_stats['traces']} verify traces with "
+            f"{held_before}/{shapes} shapes already in the store "
+            f"({compile_stats['aot_loads']} loaded)")
+    return problems
+
+
+def compile_since(before: dict) -> dict:
+    """ops.compile_stats() now, less an earlier snapshot."""
+    from plenum_tpu.ops import compile_stats
+    return {k: round(v - before[k], 3) for k, v in compile_stats().items()}
+
+
+def shapes_held(waves, devices=(None,)) -> tuple[int, int]:
+    """-> (how many of these verify programs the executable store holds
+    already, how many there are), over the given lane devices."""
+    from plenum_tpu.crypto.ed25519 import JaxEd25519Verifier
+    held = [h for d in devices
+            for h in JaxEd25519Verifier(device=d).in_store(waves).values()]
+    return sum(held), len(held)
+
+
 def require_device(rehearsal: bool, min_count: int = 1) -> dict:
     """Ask JAX what this process got. This initialises the backend: the
     caller is, from here on, the chip's one owner."""
@@ -160,14 +195,14 @@ def kernel_check(sizes: Sizes, seed: int) -> tuple[dict, list[str]]:
     will dispatch plus the ladder's largest; sha256_batch and merkle_wave
     against hashlib.
 
-    The shapes are checked CONCURRENTLY, one thread each. That is set-up
-    economy, not a feature: one cold verify program costs ~35 s of
-    tracing and ~130 s of XLA:TPU compilation, five in sequence overrun
-    the time limit, and XLA compiles with the GIL released. What a thread
-    compiles here the ring pool finds in this process's jit cache, and
-    the crypto service of the served phase finds in the persistent one."""
-    from concurrent.futures import ThreadPoolExecutor
-
+    The shapes are obtained in ONE preload() from this, the main thread:
+    what the executable store (ops/aot.py) holds is loaded in turn, the
+    rest compile at once, one thread each. That is set-up economy, not a
+    feature: one cold verify program costs ~35 s of tracing and ~130 s
+    of XLA:TPU compilation, five in sequence overrun the time limit, and
+    XLA compiles with the GIL released. What is obtained here the ring
+    pool's prewarm is handed again in this process, and the crypto
+    service of the served phase loads from the store without tracing."""
     from plenum_tpu.crypto.ed25519 import (CpuEd25519Verifier, Ed25519Signer,
                                            JaxEd25519Verifier)
     from plenum_tpu.ledger.tree_hasher import fused_wave_levels
@@ -196,7 +231,12 @@ def kernel_check(sizes: Sizes, seed: int) -> tuple[dict, list[str]]:
                 msg += b"!"
             items[j] = (msg, sig, vk)
         want = CpuEd25519Verifier().verify_batch(items)
-        got = JaxEd25519Verifier(min_batch=bucket).verify_batch(items)
+        # through preload(), as a prewarm obtains it: the executable
+        # checked here (obtained below, before the loop) is the one the
+        # pool's prewarm is handed again
+        device = JaxEd25519Verifier(min_batch=bucket)
+        device.preload([shape])
+        got = device.verify_batch(items)
         row = {"bucket": bucket, "keys": n_keys,
                "done_at_s": round(time.perf_counter() - t_start, 1),
                "rejected": int((~got).sum()), "corrupted": len(bad),
@@ -204,12 +244,25 @@ def kernel_check(sizes: Sizes, seed: int) -> tuple[dict, list[str]]:
         say("single", kernel=row)
         return row
 
-    with ThreadPoolExecutor(len(sizes.kernel_shapes)) as pool:
-        rows = list(pool.map(verify_shape, sizes.kernel_shapes))
-    out: dict = {"verify": rows}
+    from plenum_tpu.ops import aot, compile_stats
+    held, shapes = shapes_held(sizes.kernel_shapes)
+    c0 = compile_stats()
+    JaxEd25519Verifier().preload(sizes.kernel_shapes)
+    rows = [verify_shape(shape) for shape in sizes.kernel_shapes]
+    out: dict = {"verify": rows, "store_held": [held, shapes],
+                 "compile": compile_since(c0)}
+    # which obtains overlapped: seconds from the first one's start
+    spans = aot.timeline()
+    first = min((r["start"] for r in spans), default=0.0)
+    say("single", kernel_check_compile=out["compile"],
+        store_held=out["store_held"],
+        store_timeline=[[r["what"], r["shapes"][0][0], r["shapes"][2][0],
+                         round(r["start"] - first, 1),
+                         round(r["end"] - first, 1)] for r in spans])
     problems = [f"verify bucket {r['bucket']} x {r['keys']} keys: device "
                 f"verdicts differ from CpuEd25519Verifier" for r in rows
                 if not r["equal_to_cpu"] or r["rejected"] != r["corrupted"]]
+    problems += store_failures("kernel check", out["compile"], held, shapes)
 
     n = sizes.leaves
     leaves = [b"chip-smoke-leaf-%d-%d" % (seed, i) * (1 + i % 3)
@@ -375,9 +428,19 @@ def ring_pool(phase: str, sizes: Sizes, seed: int, lanes: int = 1
             raise SystemExit(f"chip_smoke: {lanes} lanes on "
                              f"{len(set(devices))} distinct devices")
     nyms, attribs, users = local_stream(pool.trustee, sizes, seed)
+    # what warm_pool prewarms, per lane (the store's key holds the
+    # device): held by the store already, or just obtained by the kernel
+    # check in this process, it may not be traced again
+    held, shapes = shapes_held(
+        [(b, 1) for b in pipe.buckets[:2]],
+        [lane.inner.device for lane in pipe.lanes] if lanes > 1 else (None,))
+    c0 = compile_stats()
     warm = lp.warm_pool(pool, nyms.pop(), timeout=600.0)
     out["setup_s"] = warm["setup_s"]
-    say(phase, pool_setup_s=warm["setup_s"], compile=compile_stats())
+    out["setup_compile"] = compile_since(c0)
+    say(phase, pool_setup_s=warm["setup_s"], compile=out["setup_compile"],
+        store_held=[held, shapes])
+    problems += store_failures(phase, out["setup_compile"], held, shapes)
 
     if lanes > 1:
         # unique content per lane: the ring's verdict cache is shared, so
@@ -455,14 +518,12 @@ def ring_pool(phase: str, sizes: Sizes, seed: int, lanes: int = 1
 
 
 def phase_single(sizes: Sizes, seed: int, rehearsal: bool) -> dict:
-    from plenum_tpu.ops import compile_stats
     t0 = time.perf_counter()
     device = require_device(rehearsal)
     say("single", device=device)
     out: dict = {"device": device, "native": require_native()}
     out["kernel_check"], problems = kernel_check(sizes, seed)
     out["kernel_check"]["seconds"] = round(time.perf_counter() - t0, 1)
-    out["kernel_check"]["compile"] = compile_stats()
     out["pool"], p = ring_pool("single", sizes, seed)
     # everything before the traffic window: backend init, native build,
     # tracing + compiling (kernel check), warm-up txn, prewarm, pin
@@ -522,7 +583,9 @@ def phase_served(sizes: Sizes, seed: int, rehearsal: bool) -> dict:
                        timeout=400.0)
     service, final = res.get("service") or {}, res.get("crypto_service") or {}
     device = service.get("device")
-    say("served", device=device, setup_s=service.get("setup_s"))
+    at_pin = service.get("at_pin") or {}
+    say("served", device=device, setup_s=service.get("setup_s"),
+        compile=at_pin.get("compile"))
     problems = []
     want = "cpu" if rehearsal else "tpu"
     if not device or device["platform"] != want:
@@ -531,7 +594,15 @@ def phase_served(sizes: Sizes, seed: int, rehearsal: bool) -> dict:
     if res["txns_ordered"] != 2 * sizes.writes:
         problems.append(f"{res['txns_ordered']}/{2 * sizes.writes} writes "
                         f"got f+1 matching replies")
-    at_pin = service.get("at_pin") or {}
+    if at_pin.get("compile"):
+        # this launcher cannot ask the store (only the chip's owner can):
+        # what the service did not store it must have loaded, untraced
+        c = at_pin["compile"]
+        shapes = c["aot_stores"] + c["aot_loads"]
+        problems += store_failures("served", c, c["aot_loads"], shapes)
+        if not shapes:
+            problems.append("served: the service's prewarm obtained "
+                            "nothing through the executable store")
 
     def grew(*path) -> int:
         """Growth of one crypto_service stats() counter over the window."""
@@ -676,6 +747,13 @@ def main(argv=None) -> int:
         json.dump({"seed": args.seed, "rehearsal": args.rehearse_cpu,
                    "results": results}, fh, indent=1)
     failed = [p for r in results.values() for p in r.get("problems", ())]
+    served = (results.get("served") or {}).get("setup_compile") or {}
+    if results["single"]["ok"] and (served.get("aot_stores")
+                                    or served.get("traces")):
+        # the service's shapes are among the kernel check's: minutes
+        # after `single` stored them, another process must load them
+        failed.append(f"served: the service traced or stored a verify "
+                      f"shape the single phase had just stored: {served}")
     if failed or len(results) != len(PHASES):
         print("chip_smoke FAILED:\n  " + "\n  ".join(failed), flush=True)
         return 1

@@ -16,8 +16,10 @@ from __future__ import annotations
 import hashlib
 import time
 from abc import ABC, abstractmethod
-from typing import Optional, Sequence
+from concurrent.futures import ThreadPoolExecutor
+from typing import Iterable, Optional, Sequence
 
+import jax
 import numpy as np
 
 from plenum_tpu.common.metrics import MetricsName
@@ -31,7 +33,7 @@ try:
 except Exception:  # pragma: no cover
     _HAVE_CRYPTOGRAPHY = False
 
-from plenum_tpu.ops import ed25519 as _ops
+from plenum_tpu.ops import aot as _aot, ed25519 as _ops
 
 VerifyItem = tuple[bytes, bytes, bytes]   # (message, signature64, verkey32)
 
@@ -127,6 +129,12 @@ class Ed25519Verifier(ABC):
 
     def collect_batch(self, token, wait: bool = True) -> Optional[np.ndarray]:
         return token
+
+    def preload(self, waves: Iterable[tuple[int, int]]) -> list:
+        """Warm-up hint: the (items, distinct verkeys) of every wave a
+        prewarm is about to dispatch. A device backend obtains those
+        programs now, all at once; a host backend has none."""
+        return []
 
 
 _VK_VALID_CACHE: dict[bytes, bool] = {}
@@ -257,6 +265,14 @@ class CpuEd25519Verifier(Ed25519Verifier):
         return out
 
 
+def _bytes_avals(m_pad: int, u_pad: int) -> tuple:
+    """The abstract signature of one compressed dispatch, as
+    `_dispatch_bytes` stages it: S, h, key table, key index, R."""
+    rows = jax.ShapeDtypeStruct((m_pad, 32), np.uint8)
+    return (rows, rows, jax.ShapeDtypeStruct((u_pad, 32), np.uint8),
+            jax.ShapeDtypeStruct((m_pad,), np.int32), rows)
+
+
 class JaxEd25519Verifier(Ed25519Verifier):
     """Batched device verification.
 
@@ -296,6 +312,9 @@ class JaxEd25519Verifier(Ed25519Verifier):
         # sharding seam the multi-device pipeline builds on. None = the
         # backend default device (single-chip behavior, unchanged).
         self.device = device
+        # (m_pad, u_pad) -> the executable preload() obtained for it;
+        # _device_verify_bytes looks here before the jitted kernel
+        self._preloaded: dict[tuple[int, int], object] = {}
 
     def _neg_a_limbs(self, vk: bytes) -> Optional[np.ndarray]:
         if vk in self._pt_cache:
@@ -401,8 +420,63 @@ class JaxEd25519Verifier(Ed25519Verifier):
         return m_pad, (small if n_keys <= small else m_pad)
 
     def _device_verify_bytes(self, s_u8, h_u8, k_u8, idx, r_u8):
-        return _ops.verify_kernel_bytes(
+        kernel = self._preloaded.get((s_u8.shape[0], k_u8.shape[0]),
+                                     _ops.verify_kernel_bytes)
+        return kernel(
             *_ops.stage_on(self.device, s_u8, h_u8, k_u8, idx, r_u8))
+
+    def in_store(self, waves: Iterable[tuple[int, int]]) -> dict:
+        """{(m_pad, u_pad): does the executable store hold that program
+        for this verifier's device?} — what preload() would load rather
+        than compile. Touches the backend: the chip's owner only."""
+        return {shape: _aot.has_entry(_ops.verify_kernel_bytes,
+                                      _bytes_avals(*shape), self.device)
+                for shape in sorted({self._pad_sizes(n, keys)
+                                     for n, keys in waves})}
+
+    def preload(self, waves: Iterable[tuple[int, int]]) -> list:
+        """Obtain the verify program of every wave a prewarm is about to
+        dispatch through the executable store (ops/aot.py): on a machine
+        that compiled them before, each is loaded without entering the
+        kernel's Python body; otherwise it is traced and compiled as ever
+        and stored for the next process. The warm-up waves that follow
+        prove each one answers; a shape nobody preloaded still traces
+        and compiles on its first dispatch.
+        -> the (m_pad, u_pad) shapes obtained by this call.
+
+        Stored programs are loaded ON THE CALLING THREAD, one after the
+        other; the rest compile at once, one thread each (XLA compiles
+        with the GIL released). Measured on a v5e (PR 26): one PjRt load
+        issued from the process's main thread takes ~13 s, the next ~7 s,
+        whatever the shape; the same load issued from another thread
+        takes 50-75 s, and two side by side ~50 s each. So call this
+        from the main thread.
+
+        A subclass that re-routes the dispatch (the sharded plane's SPMD
+        program, a test double) never runs these programs, so it gets
+        none."""
+        cls = type(self)
+        if (not self._compressed_dispatch
+                or cls.submit_batch is not JaxEd25519Verifier.submit_batch
+                or cls._device_verify_bytes
+                is not JaxEd25519Verifier._device_verify_bytes):
+            return []
+        stored = {shape: held for shape, held in self.in_store(waves).items()
+                  if shape not in self._preloaded}
+
+        def obtain(shape):
+            return _aot.obtain(_ops.verify_kernel_bytes,
+                               _bytes_avals(*shape), self.device)
+
+        for shape, held in stored.items():
+            if held:
+                self._preloaded[shape] = obtain(shape)
+        missing = [shape for shape, held in stored.items() if not held]
+        if missing:
+            with ThreadPoolExecutor(len(missing)) as pool:
+                self._preloaded.update(zip(missing,
+                                           pool.map(obtain, missing)))
+        return list(stored)
 
     def _dispatch_limbs(self, items: Sequence[VerifyItem]):
         n = len(items)
@@ -548,6 +622,9 @@ class CoalescingVerifier(Ed25519Verifier):
         # fill latency, dispatch wall time, batch size
         self.metrics = None
         self._first_staged_at: Optional[float] = None
+
+    def preload(self, waves) -> list:
+        return self._inner.preload(waves)
 
     def flush(self) -> bool:
         """Dispatch everything staged if the device is idle. -> dispatched?

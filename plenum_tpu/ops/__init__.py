@@ -16,10 +16,20 @@ a checkout never carries another machine's XLA:CPU entries). The path
 does not depend on the host, the user or the process: it is part of what
 a cache hit is keyed on, so a directory that moves never hits.
 
-`compile_stats()` counts what the cache cannot hide: every executable
-this process had to obtain (compiled or loaded) and the seconds spent,
-so a harness can report set-up apart from its traffic window and assert
-the window itself obtained none.
+A hit in that cache still costs the kernel's whole trace and lowering,
+because the lowered module is the cache's key. The executable store
+(`plenum_tpu/ops/aot.py`, entries under `<cache dir>/plenum_aot/`) is
+keyed on what decides the module instead, so the pinned verify programs
+are obtained once per MACHINE: at warm-up a later process loads them,
+concurrently, without entering the kernel's Python body.
+
+`compile_stats()` counts what neither can hide: every executable this
+process had to obtain (compiled, loaded from JAX's cache or loaded from
+the store) and the seconds spent, so a harness can report set-up apart
+from its traffic window and assert the window itself obtained none; and
+how often the store engaged (`aot_loads`, `aot_stores`, `aot_rejected`)
+beside how often a stored kernel's body was entered all the same
+(`traces`).
 """
 from __future__ import annotations
 
@@ -42,16 +52,24 @@ jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_JAXPR_TRACE = "/jax/core/compile/jaxpr_trace_duration"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 # one entry per event; list.append is atomic, so lane threads compiling
 # concurrently during prewarm cannot lose a count
 _obtained_s: list[float] = []
 _cache_hits: list[int] = []
+_aot: dict[str, list[int]] = {
+    "aot_loads": [], "aot_stores": [], "aot_rejected": [], "traces": []}
+# names of the kernels the executable store serves: only THEIR traces
+# count (one verify trace fires this event for ~10^4 inner jnp calls too)
+_stored_kernels: set[str] = set()
 
 
-def _on_duration(event: str, duration_secs: float, **_kw) -> None:
+def _on_duration(event: str, duration_secs: float, **kw) -> None:
     if event == _BACKEND_COMPILE:
         _obtained_s.append(duration_secs)
+    elif event == _JAXPR_TRACE and kw.get("fun_name") in _stored_kernels:
+        _aot["traces"].append(1)
 
 
 def _on_event(event: str, **_kw) -> None:
@@ -63,15 +81,35 @@ jax.monitoring.register_event_duration_secs_listener(_on_duration)
 jax.monitoring.register_event_listener(_on_event)
 
 
+def count_traces_of(kernel_name: str) -> None:
+    """The executable store names each kernel it serves; from then on a
+    trace of that kernel shows in `compile_stats()["traces"]`."""
+    _stored_kernels.add(kernel_name)
+
+
+def note_aot(kind: str, seconds: float = 0.0) -> None:
+    """The executable store's events. A load is an executable obtained:
+    it counts in `executables` and `seconds` like a compile."""
+    _aot[kind].append(1)
+    if kind == "aot_loads":
+        _obtained_s.append(seconds)
+
+
 def compile_stats() -> dict:
     """Cumulative, process-wide: `executables` = programs this process
     obtained from the backend (each a jit-cache miss: an XLA compile or a
-    persistent-cache load), `cache_hits` = how many of those the
-    persistent cache served, `seconds` = wall time spent obtaining them.
+    persistent-cache load; or a load from the executable store),
+    `cache_hits` = how many of those JAX's persistent cache served,
+    `seconds` = wall time spent obtaining them (summed, so obtains that
+    overlapped count in full). `aot_loads` / `aot_stores` /
+    `aot_rejected` = entries of the executable store loaded, written, and
+    found damaged (deleted and compiled again); `traces` = times the
+    Python body of a kernel the store serves was entered.
     Snapshot before and after a window and subtract."""
     return {"executables": len(_obtained_s),
             "cache_hits": len(_cache_hits),
-            "seconds": round(sum(_obtained_s), 3)}
+            "seconds": round(sum(_obtained_s), 3),
+            **{kind: len(events) for kind, events in _aot.items()}}
 
 
 def device_info() -> dict:

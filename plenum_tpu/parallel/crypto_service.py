@@ -448,6 +448,17 @@ class CryptoPlaneServer:
                         waves.append((b, [
                             (*PREWARM_ITEM[:2], i.to_bytes(32, "little"))
                             for i in range(b)]))
+                # every wave's program first: the executable store loads
+                # what this machine compiled before, the rest compile at
+                # once. ON THIS THREAD, the loop's and the process's main
+                # one, and not in an executor: a PjRt load takes ~10 s
+                # from here and 50-75 s from any other thread (PR 26), and
+                # nothing else is served before pin. A program that cannot
+                # be obtained is this request's error; the waves below
+                # then prove each one answers.
+                self._inner.preload(
+                    [(len(items), len({vk for _, _, vk in items}))
+                     for _, items in waves])
                 for b, items in waves:
                     digests = [_digest(*it) for it in items]
                     before = sup.supervisor_stats() if sup else None

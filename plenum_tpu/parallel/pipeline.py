@@ -108,6 +108,12 @@ def _device_backed(verifier) -> bool:
 PREWARM_ITEM = (b"pipeline-prewarm", b"\x00" * 64, b"\x00" * 32)
 
 
+def _pad_waves(buckets: Sequence[int]) -> list[tuple[int, int]]:
+    """What `_warm_dispatch` sends per bucket, as `Ed25519Verifier.preload`
+    takes it: `bucket` lanes under ONE verkey."""
+    return [(b, 1) for b in buckets]
+
+
 def _warm_dispatch(inner, bucket: int) -> None:
     """One all-pad warm-up wave of `bucket` PREWARM_ITEM lanes through
     `inner`, which may NOT swallow a failure. A supervised inner turns a device dispatch
@@ -467,10 +473,14 @@ class CryptoPipeline:
             return []
         warmed = []
         ladder = set(self.buckets)
-        for b in sorted(set(buckets if buckets is not None
-                            else self.buckets[:1])):
-            if b not in ladder:
-                continue
+        want = [b for b in sorted(set(buckets if buckets is not None
+                                      else self.buckets[:1]))
+                if b in ladder]
+        # every bucket's program at once (loaded from the executable
+        # store where this machine compiled it before); the waves below
+        # stay one after the other and prove each one answers
+        self._ed_inner.preload(_pad_waves(want))
+        for b in want:
             _warm_dispatch(self._ed_inner, b)
             self.note_shape(self._cache_bucket(1, b))
             warmed.append(b)
@@ -1616,6 +1626,9 @@ class MultiDeviceCryptoPipeline(CryptoPipeline):
         errors: list[tuple[int, Exception]] = []
 
         def warm_lane(lane: _DeviceLane) -> None:
+            # the lane's own executables (the store's key holds the
+            # device ordinal), all buckets at once, then the waves
+            lane.inner.preload(_pad_waves(want))
             for b in want:
                 _warm_dispatch(lane.inner, b)
                 self._note_lane_shape(lane, self._cache_bucket(1, b))

@@ -471,6 +471,11 @@ class SupervisedVerifier(Ed25519Verifier):
 
     # --- Ed25519Verifier protocol ---------------------------------------
 
+    def preload(self, waves) -> list:
+        """Warm-up only, and unsupervised on purpose: a program that
+        cannot be obtained must raise there, not fall back to the CPU."""
+        return self._device.preload(waves)
+
     def submit_batch(self, items: Sequence[VerifyItem]):
         items = list(items)
         self._service_probe()

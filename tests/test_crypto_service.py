@@ -535,6 +535,33 @@ def test_prewarm_full_key_tables_and_loud_failure(service):
     fed.close()
 
 
+def test_prewarm_rpc_preloads_both_key_tables_before_its_waves(service):
+    """The RPC knows both key-table shapes from full_keys and hands the
+    inner's preload() all of them before the first wave (a device inner
+    obtains them at once, through the executable store)."""
+    from plenum_tpu.crypto.ed25519 import CpuEd25519Verifier
+    from plenum_tpu.parallel.crypto_service import FederatedEd25519Client
+    calls = []
+
+    class Recording(CpuEd25519Verifier):
+        def preload(self, waves):
+            calls.append(("preload", sorted(waves)))
+            return []
+
+        def submit_batch(self, items):
+            calls.append(("wave", len(items),
+                          len({vk for _, _, vk in items})))
+            return super().submit_batch(items)
+
+    server, connect = service
+    server._inner = Recording()
+    fed = FederatedEd25519Client(socket_path=connect().socket_path)
+    assert fed.prewarm([16], full_keys=True)["warmed"] == [16]
+    fed.close()
+    assert calls == [("preload", [(16, 1), (16, 16)]), ("wave", 16, 1),
+                     ("wave", 16, 16)]
+
+
 def test_federated_pipeline_rides_remote_lane(service):
     """End-to-end: a FederatedCryptoPipeline with one REAL remote lane
     over the service socket — prewarm negotiation turns padding off for
