@@ -237,6 +237,8 @@ class MetricsName:
     PIPELINE_DEDUP_RATIO = "pipeline.dedup_ratio"
     PIPELINE_BUCKET_HIT_RATE = "pipeline.bucket_hit_rate"
     PIPELINE_COMPILED_SHAPES = "pipeline.compiled_shapes"
+    # first submit of a wave to its verdicts (sampled, one a wave)
+    PIPELINE_VERDICT_WAIT = "pipeline.verdict_wait"
     PIPELINE_CTL_FLUSH_WAIT = "pipeline_ctl.flush_wait"
     PIPELINE_CTL_BUCKET_FLOOR = "pipeline_ctl.bucket_floor"
     PIPELINE_CTL_DECISIONS = "pipeline_ctl.decisions"
@@ -404,6 +406,7 @@ SAMPLED_NAMES = frozenset({
     MetricsName.COMMIT_DURABLE_TIME, MetricsName.COMMIT_REPLY_TIME,
     MetricsName.BLS_PAIRINGS_PER_BATCH,
     MetricsName.CRYPTO_DISPATCH_BUDGET,
+    MetricsName.PIPELINE_VERDICT_WAIT,
     MetricsName.READ_PROOF_GEN_TIME,
     MetricsName.READ_PROOF_BYTES_STATE,
     MetricsName.READ_PROOF_BYTES_STATE_MULTI,

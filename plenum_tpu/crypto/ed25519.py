@@ -446,11 +446,13 @@ class JaxEd25519Verifier(Ed25519Verifier):
 
         Stored programs are loaded ON THE CALLING THREAD, one after the
         other; the rest compile at once, one thread each (XLA compiles
-        with the GIL released). Measured on a v5e (PR 26): one PjRt load
-        issued from the process's main thread takes ~13 s, the next ~7 s,
-        whatever the shape; the same load issued from another thread
-        takes 50-75 s, and two side by side ~50 s each. So call this
-        from the main thread.
+        with the GIL released), except those another process of this
+        machine is compiling already, whose entries are waited for and
+        loaded on the calling thread too. Measured on a v5e (PR 26): one
+        PjRt load issued from the process's main thread takes ~13 s, the
+        next ~7 s, whatever the shape; the same load issued from another
+        thread takes 50-75 s, and two side by side ~50 s each. So call
+        this from the main thread.
 
         A subclass that re-routes the dispatch (the sharded plane's SPMD
         program, a test double) never runs these programs, so it gets
@@ -464,9 +466,15 @@ class JaxEd25519Verifier(Ed25519Verifier):
         stored = {shape: held for shape, held in self.in_store(waves).items()
                   if shape not in self._preloaded}
 
-        def obtain(shape):
+        def obtain(shape, wait=True):
             return _aot.obtain(_ops.verify_kernel_bytes,
-                               _bytes_avals(*shape), self.device)
+                               _bytes_avals(*shape), self.device, wait=wait)
+
+        def compile_unless_claimed(shape):
+            try:
+                return obtain(shape, wait=False)
+            except _aot.ClaimedElsewhere:
+                return None
 
         for shape, held in stored.items():
             if held:
@@ -474,8 +482,12 @@ class JaxEd25519Verifier(Ed25519Verifier):
         missing = [shape for shape, held in stored.items() if not held]
         if missing:
             with ThreadPoolExecutor(len(missing)) as pool:
-                self._preloaded.update(zip(missing,
-                                           pool.map(obtain, missing)))
+                got = list(pool.map(compile_unless_claimed, missing))
+            for shape, exe in zip(missing, got):
+                # another process of this machine is compiling that one
+                # (validators started together want the same programs):
+                # wait for its entry and load it HERE, not in a worker
+                self._preloaded[shape] = exe or obtain(shape)
         return list(stored)
 
     def _dispatch_limbs(self, items: Sequence[VerifyItem]):

@@ -2348,4 +2348,8 @@ class Node:
             "batch_controller": (self.batch_controller.trajectory()
                                  if self.batch_controller is not None
                                  else None),
+            # a validator that owns a device plane accounts for it here
+            # (parallel/pipeline.py plane_state); None without a ring
+            "plane": (self.c.pipeline.plane_state()
+                      if self.c.pipeline is not None else None),
         }

@@ -97,6 +97,7 @@ SNAPSHOT_SCHEMA: dict[str, frozenset] = {
         MetricsName.PIPELINE_DEDUP_RATIO,
         MetricsName.PIPELINE_BUCKET_HIT_RATE,
         MetricsName.PIPELINE_COMPILED_SHAPES,
+        MetricsName.PIPELINE_VERDICT_WAIT,
         MetricsName.PIPELINE_CTL_FLUSH_WAIT,
         MetricsName.PIPELINE_CTL_BUCKET_FLOOR,
         MetricsName.PIPELINE_CTL_DECISIONS,

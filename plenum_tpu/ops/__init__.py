@@ -112,10 +112,16 @@ def compile_stats() -> dict:
             **{kind: len(events) for kind, events in _aot.items()}}
 
 
-def device_info() -> dict:
+def device_info(memory: bool = False) -> dict:
     """The device as JAX reports it — stamped on every result that names
     a device figure. Initializes the backend: only the process that is
-    meant to own the chip may call this."""
+    meant to own the chip may call this. `memory` adds the peak bytes in
+    use on the fullest local device (0 where the backend keeps none)."""
     devs = jax.devices()
-    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
-            "count": len(devs)}
+    out = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+           "count": len(devs)}
+    if memory:
+        out["memory_peak_bytes"] = max(
+            int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devs)
+    return out

@@ -9,7 +9,9 @@ lowered module instead — the kernel's source, its abstract input
 signature, the JAX and backend builds, the device — so a later process
 loads the serialized executable and never enters the kernel's body.
 
-Where: `<jax_compilation_cache_dir>/plenum_aot/<kernel>-<key>.exe`. The
+Where: `<jax_compilation_cache_dir>/plenum_aot/<kernel>-<key>.exe` (and,
+while one process compiles it, `<...>.exe.claim` beside it: processes
+started together compile each entry once between them). The
 directory is derived from the one cache directory JAX already has (set
 from outside through `JAX_COMPILATION_CACHE_DIR`, else by
 `plenum_tpu/ops/__init__.py`); clearing the store is deleting that
@@ -149,7 +151,12 @@ def has_entry(jitted, avals, device=None) -> bool:
     return bool(path) and os.path.exists(path)
 
 
-def obtain(jitted, avals: Sequence[jax.ShapeDtypeStruct], device=None):
+class ClaimedElsewhere(Exception):
+    """Another process of this machine is compiling this entry now."""
+
+
+def obtain(jitted, avals: Sequence[jax.ShapeDtypeStruct], device=None,
+           wait: bool = True):
     """-> the `jax.stages.Compiled` of `jitted` for `avals`: loaded from
     the store when a sound entry is there, else lowered and compiled as
     any jit miss is and then stored. `device` as `ops.ed25519.stage_on`
@@ -159,7 +166,10 @@ def obtain(jitted, avals: Sequence[jax.ShapeDtypeStruct], device=None):
     Thread-safe; obtains of different keys run concurrently (XLA
     compiles release the GIL). A LOAD is best issued from the process's
     main thread: `JaxEd25519Verifier.preload` says what it costs
-    elsewhere."""
+    elsewhere. So where another process holds the entry's claim, a
+    caller on a worker thread passes `wait=False`, gets ClaimedElsewhere
+    at once, and asks again from its main thread, which waits for that
+    entry and loads it there."""
     ops.count_traces_of(jitted.__name__)
     key, path, target = _entry(jitted, avals, device)
     with _lock:
@@ -171,7 +181,7 @@ def obtain(jitted, avals: Sequence[jax.ShapeDtypeStruct], device=None):
         return fut.result()
     try:
         exe = _load_or_compile(jitted, path, avals, target,
-                               committed=device is not None)
+                               committed=device is not None, wait=wait)
     except BaseException as e:
         with _lock:
             del _obtained[key]          # the next caller tries again
@@ -182,24 +192,49 @@ def obtain(jitted, avals: Sequence[jax.ShapeDtypeStruct], device=None):
 
 
 def _load_or_compile(jitted, path: Optional[str], avals, device,
-                     committed: bool):
+                     committed: bool, wait: bool = True):
+    """Load the entry; where there is none, compile and store it, unless
+    another process of this machine holds the entry's claim (four
+    validators started at once each see their chip as ordinal 0 and want
+    the same keys): then wait for its entry and load that."""
     name, shapes = jitted.__name__, [tuple(a.shape) for a in avals]
-    if path and os.path.exists(path):
-        t0 = time.monotonic()
-        try:
-            exe = _load(path, device)
-        except Exception as e:
-            # damaged, or written by a runtime this one cannot read: one
-            # recompile, loudly
-            ops.note_aot("aot_rejected")
-            logger.warning("executable store: rejected %s (%s: %s); "
-                           "compiling again", path, type(e).__name__, e)
-            _unlink(path)
-        else:
-            t1 = time.monotonic()
-            ops.note_aot("aot_loads", t1 - t0)
-            _timeline.append(("load", name, shapes, t0, t1))
-            return exe
+    claimed = False
+    try:
+        while path:
+            if os.path.exists(path):
+                t0 = time.monotonic()
+                try:
+                    exe = _load(path, device)
+                except Exception as e:
+                    # damaged, or written by a runtime this one cannot
+                    # read: one recompile, loudly
+                    ops.note_aot("aot_rejected")
+                    logger.warning("executable store: rejected %s (%s: %s)"
+                                   "; compiling again", path,
+                                   type(e).__name__, e)
+                    _unlink(path)
+                else:
+                    t1 = time.monotonic()
+                    ops.note_aot("aot_loads", t1 - t0)
+                    _timeline.append(("load", name, shapes, t0, t1))
+                    return exe
+            claimed = _claim(path)
+            if claimed:
+                break
+            if not wait and _claimant_alive(path):
+                raise ClaimedElsewhere(path)
+            t0 = time.monotonic()
+            _wait_for_claimant(path)
+            _timeline.append(("wait", name, shapes, t0, time.monotonic()))
+        return _compile_and_store(jitted, path, avals, device, committed)
+    finally:
+        if claimed:
+            _unlink(_claim_path(path))
+
+
+def _compile_and_store(jitted, path: Optional[str], avals, device,
+                       committed: bool):
+    name, shapes = jitted.__name__, [tuple(a.shape) for a in avals]
     t0 = time.monotonic()
     sharding = SingleDeviceSharding(device) if committed else None
     with _compiled_here():
@@ -219,6 +254,62 @@ def _load_or_compile(jitted, path: Optional[str], avals, device,
             ops.note_aot("aot_stores")
             _timeline.append(("store", name, shapes, t1, time.monotonic()))
     return exe
+
+
+# --- one compile per machine: the claim beside an entry ---------------------
+# `<entry>.claim` holds the pid of the process compiling that entry. It is
+# made with O_EXCL, so of processes racing for one key exactly one compiles;
+# it goes when the entry is written (or the compile failed). A claim whose
+# process is gone is taken over; one older than CLAIM_MAX_S is ignored, so a
+# wedged claimant costs a wait, never the program.
+
+CLAIM_MAX_S = 900.0             # > the longest compile seen (251 s, PR 26)
+
+
+def _claim_path(path: str) -> str:
+    return path + ".claim"
+
+
+def _claim(path: str) -> bool:
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    try:
+        fd = os.open(_claim_path(path),
+                     os.O_CREAT | os.O_EXCL | os.O_WRONLY, 0o644)
+    except FileExistsError:
+        return False
+    with os.fdopen(fd, "w") as fh:
+        fh.write(str(os.getpid()))
+    return True
+
+
+def _claimant_alive(path: str) -> bool:
+    """Is a live process of this machine compiling `path`'s entry now?"""
+    claim = _claim_path(path)
+    try:
+        age = time.time() - os.stat(claim).st_mtime
+        with open(claim) as fh:
+            pid = int(fh.read().strip() or 0)
+    except (OSError, ValueError):
+        return False
+    if pid == os.getpid() or age > CLAIM_MAX_S:
+        return False
+    try:
+        os.kill(pid, 0)
+    except ProcessLookupError:
+        return False
+    except PermissionError:
+        pass                    # alive, someone else's
+    return True
+
+
+def _wait_for_claimant(path: str) -> None:
+    """Until the entry is there, or its claimant is gone (a claim left
+    behind is removed: the caller then claims for itself)."""
+    while not os.path.exists(path):
+        if not _claimant_alive(path):
+            _unlink(_claim_path(path))
+            return
+        time.sleep(0.2)
 
 
 @contextlib.contextmanager

@@ -103,6 +103,11 @@ def _device_backed(verifier) -> bool:
     return False
 
 
+# the commit-wave pad ladder every warm-up pins (`prewarm_cmt`): level
+# flushes dedup to small job counts, so a short power-of-two ladder covers
+# steady state and bigger levels split at the cap
+CMT_LADDER = (1, 2, 4, 8)
+
 # the all-pad warm-up lane: an all-zero verkey (device decompression
 # rejects it; every verdict is False and nothing touches a verdict cache)
 PREWARM_ITEM = (b"pipeline-prewarm", b"\x00" * 64, b"\x00" * 32)
@@ -389,7 +394,9 @@ class CryptoPipeline:
             "dispatched_items": 0,       # unique items that hit the device
             "coalesced_items": 0,        # caller items settled by waves
             "dedup_hits": 0,             # all kinds: cache + in-window dup
-            "cache_hits": 0,
+            "cache_hits": 0,             # all kinds
+            "ed_cache_hits": 0,          # signatures this ring's own
+                                         # verdict cache answered
             "bucket_hits": 0,            # waves landing on the floor bucket
             "pad_items": 0,
             "overflow_waves": 0,
@@ -450,6 +457,11 @@ class CryptoPipeline:
         shapes = self._shapes if shapes is None else shapes
         return sorted({k[1] for k in shapes if k[0] == KIND_ED})
 
+    def ed_shapes(self) -> list[list[int]]:
+        """[pad bucket, key table] of every verify shape dispatched so
+        far; once pinned, the only ones a wave may take."""
+        return sorted([k[1], k[2]] for k in self._shapes if k[0] == KIND_ED)
+
     def _cmt_buckets(self, shapes: Optional[set] = None) -> list[int]:
         """Pad buckets with at least one compiled commitment shape —
         the cmt lane's pin ladder, enforced by `_cmt_plan` after pin()."""
@@ -462,6 +474,22 @@ class CryptoPipeline:
         shapes = self._shapes if shapes is None else shapes
         tabs = [k[2] for k in shapes if k[0] == KIND_ED]
         return max(tabs) if tabs else 64
+
+    def quota_buckets(self) -> list[int]:
+        """The pad buckets ONE validator's ring pins: the ladder up to
+        the first bucket that holds a receive quota (a node stages at
+        most LISTENER_MESSAGE_QUOTA client requests, or
+        REMOTES_MESSAGE_QUOTA propagated ones, per prod cycle; what
+        coalesces beyond that splits at the largest pinned bucket).
+        64 and 128 lanes at the default quotas of 100."""
+        quota = max(self.config.LISTENER_MESSAGE_QUOTA,
+                    self.config.REMOTES_MESSAGE_QUOTA)
+        out = []
+        for b in self.buckets:
+            out.append(b)
+            if b >= quota:
+                break
+        return out
 
     def prewarm(self, buckets: Optional[Sequence[int]] = None) -> list[int]:
         """Compile the given pad buckets through the device inner NOW —
@@ -621,6 +649,7 @@ class CryptoPipeline:
                     tok.plan[i] = ("k", hit)
                     self.stats["dedup_hits"] += 1
                     self.stats["cache_hits"] += 1
+                    self.stats["ed_cache_hits"] += 1
                     wave.coalesced += 1
                 elif key in in_wave:
                     tok.plan[i] = ("w", wave, in_wave[key])
@@ -793,6 +822,11 @@ class CryptoPipeline:
             verdict_cache_put(self._ed_cache, self._CACHE_MAX, key,
                               bool(ok[j]))
         t_done = self._now()
+        if self.metrics is not None and wave.n_real:
+            # first submit of the wave to its verdicts: what a client-auth
+            # batch waits for (hold + pack + device), one sample a wave
+            self.metrics.add_event(MetricsName.PIPELINE_VERDICT_WAIT,
+                                   t_done - (wave.t_first or t_done))
         if self.controller is not None:
             self.controller.note_wave(
                 (wave.t_packed or t_done) - (wave.t_first or t_done),
@@ -1204,6 +1238,13 @@ class CryptoPipeline:
         total = self.stats["submitted_items"]
         return self.stats["dedup_hits"] / total if total else 0.0
 
+    def verify_items(self) -> int:
+        """Signature checks callers asked of the ring (`submitted_items`
+        counts every kind)."""
+        st = self.stats
+        return (st["submitted_items"] - st["bls_items"] - st["sha_items"]
+                - st["cmt_items"])
+
     def sample_metrics(self, metrics) -> None:
         """Cumulative gauges for the node's periodic sampler (read back
         via max/last in the report, like the supervisor counters)."""
@@ -1247,6 +1288,9 @@ class CryptoPipeline:
                       + self.stats["pad_items"]), 3),
             "compiled_shapes": self.compiled_shapes,
             "unpinned_shapes": self.stats["unpinned_shapes"],
+            "pinned": self.pinned,
+            "verify_items": self.verify_items(),
+            "verdict_cache_hits": self.stats["ed_cache_hits"],
             "bls": {k: self.stats[f"bls_{k}"]
                     for k in ("batches", "items", "unique")},
             "sha": {k: self.stats[f"sha_{k}"]
@@ -1259,6 +1303,35 @@ class CryptoPipeline:
         if self.controller is not None:
             out["controller"] = self.controller.trajectory()
         return out
+
+    def close(self) -> None:
+        """End of the owning process: release what the inner holds (a
+        lane's worker thread, a remote inner's socket). The single ring
+        itself holds neither."""
+        close = getattr(self._ed_inner, "close", None)
+        if callable(close):
+            close()
+
+    def supervisors(self) -> list:
+        """The SupervisedVerifier of every device lane (the single ring
+        has one, or none around a bare inner)."""
+        from .supervisor import find_supervisor
+        sup = find_supervisor(self._ed_inner)
+        return [sup] if sup is not None else []
+
+    def plane_state(self) -> dict:
+        """The owner's account of its device plane, as VALIDATOR_INFO
+        serves it: the ring's summary, each lane's supervisor, what this
+        process obtained from the compiler or the executable store, and
+        the device JAX gave it with the peak bytes in use (None behind a
+        host inner: asking would initialise a backend nobody uses)."""
+        from plenum_tpu import ops
+        return {"ring": self.summary(),
+                "supervisors": [s.supervisor_stats()
+                                for s in self.supervisors()],
+                "compile": ops.compile_stats(),
+                "device": ops.device_info(memory=True)
+                if self._bucketed else None}
 
 
 class _DeviceLane:
@@ -1419,6 +1492,11 @@ class MultiDeviceCryptoPipeline(CryptoPipeline):
                       for i, inner in enumerate(ed_inners)]
         self._rr = 0                     # round-robin cursor (unhinted)
         self._bucketed = any(l.bucketed for l in self.lanes)
+
+    def supervisors(self) -> list:
+        from .supervisor import find_supervisor
+        return [sup for sup in (find_supervisor(l.inner)
+                                for l in self.lanes) if sup is not None]
 
     # --- clock / key plumbing across lanes ------------------------------
 
@@ -1872,16 +1950,39 @@ class PipelinedTreeHasher(_TreeHasherBase):
                                  note_shape=self._pipeline.note_shape)
 
 
+def staged_bucket(config, submitters: int = 1) -> int:
+    """The power of two that holds what `submitters` co-hosted nodes can
+    stage in one prod cycle, each a full client quota and a full
+    propagate quota: the largest wave their shared plane may have to
+    pack."""
+    bucket = 1
+    while bucket < submitters * (config.LISTENER_MESSAGE_QUOTA
+                                 + config.REMOTES_MESSAGE_QUOTA):
+        bucket *= 2
+    return bucket
+
+
 def make_crypto_pipeline(config, backend: str,
-                         min_batch: int = 128,
+                         min_batch: int = 1,
                          supervised: bool = True,
                          ed_inner: Optional[Ed25519Verifier] = None,
-                         n_devices: Optional[int] = None
+                         n_devices: Optional[int] = None,
+                         submitters: int = 1
                          ) -> Optional[CryptoPipeline]:
-    """Config-gated construction seam: `CRYPTO_PIPELINE=False` (or a
-    non-device backend) -> None, and every consumer keeps its per-call
-    dispatch path — the disabled cost is one `is None` check at wiring
-    time (pinned by the microbenchmark in tests/test_pipeline.py).
+    """THE construction seam of the ring, for every process that owns a
+    device plane: a validator that owns its chip (`tools/start_node.py`
+    with `--backend jax`) and the co-hosted pool
+    (`tools/local_pool.build_pool`, `submitters` = its node count) build
+    theirs here, so the two cannot drift apart: supervised
+    `JaxEd25519Verifier(min_batch=1)` (the ring owns the shape policy:
+    its pinned ladder pads, the inner must not pad again), SHA on the
+    device past `PIPELINE_SHA_MIN_BATCH`, and a PIPELINE_MAX_BUCKET of
+    at least `staged_bucket(config, submitters)`.
+
+    Config-gated: `CRYPTO_PIPELINE=False` (or a non-device backend) ->
+    None, and every consumer keeps its per-call dispatch path — the
+    disabled cost is one `is None` check at wiring time (pinned by the
+    microbenchmark in tests/test_pipeline.py).
 
     `n_devices` (default: config.PIPELINE_DEVICES) selects the scale-out
     shape: 1 -> the single-ring PR 8 pipeline EXACTLY (no lane
@@ -1891,6 +1992,8 @@ def make_crypto_pipeline(config, backend: str,
         return None
     if backend not in ("jax", "jax-sharded") and ed_inner is None:
         return None
+    config = config.replace(PIPELINE_MAX_BUCKET=max(
+        staged_bucket(config, submitters), config.PIPELINE_MAX_BUCKET))
     if n_devices is None:
         n_devices = getattr(config, "PIPELINE_DEVICES", 1)
     hosts = [h.strip() for h in

@@ -136,52 +136,26 @@ def build_pool(n_nodes: int, backend: str, seed: int = 1,
     elif backend == "jax":
         from plenum_tpu.crypto.ed25519 import (CoalescingVerifier,
                                                JaxEd25519Verifier)
-        # one shape covering the coalesced steady state: every node can
-        # stage a full CLIENT quota and a full PROPAGATE quota in the same
-        # cycle, so pad every dispatch to the next power of two covering
-        # both (a second shape would mean a second multi-minute compile)
-        per_node = (config.LISTENER_MESSAGE_QUOTA
-                    + config.REMOTES_MESSAGE_QUOTA)
-        bucket = 1
-        while bucket < n_nodes * per_node:
-            bucket *= 2
-        # supervised, as in production: a device wedge mid-run degrades
-        # the pool to CPU-speed verdicts (breaker + hedged fallback)
-        # instead of stalling it — and run_load reports it as
+        # supervised either way, as in production: a device wedge mid-run
+        # degrades the pool to CPU-speed verdicts (breaker + hedged
+        # fallback) instead of stalling it — and run_load reports it as
         # backend_state != "ok", never as a healthy device run
-        from plenum_tpu.parallel.supervisor import supervise
         if config.CRYPTO_PIPELINE:
-            pipe_config = config.replace(PIPELINE_MAX_BUCKET=max(
-                bucket, config.PIPELINE_MAX_BUCKET))
-            if config.PIPELINE_REMOTE_HOSTS:
-                # cross-host federation: rostered remote crypto hosts
-                # join the ring as extra lanes with work-stealing
-                # (parallel/federation.py); gated strictly on the
-                # roster knob so unset keeps the arms below exact
-                from plenum_tpu.parallel.federation import \
-                    make_federated_pipeline
-                pipeline = make_federated_pipeline(pipe_config,
-                                                   min_batch=1)
-            elif config.PIPELINE_DEVICES != 1:
-                # multi-chip scale-out: one breakable lane per local
-                # device, each with its own supervised pinned verifier
-                from plenum_tpu.parallel.pipeline import \
-                    make_multidevice_pipeline
-                pipeline = make_multidevice_pipeline(
-                    pipe_config, config.PIPELINE_DEVICES, min_batch=1)
-            else:
-                from plenum_tpu.parallel.pipeline import CryptoPipeline
-                # the pipeline owns the shape policy: its pinned bucket
-                # ladder covers the coalesced steady state
-                pipeline = CryptoPipeline(
-                    ed_inner=supervise(JaxEd25519Verifier(min_batch=1)),
-                    config=pipe_config,
-                    sha_device=True,
-                    sha_min_device=config.PIPELINE_SHA_MIN_BATCH)
+            # the ring a validator that owns its chip builds
+            # (tools/start_node.py), sized for n_nodes submitters; the
+            # PIPELINE_DEVICES / PIPELINE_REMOTE_HOSTS knobs select the
+            # multi-chip and federated rings inside the same seam
+            from plenum_tpu.parallel.pipeline import make_crypto_pipeline
+            pipeline = make_crypto_pipeline(config, "jax",
+                                            submitters=n_nodes)
             plane = pipeline.verifier()
         else:
-            plane = CoalescingVerifier(supervise(
-                JaxEd25519Verifier(min_batch=bucket)))
+            # no ring: ONE shape covering the coalesced steady state (a
+            # second shape would mean a second multi-minute compile)
+            from plenum_tpu.parallel.pipeline import staged_bucket
+            from plenum_tpu.parallel.supervisor import supervise
+            plane = CoalescingVerifier(supervise(JaxEd25519Verifier(
+                min_batch=staged_bucket(config, n_nodes))))
     for name in names:
         bus = net.create_peer(name)
         components = NodeBootstrap(
@@ -249,11 +223,9 @@ def warm_pool(pool: Pool, warm_request, timeout: float) -> dict:
     names, replies = pool.names, pool.replies
     pipe = pool.pipeline
     if pipe is not None:
+        from plenum_tpu.parallel.pipeline import CMT_LADDER
         pipe.prewarm(pipe.buckets[:2])
-        # cmt ladder for the fused commit wave: level flushes across the
-        # co-hosted replicas dedup to small job counts, so a short pow-2
-        # ladder covers steady state (bigger levels split at the cap)
-        pipe.prewarm_cmt([1, 2, 4, 8])
+        pipe.prewarm_cmt(CMT_LADDER)
     for n in names:
         pool.nodes[n].handle_client_message(warm_request.to_dict(), "warmup")
     deadline = time.perf_counter() + timeout
@@ -337,9 +309,11 @@ def plane_supervisors(plane) -> list:
     from plenum_tpu.parallel.supervisor import find_supervisor
     if plane is None:
         return []
-    lanes = getattr(getattr(plane, "_pipeline", None), "lanes", None)
-    inners = [lane.inner for lane in lanes] if lanes else [plane]
-    return [sup for sup in map(find_supervisor, inners) if sup is not None]
+    pipe = getattr(plane, "_pipeline", None)
+    if pipe is not None:
+        return pipe.supervisors()
+    sup = find_supervisor(plane)
+    return [sup] if sup is not None else []
 
 
 def plane_report(plane, at_pin: Optional[list] = None) -> dict:

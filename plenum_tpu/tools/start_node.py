@@ -53,6 +53,39 @@ class _DurableSpylog(deque):
             pass          # a full disk must not take down consensus
 
 
+def warm_ring(pipeline) -> dict:
+    """Set-up of a validator's own device plane, all of it before the
+    node serves: the device JAX gave this process (which thereby owns
+    it), the pinned verify programs obtained through the executable
+    store on THIS thread — call from the main one: a first load from any
+    other costs 50-75 s (ops/aot.py) — one all-pad wave per bucket that
+    the device must answer, the commit-wave ladder, then pin(). From
+    there the ring dispatches pinned shapes only.
+    -> what it did, for the start line."""
+    import jax
+
+    from plenum_tpu import ops
+    from plenum_tpu.parallel.pipeline import CMT_LADDER
+    t0 = time.perf_counter()
+    device = ops.device_info()
+    if device["platform"] != "tpu" and not jax.config.jax_platforms:
+        # JAX falls back to the CPU in silence when it finds no
+        # accelerator; only a platform named from outside
+        # (JAX_PLATFORMS) may be anything else
+        raise SystemExit(f"--backend jax found {device}: a validator "
+                         f"that asks for its device must own a TPU")
+    before = ops.compile_stats()
+    buckets = pipeline.prewarm(pipeline.quota_buckets())
+    pipeline.prewarm_cmt(CMT_LADDER)
+    pipeline.pin()
+    after = ops.compile_stats()
+    return {"device": device, "pinned": pipeline.pinned,
+            "buckets": buckets, "shapes": pipeline.ed_shapes(),
+            "cmt_ladder": list(CMT_LADDER),
+            "compile": {k: round(after[k] - before[k], 3) for k in after},
+            "seconds": round(time.perf_counter() - t0, 3)}
+
+
 def build_node(name: str, base_dir: str, backend: str = "cpu",
                kv: str = "file", record: bool = False):
     """-> (prodable, node, registry) ready for a Looper."""
@@ -100,9 +133,18 @@ def build_node(name: str, base_dir: str, backend: str = "cpu",
     # bootstrap's default picks the native store with file fallback);
     # "native"/"chunked" select those engines explicitly
     storage_backend = kv if kv in ("native", "chunked") else "native"
+    # a validator that owns its device drives it through the ring the
+    # co-hosted pool uses: client-auth, the BLS batch check and the tree
+    # hasher all stage into it (node/bootstrap.py). `cpu` and `service`
+    # nodes get None and keep their per-call verifier
+    pipeline = None
+    if backend.startswith("jax"):
+        from plenum_tpu.parallel.pipeline import make_crypto_pipeline
+        pipeline = make_crypto_pipeline(config, backend)
     components = NodeBootstrap(
         name, genesis_txns=genesis, data_dir=data_dir,
         crypto_backend=backend, storage_backend=storage_backend,
+        pipeline=pipeline,
         bls_seed=bytes.fromhex(keys["bls_seed"]),
         # commitment scheme rides the ONE config (PLENUM_CONFIG_JSON
         # {"STATE_COMMITMENT": "verkle"}) — the whole pool must agree,
@@ -244,6 +286,10 @@ def main(argv=None):
 
     prodable, node, _ = build_node(args.name, args.base_dir, args.backend,
                                    args.kv, record=args.record)
+    ring = node.c.pipeline
+    if ring is not None:
+        # before the start line: whoever waits for it may send at once
+        print(json.dumps({"ring": warm_ring(ring)}), flush=True)
     import signal as _signal
     profiler = None
     if args.profile:
@@ -292,6 +338,11 @@ def main(argv=None):
             node.tracer.dump()
         except Exception:
             pass
+        if ring is not None:
+            try:
+                ring.close()
+            except Exception:
+                pass
         # 128+SIGTERM: supervisors must see termination, not a clean exit
         os._exit(143)
 

@@ -300,6 +300,81 @@ def test_two_threads_one_key_obtain_once(store):
     assert _delta(c0)["aot_stores"] == 1 and _delta(c0)["traces"] == 1
 
 
+def _entry_path(store) -> str:
+    return aot._entry(tiny_kernel, AVALS, None)[1]
+
+
+def test_a_live_claim_is_waited_for_and_its_entry_loaded(store):
+    """Four validators started together want the same programs: the one
+    that holds the claim compiles, the others wait and load its entry."""
+    path = _entry_path(store)
+    sleeper = subprocess.Popen([sys.executable, "-c",
+                                "import time; time.sleep(60)"])
+    try:
+        os.makedirs(store)
+        with open(aot._claim_path(path), "w") as fh:
+            fh.write(str(sleeper.pid))
+        c0 = ops.compile_stats()
+        with pytest.raises(aot.ClaimedElsewhere):   # a worker's answer
+            aot.obtain(tiny_kernel, AVALS, wait=False)
+        got = []
+        waiter = threading.Thread(
+            target=lambda: got.append(aot.obtain(tiny_kernel, AVALS)))
+        waiter.start()
+        time.sleep(0.6)
+        assert waiter.is_alive() and not os.path.exists(path)
+        assert _delta(c0)["aot_stores"] == 0        # it did not compile
+        # the claimant finishes: entry first, then the claim goes
+        aot._compile_and_store(tiny_kernel, path, AVALS,
+                               jax.local_devices()[0], False)
+        os.unlink(aot._claim_path(path))
+        waiter.join(30)
+        assert got and (np.asarray(got[0](X, Y)) == WANT).all()
+        d = _delta(c0)
+        assert d["aot_loads"] == 1 and d["aot_stores"] == 1  # ours above
+    finally:
+        sleeper.kill()
+        sleeper.wait()
+
+
+@pytest.mark.parametrize("stale", ["dead_claimant", "too_old", "garbage"])
+def test_a_stale_claim_is_taken_over(store, stale):
+    path = _entry_path(store)
+    os.makedirs(store)
+    gone = subprocess.Popen([sys.executable, "-c", "pass"])
+    gone.wait()
+    with open(aot._claim_path(path), "w") as fh:
+        fh.write("not a pid" if stale == "garbage" else
+                 str(gone.pid if stale == "dead_claimant" else os.getppid()))
+    if stale == "too_old":
+        old = time.time() - aot.CLAIM_MAX_S - 5
+        os.utime(aot._claim_path(path), (old, old))
+    c0 = ops.compile_stats()
+    exe = aot.obtain(tiny_kernel, AVALS, wait=False)
+    assert (np.asarray(exe(X, Y)) == WANT).all()
+    assert _delta(c0)["aot_stores"] == 1
+    assert sorted(os.listdir(store)) == [os.path.basename(path)]
+
+
+def test_racing_processes_store_one_entry_between_them(tmp_path):
+    env = dict(os.environ, PYTHONPATH=ROOT, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    procs = [subprocess.Popen([sys.executable, "-c", CHILD], env=env,
+                              stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                              text=True) for _ in range(4)]
+    stats = []
+    for p in procs:
+        out, err = p.communicate(timeout=300)
+        assert p.returncode == 0, err[-2000:]
+        got = json.loads(out.strip().splitlines()[-1])
+        assert got["out"] == WANT.tolist()
+        stats.append(got["stats"])
+    assert sum(s["aot_stores"] for s in stats) == 1
+    assert sum(s["aot_loads"] for s in stats) == 3
+    (name,) = os.listdir(tmp_path / aot.DIR_NAME)   # no claim left behind
+    assert name.endswith(".exe")
+
+
 def test_no_cache_directory_means_no_store(monkeypatch):
     monkeypatch.setattr(aot, "store_dir", lambda: None)
     monkeypatch.setattr(aot, "_obtained", {})
@@ -403,27 +478,36 @@ def test_preload_loads_on_the_calling_thread_and_compiles_in_others(
         monkeypatch):
     """What the store holds is loaded here, in turn (a PjRt load issued
     from the main thread costs a fifth of one issued from any other:
-    PERF.md, PR 26); what it lacks compiles at once, a thread each."""
+    PERF.md, PR 26); what it lacks compiles at once, a thread each; what
+    another process is compiling meanwhile is waited for and loaded
+    here too, never in a worker."""
     from plenum_tpu.crypto.ed25519 import JaxEd25519Verifier
     seen = []
 
     def has_entry(jitted, avals, device=None):
         return avals[0].shape[0] <= 32
 
-    def obtain(jitted, avals, device=None):
-        seen.append((avals[0].shape[0], threading.get_ident()))
-        return f"exe{avals[0].shape[0]}"
+    def obtain(jitted, avals, device=None, wait=True):
+        m = avals[0].shape[0]
+        seen.append((m, threading.get_ident(), wait))
+        if m == 256 and not wait:
+            raise aot.ClaimedElsewhere("another validator has it")
+        return f"exe{m}"
     monkeypatch.setattr(aot, "has_entry", has_entry)
     monkeypatch.setattr(aot, "obtain", obtain)
     device = JaxEd25519Verifier()
-    assert device.preload([(16, 1), (32, 1), (64, 1), (128, 1)]) \
-        == [(16, 16), (32, 32), (64, 64), (128, 64)]
+    assert device.preload([(16, 1), (32, 1), (64, 1), (128, 1), (256, 1)]) \
+        == [(16, 16), (32, 32), (64, 64), (128, 64), (256, 64)]
     here = threading.get_ident()
-    assert [(m, t == here) for m, t in seen[:2]] == [(16, True), (32, True)]
-    assert sorted(m for m, _ in seen[2:]) == [64, 128]
-    assert all(t != here for _, t in seen[2:])
+    assert [(m, t == here) for m, t, _ in seen[:2]] \
+        == [(16, True), (32, True)]
+    assert sorted(m for m, _, _ in seen[2:5]) == [64, 128, 256]
+    assert all(t != here and not wait for _, t, wait in seen[2:5])
+    assert [(m, t == here, wait) for m, t, wait in seen[5:]] \
+        == [(256, True, True)]
     assert device._preloaded == {(16, 16): "exe16", (32, 32): "exe32",
-                                 (64, 64): "exe64", (128, 64): "exe128"}
+                                 (64, 64): "exe64", (128, 64): "exe128",
+                                 (256, 64): "exe256"}
 
 
 def test_preload_leaves_rerouted_dispatch_alone():
