@@ -185,6 +185,12 @@ class MetricsName:
     BATCH_CTL_DEPTH = "batch_ctl.depth"
     BATCH_CTL_COALESCE = "batch_ctl.coalesce"
     BATCH_CTL_DECISIONS = "batch_ctl.decisions"
+    # why the master primary cut each batch (ordering_service._cut_reason):
+    # cumulative counts, one event per cut (read back via max)
+    BATCH_CUT_FULL = "consensus.batch_cut_full"
+    BATCH_CUT_IDLE = "consensus.batch_cut_idle"
+    BATCH_CUT_TIMEOUT = "consensus.batch_cut_timeout"
+    BATCH_CUT_FORCED = "consensus.batch_cut_forced"
     VIEW_CHANGES = "consensus.view_changes"
     SUSPICIONS = "consensus.suspicions"
     BACKUP_INSTANCE_REMOVED = "consensus.backup_instance_removed"

@@ -16,7 +16,10 @@ from typing import Any, Optional
 class Config:
     # --- 3PC batching (ref plenum/config.py:256-258) ---
     Max3PCBatchSize: int = 1000
-    Max3PCBatchWait: float = 0.1        # ref default 3s; we run a faster loop
+    # the LONGEST a queued request waits for its batch, behind a batch of
+    # its instance still being ordered; an instance with nothing in flight
+    # cuts at once (ordering_service._cut_reason). Ref default 3s.
+    Max3PCBatchWait: float = 0.1
     # Deep in-flight window: how far the primary's speculative uncommitted
     # batches may run AHEAD of the last committed one before fresh cuts
     # pause (still clamped by the [low, low+LOG_SIZE] watermark window and

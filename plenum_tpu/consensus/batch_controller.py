@@ -2,9 +2,25 @@
 
 The reference runs `Max3PCBatchSize` / `Max3PCBatchWait` / the in-flight
 window as static config: right for exactly one pool shape and wrong for
-every other. This controller closes the loop the tracing plane opened
-(ROADMAP item 2): the ordering hot path stamps each batch's lifecycle on
-the node's INJECTABLE timer — queue wait at cut, cut → commit-quorum span,
+every other. Two mechanisms replace that here.
+
+The FIRST brake on latency is the primary's wait gate itself
+(`OrderingService._cut_reason`), which is self-clocked and needs no
+steering: a partial batch is cut the moment the instance has nothing in
+flight, and held only behind a batch still being ordered, so an idle
+pool answers at once and a busy one accumulates requests for exactly as
+long as its own 3PC round takes. `batch_wait` is the LONGEST a request
+may wait behind a batch in flight, not a wait every partial batch pays.
+Every cut names its reason (`CUT_METRICS`: `full`, `idle`, `timeout`,
+`forced`); the four cumulative counts ride the metrics store and
+`trajectory()["cuts"]`. The share of `idle` cuts is how often the gate's
+self-clocking engaged; a `timeout` cut means a round outlasted the wait.
+
+The SECOND is this controller, which closes the loop the tracing plane
+opened (ROADMAP item 2) over what the gate leaves open: how long that
+longest wait is, the size cap, the speculative depth and group-commit
+coalescing. The ordering hot path stamps each batch's lifecycle on the
+node's INJECTABLE timer — queue wait at cut, cut → commit-quorum span,
 group-commit flush span — and every `BATCH_CONTROL_INTERVAL` the
 controller folds those samples into rolling per-stage p50/p95 attribution
 and moves the knobs toward the latency SLO:
@@ -16,8 +32,9 @@ and moves the knobs toward the latency SLO:
   * **fixed per-batch costs dominate** (SLO violated, batches underfull,
     3PC/durable spans dominate): per-batch overhead — n² vote floods, BLS
     sign/verify, the flush — is being paid on batches that carry few
-    requests. Grow the wait so more requests coalesce per batch, and
-    raise group-commit coalescing so flushes amortize.
+    requests. Grow the wait (a partial batch holds longer behind a batch
+    in flight, so more requests coalesce), and raise group-commit
+    coalescing so flushes amortize.
   * **saturated** (SLO violated, batches full, service spans dominate):
     genuinely too much work in flight — multiplicatively shrink the
     speculative in-flight depth.
@@ -45,6 +62,17 @@ from plenum_tpu.config import Config
 # rolling-window length per stage: long enough that p95 is meaningful,
 # short enough that the loop tracks a load shift within a few intervals
 _WINDOW = 256
+
+# why a batch was cut (OrderingService._cut_reason) -> its cumulative count
+# on the metrics store: the queue filled, the instance had nothing in
+# flight, the oldest request was `batch_wait` old behind a batch still
+# being ordered, or a freshness batch was due
+CUT_METRICS = {
+    "full": MetricsName.BATCH_CUT_FULL,
+    "idle": MetricsName.BATCH_CUT_IDLE,
+    "timeout": MetricsName.BATCH_CUT_TIMEOUT,
+    "forced": MetricsName.BATCH_CUT_FORCED,
+}
 
 
 class BatchController:
@@ -88,6 +116,9 @@ class BatchController:
         self._durable: deque = deque(maxlen=_WINDOW)  # drain -> flush closed
         self._fills: deque = deque(maxlen=_WINDOW)    # reqs per cut batch
         self._fresh = 0          # samples since the last decision
+        # cumulative cuts by reason: the master OrderingService counts
+        # into this very dict (its `cuts`), trajectory() reports it
+        self.cuts = dict.fromkeys(CUT_METRICS, 0)
 
         self.decisions = 0
         self.last_decision: dict = {}
@@ -267,6 +298,7 @@ class BatchController:
             "wait_ms": round(self.batch_wait * 1000, 3),
             "depth": self.depth,
             "coalesce": self.group_commit_max,
+            "cuts": dict(self.cuts),
             "slo_ms": round(self._config.BATCH_SLO_P95 * 1000, 3),
             "stage_p50_ms": {k: round(v * 1000, 3) for k, v in p50.items()},
             "stage_p95_ms": {k: round(v * 1000, 3) for k, v in p95.items()},

@@ -39,6 +39,7 @@ from plenum_tpu.common.timer import TimerService
 from plenum_tpu.common import tracing
 from plenum_tpu.config import Config
 
+from .batch_controller import CUT_METRICS
 from .batch_executor import AppliedBatch, BatchExecutor
 from .batch_id import BatchID
 from .bls_bft_replica import BlsBftReplica
@@ -85,6 +86,12 @@ class OrderingService:
         # (view, pp_seq_no) -> cut stamp on the injectable timer; feeds
         # the controller's cut -> commit-quorum span on the primary
         self._cut_ts: dict[tuple[int, int], float] = {}
+        # why each batch this instance cut was cut (_cut_reason):
+        # cumulative, one count per CUT_METRICS reason. ONE set of counts:
+        # the master counts into its controller's, which trajectory() shows
+        self.cuts: dict[str, int] = (
+            controller.cuts if controller is not None
+            else dict.fromkeys(CUT_METRICS, 0))
 
         # 3PC logs (all keyed by (view_no, pp_seq_no))
         self.sent_preprepares: dict[tuple[int, int], PrePrepare] = {}
@@ -240,19 +247,11 @@ class OrderingService:
         ledgers = [ledger_id] if ledger_id is not None else list(self.request_queues)
         for lid in ledgers:
             queue = self.request_queues.setdefault(lid, OrderedDict())
-            if not queue and not force_empty:
-                continue
-            # Partial batches wait up to the batch wait for more requests
-            # (full ones cut immediately). The wait is measured from the
-            # OLDEST queued request's own enqueue stamp (the queue value):
-            # the previous per-ledger clock was re-armed every prod tick
-            # that left leftovers behind — e.g. while the in-flight gate
-            # held — so under a steady trickle a partial batch could wait
-            # far past the configured bound.
-            if (not force_empty and len(queue) < max_size
-                    and now - next(iter(queue.values())) < max_wait):
-                continue
-            while queue or force_empty:
+            while True:
+                reason = self._cut_reason(queue, now, max_size, max_wait,
+                                          force_empty)
+                if reason is None:
+                    break
                 if self._data.pp_seq_no + 1 > self._data.high_watermark:
                     break
                 # bound the SPECULATIVE window: how far uncommitted applies
@@ -293,14 +292,45 @@ class OrderingService:
                 # queue wait attributed from the oldest request actually
                 # CUT (a stale bodyless head must not inflate the sample)
                 self._send_one_batch(lid, digests,
-                                     queue_wait=max(0.0, now - oldest_cut))
+                                     queue_wait=max(0.0, now - oldest_cut),
+                                     reason=reason)
                 sent += 1
                 if force_empty:
                     break
         return sent
 
+    def _cut_reason(self, queue: OrderedDict, now: float, max_size: int,
+                    max_wait: float, force_empty: bool) -> Optional[str]:
+        """Why the next batch may be cut NOW (a `CUT_METRICS` key), or
+        None to hold. The one wait gate, self-clocked (Nagle's rule): a
+        partial batch waits only while waiting can buy something, i.e.
+        while an earlier batch of this instance is still being ordered.
+        An idle instance proposes what has queued at once; a busy one
+        accumulates for as long as its own 3PC round takes, so batches
+        grow with load by themselves. The batch wait stays the LONGEST a
+        request may wait, measured from the OLDEST queued request's own
+        enqueue stamp (the queue value), so no code path can restart a
+        waiting request's clock. Pure function of consensus state and the
+        injectable timer: record/replay cuts the same batches."""
+        if force_empty:
+            return "forced"
+        if not queue:
+            return None
+        if len(queue) >= max_size:
+            return "full"
+        head, enq_ts = next(iter(queue.items()))
+        # a bodyless head (send_3pc_batch re-queued it with a fresh stamp
+        # and pulls its body) is no reason to cut: it keeps its once-per-
+        # batch-wait retry, the only timeout cut with nothing in flight
+        if (self._data.pp_seq_no <= self._data.last_ordered_3pc[1]
+                and self._get_request(head) is not None):
+            return "idle"
+        if now - enq_ts >= max_wait:
+            return "timeout"
+        return None
+
     def _send_one_batch(self, ledger_id: int, digests: list[str],
-                        queue_wait: float = 0.0) -> None:
+                        queue_wait: float, reason: str) -> None:
         reqs = [r for r in (self._get_request(d) for d in digests) if r is not None]
         pp_time = self._timer.get_current_time()
         view_no = self._data.view_no
@@ -335,11 +365,13 @@ class OrderingService:
         key = (view_no, pp_seq_no)
         self.sent_preprepares[key] = pre_prepare
         self.prePrepares[key] = pre_prepare
+        self.cuts[reason] += 1
         if self._controller is not None:
             self._controller.note_batch_cut(queue_wait, len(digests))
             self._cut_ts[key] = pp_time
         if self._metrics is not None:
             self._phase_ts[key] = [self._timer.get_current_time(), None]
+            self._metrics.add_event(CUT_METRICS[reason], self.cuts[reason])
         if self._tracer.enabled:
             # reqs list links request digests -> this batch for waterfall
             # assembly; seq links the batch -> the durable flush event

@@ -62,6 +62,8 @@ SNAPSHOT_SCHEMA: dict[str, frozenset] = {
         MetricsName.BATCH_CTL_SIZE, MetricsName.BATCH_CTL_WAIT,
         MetricsName.BATCH_CTL_DEPTH, MetricsName.BATCH_CTL_COALESCE,
         MetricsName.BATCH_CTL_DECISIONS,
+        MetricsName.BATCH_CUT_FULL, MetricsName.BATCH_CUT_IDLE,
+        MetricsName.BATCH_CUT_TIMEOUT, MetricsName.BATCH_CUT_FORCED,
         MetricsName.VIEW_CHANGES, MetricsName.SUSPICIONS,
         MetricsName.BACKUP_INSTANCE_REMOVED, MetricsName.CATCHUPS,
         MetricsName.MASTER_3PC_BATCH_TIME,

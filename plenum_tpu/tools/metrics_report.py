@@ -408,6 +408,13 @@ def derive_summary(folds: dict[str, dict], span_s: float,
                 folds.get("batch_ctl.coalesce", {}).get("last") or 0),
             "decisions": int(cum("batch_ctl.decisions") or 0),
         }
+    # why the master primary cut its batches (cumulative counts): `idle`
+    # is the self-clocked gate engaging, `timeout` the batch wait expiring
+    # behind a batch still being ordered
+    cuts = {reason: int(cum(f"consensus.batch_cut_{reason}") or 0)
+            for reason in ("full", "idle", "timeout", "forced")}
+    if any(cuts.values()):
+        out["batch_cuts"] = cuts
     # verified read plane (docs/reads.md): volume, cache effectiveness,
     # proof mix, and the proof-generation stage p50/p95 — a read-latency
     # regression must localize to proof gen vs everything else, and a

@@ -297,3 +297,230 @@ def test_view_change_reverts_deep_speculative_stack_in_reverse():
     assert reverted == list(reversed(applied))
     assert executor.applied == []
     pool.net.remove_rule(rule)
+
+
+# --- the self-clocked wait gate (OrderingService._cut_reason) ---------------
+# A partial batch waits only while an earlier batch of the instance is still
+# being ordered, and never past the batch wait. Every cut names its reason.
+
+
+def _gate_pool(**overrides):
+    """PoolSim with a batch wait long enough that only the gate's other
+    arms can cut inside a test -> (pool, primary's data, its ordering)."""
+    cfg = dict(Max3PCBatchWait=10.0, BATCH_CONTROLLER=False)
+    cfg.update(overrides)
+    pool = PoolSim(config=Config(**cfg))
+    pool.net.set_latency(0.001, 0.01)
+    primary = pool.replicas[pool.primary_name()]
+    return pool, primary.data, primary.ordering
+
+
+def _cuts(**nonzero):
+    return {"full": 0, "idle": 0, "timeout": 0, "forced": 0, **nonzero}
+
+
+def test_gate_idle_instance_cuts_a_queued_request_at_once():
+    pool, data, ordering = _gate_pool()
+    pool.finalize_request(make_request(0))
+    ordering.service()            # no time has passed: the wait bought nothing
+    assert data.pp_seq_no == 1
+    assert ordering.cuts == _cuts(idle=1)
+
+
+def test_gate_holds_behind_inflight_batch_then_cuts_the_queue_whole():
+    pool, data, ordering = _gate_pool()
+    rule = pool.net.add_rule(Stash(), match_type(Commit))
+    pool.finalize_request(make_request(0))
+    pool.run(0.5)
+    assert data.pp_seq_no == 1 and data.last_ordered_3pc[1] == 0
+    later = [make_request(i) for i in (1, 2, 3)]
+    for req in later:
+        pool.finalize_request(req)
+        pool.run(0.5)
+    assert data.pp_seq_no == 1, "partial queue cut behind a batch in flight"
+    assert len(ordering.request_queues[1]) == 3
+    # batch 1 orders: the turn after finds the instance idle and proposes
+    # everything that queued meanwhile as ONE batch
+    pool.net.remove_rule(rule)
+    pool.run(0.5)
+    assert data.pp_seq_no == 2
+    assert ordering.sent_preprepares[(0, 2)].req_idr == tuple(
+        r.digest for r in later)
+    assert ordering.cuts == _cuts(idle=2)
+    for name in NODES:
+        assert [o.pp_seq_no for o in pool.ordered[name]] == [1, 2]
+
+
+def test_gate_inflight_and_never_ordered_cuts_at_the_batch_wait_not_later():
+    pool, data, ordering = _gate_pool(Max3PCBatchWait=1.0)
+    pool.net.add_rule(Discard(), match_type(Commit))
+    pool.finalize_request(make_request(0))
+    pool.run(0.5)
+    assert data.pp_seq_no == 1
+    pool.finalize_request(make_request(1))
+    pool.run(1.0)                 # the last service() saw it 0.75 s old
+    assert data.pp_seq_no == 1
+    ordering.service()            # exactly batch_wait old
+    assert data.pp_seq_no == 2
+    assert ordering.cuts == _cuts(idle=1, timeout=1)
+
+
+def test_gate_full_queue_cuts_whatever_is_in_flight():
+    pool, data, ordering = _gate_pool(Max3PCBatchSize=3)
+    pool.net.add_rule(Stash(), match_type(Commit))
+    pool.finalize_request(make_request(0))
+    pool.run(0.5)
+    for i in range(1, 8):         # 7 queued behind batch 1: 3 + 3 + 1
+        pool.finalize_request(make_request(i))
+    ordering.service()
+    assert data.pp_seq_no == 3
+    assert ordering.cuts == _cuts(idle=1, full=2)
+    # the odd one out is a partial batch like any other: it holds
+    assert len(ordering.request_queues[1]) == 1
+
+
+def _block_reproposal(ordering, data):
+    ordering._awaiting_reproposal.add("digest-the-new-primary-lacks")
+    return lambda: ordering._awaiting_reproposal.clear()
+
+
+def _block_old_view(ordering, data):
+    ordering._awaited_old_view[(0, 1)] = "cited-digest"
+    return lambda: ordering._awaited_old_view.clear()
+
+
+def _block_new_view(ordering, data):
+    data.waiting_for_new_view = True
+
+    def release():
+        data.waiting_for_new_view = False
+    return release
+
+
+@pytest.mark.parametrize("block", [_block_reproposal, _block_old_view,
+                                   _block_new_view])
+def test_gate_idle_rule_stays_behind_the_view_change_guards(block):
+    pool, data, ordering = _gate_pool()
+    release = block(ordering, data)
+    pool.finalize_request(make_request(0))
+    pool.run(1.0)
+    assert data.pp_seq_no == 0 and not any(ordering.cuts.values())
+    release()
+    ordering.service()
+    assert data.pp_seq_no == 1 and ordering.cuts == _cuts(idle=1)
+
+
+def test_gate_bodyless_head_keeps_its_retry_cadence():
+    """An idle instance whose whole queue awaits request bodies must not
+    spin: the re-queue's fresh stamp paces the pull to one per batch wait."""
+    pool, data, ordering = _gate_pool(Max3PCBatchWait=1.0)
+    pulls = []
+    from plenum_tpu.common.internal_messages import RequestPropagates
+    pool.replicas[pool.primary_name()].internal_bus.subscribe(
+        RequestPropagates, pulls.append)
+    req = make_request(0)
+    pool.finalize_request(req)
+    del pool.requests[req.digest]          # the body never reached us
+    pool.run(2.0)
+    assert data.pp_seq_no == 0
+    assert 1 <= len(pulls) <= 2
+    pool.requests[req.digest] = req        # it lands: cut on the next turn
+    ordering.service()
+    assert data.pp_seq_no == 1
+
+
+def test_gate_cut_reasons_add_up_to_the_batches_cut():
+    """All four reasons in one run."""
+    pool = PoolSim(config=Config(Max3PCBatchSize=3, Max3PCBatchWait=1.0,
+                                 STATE_FRESHNESS_UPDATE_INTERVAL=5.0))
+    pool.net.set_latency(0.001, 0.01)
+    primary = pool.replicas[pool.primary_name()]
+    rule = pool.net.add_rule(Stash(), match_type(Commit))
+    pool.finalize_request(make_request(0))           # idle
+    pool.run(0.5)
+    for i in (1, 2, 3):
+        pool.finalize_request(make_request(i))       # full
+    pool.run(0.25)
+    pool.finalize_request(make_request(4))           # held, then timeout
+    pool.run(2.5)
+    pool.net.remove_rule(rule)
+    pool.run(8.0)                                    # quiet: freshness
+    cuts = primary.ordering.cuts
+    assert all(cuts[reason] >= 1 for reason in cuts), cuts
+    assert sum(cuts.values()) == primary.data.pp_seq_no
+    for name in NODES:
+        assert len(pool.ordered[name]) == primary.data.pp_seq_no
+
+
+def test_gate_self_clocks_under_a_steady_trickle_and_replays_identically():
+    """A 4-node pool, every message 20 ms on the wire (so a 3PC round is
+    ~60 ms), one write every 10 ms and a batch wait nothing reaches: the
+    primary proposes what queued during its own last round, so batches
+    hold several requests with no timer involved. The decision reads only
+    consensus state and the injectable timer, so replaying the primary's
+    recorded inputs cuts the same batches: byte-identical spans."""
+    from plenum_tpu.common.event_bus import ExternalBus
+    from plenum_tpu.common.metrics import MetricsName
+    from plenum_tpu.common.node_messages import DOMAIN_LEDGER_ID
+    from plenum_tpu.common.tracing import Tracer, span_sequence
+    from plenum_tpu.crypto.ed25519 import Ed25519Signer
+    from plenum_tpu.network import SimNetwork, SimRandom
+    from plenum_tpu.node import Node, NodeBootstrap
+    from plenum_tpu.node.recorder import Recorder, attach_recorder, replay
+    from plenum_tpu.storage.kv_memory import KvMemory
+    from test_pool import NODES as POOL_NODES, make_genesis, signed_nym
+
+    n_writes = 60
+    genesis, trustee = make_genesis(POOL_NODES)
+    timer = MockTimer()
+    net = SimNetwork(timer, SimRandom(11))
+    net.set_latency(0.02, 0.02)
+    config = Config(Max3PCBatchWait=5.0)
+    recorder = Recorder(KvMemory(), now=timer.get_current_time)
+    primary = "Alpha"
+    nodes = {}
+    for name in POOL_NODES:
+        components = NodeBootstrap(name, genesis_txns=genesis).build()
+        tracer = Tracer(name, timer.get_current_time,
+                        wall_durations=False) if name == primary else None
+        nodes[name] = Node(name, timer, net.create_peer(name), components,
+                           config=config, tracer=tracer)
+    assert nodes[primary].replicas[0].data.is_primary
+    attach_recorder(nodes[primary], recorder)
+    net.connect_all()
+
+    for i in range(n_writes + 100):
+        if i < n_writes:
+            user = Ed25519Signer(seed=(b"trickle-%d" % i).ljust(32, b"\0"))
+            req = signed_nym(trustee, user, i + 1)
+            for node in nodes.values():
+                node.handle_client_message(req.to_dict(), "cli")
+        for node in nodes.values():
+            node.prod()
+        timer.advance(0.01)
+
+    ledgers = [n.c.db.get_ledger(DOMAIN_LEDGER_ID) for n in nodes.values()]
+    assert {lg.size for lg in ledgers} == {1 + n_writes}
+    assert len({lg.root_hash for lg in ledgers}) == 1
+    master = nodes[primary].replicas[0]
+    cuts = master.ordering.cuts
+    assert cuts["timeout"] == 0 and cuts["full"] == 0, cuts
+    assert cuts["idle"] == master.data.pp_seq_no
+    assert 3 <= n_writes / cuts["idle"], \
+        f"{cuts['idle']} batches for {n_writes} writes: not self-clocked"
+    assert nodes[primary].validator_info()[
+        "batch_controller"]["cuts"] == cuts
+    assert nodes[primary].metrics.summary()[
+        MetricsName.BATCH_CUT_IDLE]["max"] == cuts["idle"]
+
+    live = span_sequence(nodes[primary].tracer.snapshot())
+    first_ts = next(ts for ts, *_ in recorder.iter_records())
+    timer2 = MockTimer(start=first_ts)
+    tracer2 = Tracer(primary, timer2.get_current_time, wall_durations=False)
+    node2 = Node(primary, timer2,
+                 ExternalBus(send_handler=lambda msg, dst: None),
+                 NodeBootstrap(primary, genesis_txns=genesis).build(),
+                 config=config, tracer=tracer2)
+    replay(recorder.iter_records(), node2, timer2)
+    assert node2.replicas[0].ordering.cuts == cuts
+    assert span_sequence(tracer2.snapshot()) == live

@@ -203,7 +203,7 @@ def test_ingress_front_door_routes_across_shards():
     for _ in range(60):
         ing.service()
         fab.run(0.2)
-        if fab.shards[1].ordered_count() >= 1:
+        if fab.shards[1].domain_sizes() == {2}:  # on EVERY node of the shard
             break
     assert fab.shards[1].domain_sizes() == {2}   # ordered where it belongs
     assert fab.shards[0].domain_sizes() == {1}   # entry shard untouched

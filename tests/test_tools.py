@@ -281,6 +281,29 @@ def test_metrics_report_commit_stage_percentiles(tmp_path):
     assert summary["pairings_total"] == 200
     assert summary["plane_dispatches"] == 7
     assert summary["sig_batch_size_mean"] == 512.0
+    assert "batch_cuts" not in summary     # no cut recorded, no section
+
+
+def test_metrics_report_batch_cut_reasons(tmp_path):
+    """The master primary's cut-reason counts are cumulative gauges, one
+    event per cut: the report reads each back as its latest (max) value."""
+    from plenum_tpu.common.metrics import KvMetricsCollector
+    from plenum_tpu.consensus.batch_controller import CUT_METRICS
+    from plenum_tpu.storage.kv_file import KvFile
+    from plenum_tpu.tools.metrics_report import report_node
+
+    mdir = tmp_path / "Node1" / "metrics"
+    m = KvMetricsCollector(KvFile(str(mdir)), now=lambda: 1000.0)
+    for n in range(1, 41):
+        m.add_event(CUT_METRICS["idle"], n)
+    m.add_event(CUT_METRICS["timeout"], 1)
+    m.flush()
+    for n in range(41, 46):
+        m.add_event(CUT_METRICS["idle"], n)
+    m.flush()
+    _, summary = report_node(str(mdir), last_s=None)
+    assert summary["batch_cuts"] == {"full": 0, "idle": 45, "timeout": 1,
+                                     "forced": 0}
 
 
 def test_distinct_signers_config_orders_owner_writes():
