@@ -72,8 +72,8 @@ class ConsProofService:
         self._backoff.reset()
         self.rounds = 0
         self._broadcast_status()
-        # re-broadcast until a quorum forms (ref ConsistencyProofsTimeout
-        # re-request): lost replies or peers that were themselves mid-sync
+        # re-broadcast until a quorum forms (the reference re-requests
+        # consistency proofs): lost replies or peers that were mid-sync
         # when we asked must not stall this catchup forever — the leecher
         # has no other wakeup (found by the partition-heal fuzz: a second
         # catchup whose one-shot LedgerStatus went unanswered hung the

@@ -86,8 +86,8 @@ class MetricsName:
     READ_ANCHOR_UPDATES = "read_plane.anchor_updates"
     # per-kind envelope byte sizes (sampled -> p50/p95 in the report):
     # proof bytes are the product WAN clients download, so the
-    # bytes-per-verified-read A/B (bench config13) reads production
-    # counters, not a bench-only tally. Single-key and multi-key
+    # bytes per verified read come from production counters, not a
+    # bench-only tally. Single-key and multi-key
     # envelopes sample SEPARATE names per kind — mixing a 16-key page
     # into the single-read distribution would make its p95 describe
     # nothing a client actually downloads per read

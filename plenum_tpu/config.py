@@ -66,13 +66,8 @@ class Config:
     CHK_FREQ: int = 100
     LOG_SIZE: int = 300
 
-    # --- client timeouts (ref config.py:278-279) ---
-    CLIENT_REQACK_TIMEOUT: float = 5.0
-    CLIENT_REPLY_TIMEOUT: float = 15.0
-
     # --- monitor / RBFT degradation (ref config.py:140-154) ---
     DELTA: float = 0.1                  # master throughput ratio floor
-    LAMBDA: float = 240.0               # window for degradation checks
     OMEGA: float = 20.0                 # latency excess threshold
     PerfCheckFreq: float = 10.0
 
@@ -82,7 +77,6 @@ class Config:
     NOTIFIER_SPIKE_BOUNDS_COEFF: float = 10.0
     NOTIFIER_SPIKE_MIN_CNT: int = 15
     NOTIFIER_SPIKE_MIN_ACTIVITY: float = 10.0
-    throughput_averaging_strategy: str = "ema"
     throughput_first_ts_window: float = 15.0
 
     # --- receive quotas (ref config.py:250-251) ---
@@ -97,7 +91,6 @@ class Config:
     GC_SERVER_TUNING: bool = True
 
     # --- view change (ref config.py:294-295) ---
-    VIEW_CHANGE_TIMEOUT: float = 60.0
     NEW_VIEW_TIMEOUT: float = 30.0
     INSTANCE_CHANGE_TIMEOUT: float = 120.0
 
@@ -132,9 +125,6 @@ class Config:
     # checkpoint-lag signal, and its lone IC vote can't reach quorum)
     STUCK_BEHIND_CHECK_FREQ: float = 5.0
     BACKUP_INSTANCE_FAULTY_TIMEOUT: float = 60.0
-
-    # --- catchup (ref config.py:297) ---
-    CATCHUP_BATCH_SIZE: int = 5
 
     # --- WAN-degraded retry/timeout hardening (common/backoff.py;
     #     docs/robustness.md "Degraded WAN and membership churn") ---
@@ -317,11 +307,8 @@ class Config:
 
     # --- blacklisting (TTL: self-isolation must heal; see blacklister.py) ---
     BLACKLIST_TTL: float = 120.0
-    CatchupTransactionsTimeout: float = 6.0
-    ConsistencyProofsTimeout: float = 5.0
 
     # --- propagation ---
-    PROPAGATE_REQUEST_DELAY: float = 0.0
     # digest-gossip: at most ONE node (digest-designated) broadcasts the
     # full request body; every other propagate is a ~100-byte digest vote,
     # with on-demand body fetch through MessageReq. False restores the
@@ -382,20 +369,16 @@ class Config:
 
     # --- crypto backend seam: 'cpu' or 'jax' (the north star switch) ---
     crypto_backend: str = "cpu"
-    # Pad/flush knobs of the device batch plane (plenum_tpu/crypto/batch_plane.py)
-    CRYPTO_BATCH_MAX: int = 4096
-    CRYPTO_BATCH_PAD_POW2: bool = True
 
     # --- fused crypto pipeline (parallel/pipeline.py) ---
     # One submission ring coalescing Ed25519 client-auth, BLS batch
     # checks, and Merkle hashing across consensus stages AND co-hosted
-    # nodes, with double-buffered device dispatch. False keeps every call
-    # site on its per-call dispatch path (the construction seam returns
-    # None; the disabled cost is one `is None` check at wiring time).
-    # Device backends (jax / jax-sharded) construct it by default; the
-    # plain cpu backend never does — the ring's coalescing pays for a
-    # device round trip, not for a host loop.
-    CRYPTO_PIPELINE: bool = True
+    # nodes, with double-buffered device dispatch. A process whose
+    # backend is a device it owns (`start_node --backend jax`,
+    # `local_pool.build_pool(n, "jax")`) always builds it through
+    # `make_crypto_pipeline`; the cpu and service backends never do —
+    # the ring's coalescing pays for a device round trip, not for a
+    # host loop.
     # pinned pad-bucket ladder (pow2 steps): every ed25519 wave pads to a
     # bucket in [MIN, MAX] so steady state never meets a novel XLA shape
     PIPELINE_MIN_BUCKET: int = 64
@@ -471,7 +454,6 @@ class Config:
 
     # --- misc ---
     ACCEPTABLE_DEVIATION_PREPREPARE_SECS: float = 600.0
-    TRACK_UNORDERED: bool = True
     OUTDATED_REQS_CHECK_INTERVAL: float = 60.0
 
     def replace(self, **overrides) -> "Config":

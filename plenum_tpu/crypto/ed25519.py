@@ -14,7 +14,6 @@ the device.
 from __future__ import annotations
 
 import hashlib
-import time
 from abc import ABC, abstractmethod
 from concurrent.futures import ThreadPoolExecutor
 from typing import Iterable, Optional, Sequence
@@ -22,7 +21,6 @@ from typing import Iterable, Optional, Sequence
 import jax
 import numpy as np
 
-from plenum_tpu.common.metrics import MetricsName
 from plenum_tpu.utils.base58 import b58encode
 
 try:
@@ -581,167 +579,6 @@ class JaxEd25519Verifier(Ed25519Verifier):
         for j, i in enumerate(token.idxs):
             verdict[i] = bool(ok[j])
         return verdict
-
-    def verify_batch(self, items: Sequence[VerifyItem]) -> np.ndarray:
-        return self.collect_batch(self.submit_batch(items), wait=True)
-
-
-# the coalescing plane's verdict cache is SEPARATE from _CPU_VERDICTS so
-# cpu-vs-device differential tests never settle a device query from a
-# cpu-computed verdict
-_PLANE_VERDICTS: dict[bytes, bool] = {}
-_PLANE_VERDICTS_MAX = 65536
-
-
-class CoalescingVerifier(Ed25519Verifier):
-    """Process-wide crypto plane for CO-HOSTED nodes: coalesces the
-    signature batches of every node sharing this host's device into ONE
-    kernel dispatch per flush.
-
-    TPU-first rationale (SURVEY.md §2.3): the verify kernel is serial-depth
-    bound, so its cost is nearly flat in batch size — four nodes dispatching
-    128-item batches pay 4x the wall-clock of one 512-item dispatch. In a
-    production pool each node runs on its own host and owns its device, but
-    a multi-replica host (or the 4-nodes-1-chip bench topology) should share
-    one plane, exactly like co-located RBFT instances share one device
-    program. Each node still verifies independently — only the DISPATCH is
-    shared; verdict spans map back per submitter.
-
-    Protocol: submit_batch stages items and returns a queued token;
-    the next collect_batch (or flush()) with the device idle dispatches
-    everything staged. One dispatch in flight at a time — while busy, new
-    submissions stage for the next flush (natural backpressure, same as
-    the per-node pipeline).
-    """
-
-    class _Token:
-        __slots__ = ("items", "verdicts", "inner")
-
-        def __init__(self, items):
-            self.items = items
-            self.verdicts = None    # np.ndarray once resolved
-            # per-item plan set by flush(): ("k", verdict, None) for a
-            # cache/malformed verdict, ("d", dispatch_idx, key) for an
-            # item riding the device dispatch
-            self.inner = None
-
-    def __init__(self, inner: "JaxEd25519Verifier"):
-        self._inner = inner
-        self._staged: list[CoalescingVerifier._Token] = []
-        self._in_flight: Optional[tuple] = None   # (tok, [tokens], t_disp)
-        # perf observability (VERDICT r2 item 9): the node that most
-        # recently attached its collector reports the plane's stats —
-        # fill latency, dispatch wall time, batch size
-        self.metrics = None
-        self._first_staged_at: Optional[float] = None
-
-    def preload(self, waves) -> list:
-        return self._inner.preload(waves)
-
-    def flush(self) -> bool:
-        """Dispatch everything staged if the device is idle. -> dispatched?
-
-        Content dedup before the device: co-hosted nodes stage the SAME
-        client signatures (one copy per node), so each unique triple is
-        dispatched once per flush and verdicts are remembered across
-        flushes in a process-wide cache — identical semantics (a verdict
-        is a pure function of content), n× less device work."""
-        if self._in_flight is not None or not self._staged:
-            return False
-        batch = self._staged
-        self._staged = []
-        items: list[VerifyItem] = []
-        todo: dict[bytes, int] = {}          # key -> dispatch index
-        for tok in batch:
-            entries = []
-            for it in tok.items:
-                try:
-                    m, s, v = bytes(it[0]), bytes(it[1]), bytes(it[2])
-                except Exception:
-                    entries.append(("k", False, None))   # malformed: False
-                    continue
-                key = content_digest(m, s, v)
-                hit = _PLANE_VERDICTS.get(key)
-                if hit is not None:
-                    entries.append(("k", hit, None))
-                elif key in todo:
-                    entries.append(("d", todo[key], key))
-                else:
-                    todo[key] = len(items)
-                    entries.append(("d", len(items), key))
-                    items.append((m, s, v))
-            tok.inner = entries
-        now = time.perf_counter()
-        first_staged_at = self._first_staged_at
-        self._first_staged_at = None
-        if not items:
-            # everything rode the cache: resolve now, nothing in flight,
-            # and no batch-size/fill events — those track real dispatches
-            for tok in batch:
-                tok.verdicts = np.array(
-                    [e[1] for e in tok.inner], dtype=bool)
-            return False
-        if self.metrics is not None:
-            self.metrics.add_event(MetricsName.SIG_BATCH_SIZE, len(items))
-            if first_staged_at is not None:
-                self.metrics.add_event(MetricsName.SIG_BATCH_FILL_TIME,
-                                       now - first_staged_at)
-        inner_tok = self._inner.submit_batch(items)
-        self._in_flight = (inner_tok, batch, now)
-        return True
-
-    def _resolve_in_flight(self, wait: bool) -> bool:
-        if self._in_flight is None:
-            return True
-        inner_tok, batch, t_disp = self._in_flight
-        ok = self._inner.collect_batch(inner_tok, wait=wait)
-        if ok is None:
-            return False
-        if self.metrics is not None:
-            self.metrics.add_event(MetricsName.SIG_DISPATCH_TIME,
-                                   time.perf_counter() - t_disp)
-        filled: set = set()
-        for tok in batch:
-            verdicts = np.zeros(len(tok.inner), dtype=bool)
-            for i, (kind, val, key) in enumerate(tok.inner):
-                if kind == "k":
-                    verdicts[i] = val
-                else:
-                    verdicts[i] = bool(ok[val])
-                    if key is not None and key not in filled:
-                        filled.add(key)
-                        verdict_cache_put(_PLANE_VERDICTS,
-                                          _PLANE_VERDICTS_MAX, key,
-                                          bool(ok[val]))
-            tok.verdicts = verdicts
-        self._in_flight = None
-        return True
-
-    def submit_batch(self, items: Sequence[VerifyItem]):
-        tok = CoalescingVerifier._Token(list(items))
-        if not self._staged:
-            self._first_staged_at = time.perf_counter()
-        self._staged.append(tok)
-        return tok
-
-    def collect_batch(self, token, wait: bool = True) -> Optional[np.ndarray]:
-        while token.verdicts is None:
-            if self._in_flight is not None:
-                # resolve whatever is flying (ours or an earlier flush);
-                # a not-ready async dispatch surfaces as None to the poller
-                if not self._resolve_in_flight(wait):
-                    return None
-            elif wait:
-                # blocking collect must make progress: flush the stage
-                # (our token included) and resolve it
-                self.flush()
-            else:
-                # non-blocking poll of a still-staged token does NOT flush —
-                # coalescing depends on every co-hosted node staging its
-                # cycle's batch before the shared flush() fires (a node's
-                # pipelined submit+poll would otherwise dispatch solo)
-                return None
-        return token.verdicts
 
     def verify_batch(self, items: Sequence[VerifyItem]) -> np.ndarray:
         return self.collect_batch(self.submit_batch(items), wait=True)

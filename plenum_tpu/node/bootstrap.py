@@ -97,8 +97,8 @@ class NodeBootstrap:
         # shapes recompile, which costs minutes per shape
         self.verifier_min_batch = verifier_min_batch
         # explicit verifier override: co-hosted nodes pass ONE shared
-        # CoalescingVerifier so their dispatches ride a single device
-        # program per cycle (crypto/ed25519.py CoalescingVerifier)
+        # verifier (the ring's `verifier()` view, or the SPMD plane's
+        # sharded verifier) so their dispatches share a device plane
         self.verifier = verifier
         # fused crypto pipeline (parallel/pipeline.py): when given, the
         # authenticator, every ledger's tree hasher, and the BLS batch

@@ -614,7 +614,7 @@ class Node:
         self.metrics.add_event(MetricsName.BLS_PAIRINGS_NATIVE,
                                PAIRING_STATS["native"])
         # ShardedJaxEd25519Verifier.dispatches, possibly wrapped by the
-        # CoalescingVerifier (walk one level of ._inner)
+        # plane supervisor (walk one level of ._inner)
         verifier = getattr(self.c.authenticator.core_authenticator,
                            "verifier", None)
         for obj in (verifier, getattr(verifier, "_inner", None)):

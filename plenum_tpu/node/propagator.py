@@ -8,7 +8,7 @@ means same digest from distinct senders; a node's own propagate counts.
 
 Redesign (digest-gossip): the reference floods the FULL request body
 n*(n-1) times per transaction — the measured dominant wire cost past small
-pools (docs/performance.md 7-node table: 87% of bytes). Here at most ONE
+pools (docs/performance.md "7-node scaling"). Here at most ONE
 node broadcasts the body: the digest-DESIGNATED disseminator (derived from
 the request digest over the sorted validator list, so every node picks the
 same one with no coordination; clients broadcast to the whole pool, so

@@ -149,9 +149,9 @@ class ShardedJaxEd25519Verifier(JaxEd25519Verifier):
     identical host staging (decompression cache, scalar windows, padding),
     but the dispatch shards the signature grid over the plane's mesh, so
     every pool node's traffic runs as a multi-chip program. This is the
-    production seam for `crypto_backend="jax-sharded"` — the
-    CoalescingVerifier wraps it unchanged and node traffic flows through
-    `ShardedCryptoPlane.step` (SURVEY.md §2.3 distributed-comm row)."""
+    production seam for `crypto_backend="jax-sharded"`: node traffic
+    flows through `ShardedCryptoPlane.step` (SURVEY.md §2.3
+    distributed-comm row)."""
 
     def __init__(self, plane: ShardedCryptoPlane, min_batch: int = 1,
                  cache_size: int = 65536):

@@ -14,8 +14,7 @@ Design: an asyncio unix-socket server fronting a single inner
 `Ed25519Verifier` (cpu | jax | jax-sharded via the existing factory
 seam). A worker thread drains a queue of client batches: everything
 that arrives while the previous device dispatch runs is coalesced into
-the next one — the cross-process generalization of CoalescingVerifier
-(crypto/ed25519.py), with the same natural backpressure. Verdicts are
+the next one, which is also the backpressure. Verdicts are
 cached by content digest (bounded FIFO), so a request already verified
 for node A is free for nodes B..N.
 

@@ -1979,17 +1979,15 @@ def make_crypto_pipeline(config, backend: str,
     device past `PIPELINE_SHA_MIN_BATCH`, and a PIPELINE_MAX_BUCKET of
     at least `staged_bucket(config, submitters)`.
 
-    Config-gated: `CRYPTO_PIPELINE=False` (or a non-device backend) ->
+    A backend that is not a device this process owns (cpu, service) ->
     None, and every consumer keeps its per-call dispatch path — the
-    disabled cost is one `is None` check at wiring time (pinned by the
+    cost there is one `is None` check at wiring time (pinned by the
     microbenchmark in tests/test_pipeline.py).
 
     `n_devices` (default: config.PIPELINE_DEVICES) selects the scale-out
     shape: 1 -> the single-ring PR 8 pipeline EXACTLY (no lane
     indirection on the hot path); >1 -> per-chip lanes with independent
     breakers; 0 -> every local device."""
-    if not getattr(config, "CRYPTO_PIPELINE", True):
-        return None
     if backend not in ("jax", "jax-sharded") and ed_inner is None:
         return None
     config = config.replace(PIPELINE_MAX_BUCKET=max(

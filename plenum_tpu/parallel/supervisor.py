@@ -254,8 +254,8 @@ def _item_bytes(items: Sequence[VerifyItem]) -> int:
 class SupervisedVerifier(Ed25519Verifier):
     """Breaker + adaptive-deadline + hedged-fallback wrapper around a
     device-backed verifier. Implements the same submit/collect token
-    protocol, so node pipelining and the CoalescingVerifier work
-    unchanged on top of it. "Device" includes REMOTE backends: the
+    protocol, so node pipelining and the ring work unchanged on top of
+    it. "Device" includes REMOTE backends: the
     federated pipeline (parallel/federation.py) wraps each rostered
     crypto host's service client in its own supervisor, so a dead host
     opens exactly that lane's breaker and the probe's `rewarm()` hook —
@@ -633,9 +633,8 @@ def supervise(device: Ed25519Verifier, **kwargs) -> SupervisedVerifier:
 
 
 def find_supervisor(verifier) -> Optional[SupervisedVerifier]:
-    """Locate the SupervisedVerifier inside a wrapped chain (e.g.
-    CoalescingVerifier -> SupervisedVerifier -> device); used by the
-    node's metric sampler."""
+    """Locate the SupervisedVerifier inside a chain of wrappers that
+    each hold the next as `_inner`; used by the node's metric sampler."""
     seen = 0
     obj = verifier
     while obj is not None and seen < 4:

@@ -288,8 +288,8 @@ class ReadPlane:
 
     def _note_proof_bytes(self, env: dict) -> None:
         """Per-kind envelope byte size, sampled into the node metrics —
-        the production counter the bytes-per-verified-read A/B reads
-        (bench config13), instead of a bench-only tally. Measured at
+        the production counter a bytes-per-verified-read comparison
+        reads, instead of a bench-only tally. Measured at
         build time (before the result_digest lands: a ~70-byte constant
         across kinds, so the comparison is unaffected). Sampled 1-in-8
         per kind (first envelope always): the measurement is a full
@@ -379,8 +379,8 @@ class ReadPlane:
     def page_envelope(self, ledger_id: int,
                       keys: Sequence[bytes]) -> Optional[dict]:
         """ONE envelope answering a whole client page of state keys at
-        the ledger's anchored root — the multi-key carrier bench
-        config13 measures and tests drive (no wire query names a page
+        the ledger's anchored root — the multi-key carrier tests
+        drive (no wire query names a page
         yet; per-request envelopes remain the transport surface).
 
         Verkle-backed ledgers aggregate the page into one opening;

@@ -473,7 +473,7 @@ class ShardedSimFabric:
 
     def run(self, seconds: float = 5.0, step: float = 0.1) -> None:
         """Sim-time drive (MockTimer). Real-time timers should loop
-        `prod_all` against the wall clock instead (bench_configs)."""
+        `prod_all` against the wall clock instead."""
         elapsed = 0.0
         while elapsed < seconds:
             self.reshard.service()

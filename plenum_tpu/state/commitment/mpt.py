@@ -9,7 +9,7 @@ stays where every existing import expects it).
 
 MPT has no aggregation: a page's batch proof is simply the list of
 per-key sibling chains, each independently verifiable. That is the
-honest baseline the Verkle A/B (config13) measures against — the
+honest baseline a Verkle page is compared against — the
 interface intentionally does NOT pretend MPT pages are cheaper than
 k singles.
 """

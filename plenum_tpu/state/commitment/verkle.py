@@ -25,7 +25,7 @@ Proofs: a key's path is the chain of node commitments plus ONE opening
 per level (slot -> child scalar). `batch_open` aggregates EVERY opening
 of a whole key page into one (D, pi) pair (kzg.prove_multi), so a
 16-key page costs the page's distinct path commitments + 128 bytes of
-opening proof — the bytes-per-verified-read win config13 measures.
+opening proof: the bytes-per-verified-read win over MPT chains.
 Absence is proven fail-closed: an empty slot opens to 0; a slot held by
 a DIFFERENT key's leaf opens to that leaf's scalar, and the proof
 reveals (other_stem, other_value_hash) so the verifier can check the

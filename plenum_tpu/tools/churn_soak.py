@@ -123,7 +123,7 @@ def run_churn_soak(seconds: float = 600.0, seed: int = 11,
                     PRIMARY_HEALTH_CHECK_FREQ=0.5,
                     ORDERING_PROGRESS_TIMEOUT=2.0,
                     STATE_FRESHNESS_UPDATE_INTERVAL=3.0,
-                    VIEW_CHANGE_TIMEOUT=8.0, NEW_VIEW_TIMEOUT=4.0,
+                    NEW_VIEW_TIMEOUT=4.0,
                     OUTDATED_REQS_CHECK_INTERVAL=5.0,
                     EXECUTED_REQ_RETENTION=10.0,
                     PROPAGATE_BODYLESS_REQ_TIMEOUT=10.0)

@@ -78,6 +78,22 @@ def build_view(paths, config=None):
     return agg, incidents
 
 
+SPARK_TICKS = "▁▂▃▄▅▆▇█"
+
+
+def sparkline(values: list[float], width: int = 24) -> str:
+    if not values:
+        return ""
+    values = values[-width:]
+    lo, hi = min(values), max(values)
+    if hi <= lo:
+        return SPARK_TICKS[0] * len(values)
+    return "".join(
+        SPARK_TICKS[min(len(SPARK_TICKS) - 1,
+                        int((v - lo) / (hi - lo) * len(SPARK_TICKS)))]
+        for v in values)
+
+
 def render(agg, incidents, last_n: int = 5) -> str:
     from plenum_tpu.observability.correlate import format_incidents
     s = agg.fleet_summary()
@@ -189,7 +205,6 @@ def render(agg, incidents, last_n: int = 5) -> str:
     # verdicts behind the unbounded_growth alert
     hist = getattr(agg, "history", None)
     if hist is not None and getattr(hist, "rows", None):
-        from plenum_tpu.tools.perf_sentinel import sparkline
         rows = hist.query(max_points=24)
         tps = [float(r.get("tps", 0.0)) for r in rows]
         hmin = [float(r["health_min"]) for r in rows

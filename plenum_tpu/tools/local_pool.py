@@ -81,7 +81,7 @@ class Pool(NamedTuple):
 
     @property
     def pipeline(self):
-        """The shared CryptoPipeline ring, or None (cpu / legacy plane)."""
+        """The shared CryptoPipeline ring, or None (cpu backend)."""
         return getattr(self.plane, "_pipeline", None)
 
     def prod_all(self) -> None:
@@ -93,8 +93,16 @@ class Pool(NamedTuple):
             self.plane.flush()
 
 
+BACKENDS = ("cpu", "jax")
+
+
 def build_pool(n_nodes: int, backend: str, seed: int = 1,
                trace: bool = False, config_overrides: dict = None) -> Pool:
+    if backend not in BACKENDS:
+        # anything else would fall through make_verifier to the CPU
+        # verifier and label the run with the string it was given
+        raise ValueError(f"backend {backend!r}: build_pool builds "
+                         f"{', '.join(BACKENDS)}")
     from plenum_tpu.common.node_messages import DOMAIN_LEDGER_ID, Reply
     from plenum_tpu.common.timer import QueueTimer
     from plenum_tpu.common.tracing import Tracer
@@ -118,49 +126,22 @@ def build_pool(n_nodes: int, backend: str, seed: int = 1,
     nodes = {}
     # co-hosted nodes share ONE crypto plane: the verify kernel is
     # serial-depth bound, so n_nodes small dispatches per cycle cost
-    # ~n_nodes times one combined dispatch. With CRYPTO_PIPELINE (the
-    # default) that plane is the fused pipeline ring — client-auth
-    # Ed25519, BLS batch checks, AND Merkle hashing all coalesce/dedup
-    # across the co-hosted nodes; otherwise the legacy Ed25519-only
-    # CoalescingVerifier.
-    plane = None
-    pipeline = None
-    if backend == "jax-percall":
-        # A/B baseline arm (bench_configs.config8_pipeline_ab): every node
-        # runs its own supervised device verifier and every call site's
-        # batch dispatches ALONE — the pre-pipeline per-call behavior the
-        # coalescing win is measured against
-        config = config.replace(crypto_backend="jax",
-                                CRYPTO_PIPELINE=False)
-        backend = "jax"
-    elif backend == "jax":
-        from plenum_tpu.crypto.ed25519 import (CoalescingVerifier,
-                                               JaxEd25519Verifier)
-        # supervised either way, as in production: a device wedge mid-run
-        # degrades the pool to CPU-speed verdicts (breaker + hedged
-        # fallback) instead of stalling it — and run_load reports it as
-        # backend_state != "ok", never as a healthy device run
-        if config.CRYPTO_PIPELINE:
-            # the ring a validator that owns its chip builds
-            # (tools/start_node.py), sized for n_nodes submitters; the
-            # PIPELINE_DEVICES / PIPELINE_REMOTE_HOSTS knobs select the
-            # multi-chip and federated rings inside the same seam
-            from plenum_tpu.parallel.pipeline import make_crypto_pipeline
-            pipeline = make_crypto_pipeline(config, "jax",
-                                            submitters=n_nodes)
-            plane = pipeline.verifier()
-        else:
-            # no ring: ONE shape covering the coalesced steady state (a
-            # second shape would mean a second multi-minute compile)
-            from plenum_tpu.parallel.pipeline import staged_bucket
-            from plenum_tpu.parallel.supervisor import supervise
-            plane = CoalescingVerifier(supervise(JaxEd25519Verifier(
-                min_batch=staged_bucket(config, n_nodes))))
+    # ~n_nodes times one combined dispatch. On a device backend that
+    # plane is the ring a validator that owns its chip builds
+    # (tools/start_node.py), sized for n_nodes submitters: client-auth
+    # Ed25519, BLS batch checks AND Merkle hashing coalesce/dedup across
+    # the co-hosted nodes, supervised as in production (a device wedge
+    # mid-run degrades the pool to CPU-speed verdicts and run_load
+    # reports backend_state != "ok", never a healthy device run). The
+    # PIPELINE_DEVICES / PIPELINE_REMOTE_HOSTS knobs select the
+    # multi-chip and federated rings inside the same seam. cpu: None.
+    from plenum_tpu.parallel.pipeline import make_crypto_pipeline
+    pipeline = make_crypto_pipeline(config, backend, submitters=n_nodes)
+    plane = pipeline.verifier() if pipeline is not None else None
     for name in names:
         bus = net.create_peer(name)
         components = NodeBootstrap(
             name, genesis_txns=genesis, crypto_backend=backend,
-            verifier=None if pipeline is not None else plane,
             pipeline=pipeline,
             state_commitment=config.STATE_COMMITMENT,
             state_commitment_per_ledger=config.STATE_COMMITMENT_PER_LEDGER,
@@ -249,9 +230,7 @@ def drive(pool: Pool, requests: Sequence, window: int = 256,
     """Feed pre-signed requests to every node with at most `window` in
     flight until each has its REPLY from the first node (or `timeout`).
     -> (first_reply {digest: t}, submit_times {digest: t}, seconds).
-    256 floods the pipeline (the headline shape); small windows trickle
-    config7-style per-tick batches (the pipeline A/B's coalescing
-    measurement)."""
+    256 floods the pipeline; small windows trickle per-tick batches."""
     names, nodes = pool.names, pool.nodes
     sink = pool.replies[names[0]]
     submit_times: dict[str, float] = {}
@@ -304,16 +283,11 @@ def pool_roots(pool: Pool) -> dict:
 
 
 def plane_supervisors(plane) -> list:
-    """Every SupervisedVerifier behind a pool's crypto plane: one per
-    chip lane for a multi-device ring, else the single ring's/plane's."""
-    from plenum_tpu.parallel.supervisor import find_supervisor
-    if plane is None:
-        return []
+    """Every SupervisedVerifier behind a pool's crypto plane (a ring's
+    verifier view, or None): one per chip lane for a multi-device ring,
+    the single ring's otherwise."""
     pipe = getattr(plane, "_pipeline", None)
-    if pipe is not None:
-        return pipe.supervisors()
-    sup = find_supervisor(plane)
-    return [sup] if sup is not None else []
+    return pipe.supervisors() if pipe is not None else []
 
 
 def plane_report(plane, at_pin: Optional[list] = None) -> dict:
@@ -413,22 +387,6 @@ def run_load(n_nodes: int = 4, n_txns: int = 200, backend: str = "cpu",
                 percentile(ratios, 0.5), 4)
     pipe = pool.pipeline
     pipeline_summary = pipe.summary() if pipe is not None else None
-    percall = None
-    if backend == "jax-percall":
-        # baseline arm: per-call dispatch accounting straight from each
-        # node's supervised verifier (device_items are REAL items — the
-        # inner pads after the supervisor counts)
-        from plenum_tpu.parallel.supervisor import find_supervisor
-        tb = ti = 0
-        for n in names:
-            v = getattr(nodes[n].c.authenticator.core_authenticator,
-                        "verifier", None)
-            sup = find_supervisor(v)
-            if sup is not None:
-                tb += sup.stats["device_batches"]
-                ti += sup.stats["device_items"]
-        percall = {"device_batches": tb, "device_items": ti,
-                   "items_per_dispatch": round(ti / tb, 2) if tb else 0.0}
     # controller trajectory from the master PRIMARY (Node1 under the
     # round-robin selector): final knob positions + the rolling per-stage
     # p50/p95 vs the SLO that put them there — the bench line's view of
@@ -437,7 +395,6 @@ def run_load(n_nodes: int = 4, n_txns: int = 200, backend: str = "cpu",
     return {
         **({"trace": trace_summary} if trace_summary else {}),
         **({"pipeline": pipeline_summary} if pipeline_summary else {}),
-        **({"percall": percall} if percall else {}),
         **({"controller": ctl.trajectory()} if ctl is not None else {}),
         **({"commit_stage": stage} if stage else {}),
         **plane_report(pool.plane, at_pin=warm["supervisors"]),
@@ -459,14 +416,13 @@ def run_load(n_nodes: int = 4, n_txns: int = 200, backend: str = "cpu",
     }
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", type=int, default=4)
     ap.add_argument("--txns", type=int, default=200)
-    ap.add_argument("--backend", default="cpu",
-                    choices=["cpu", "jax", "jax-percall"])
+    ap.add_argument("--backend", default="cpu", choices=BACKENDS)
     ap.add_argument("--json", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     stats = run_load(args.nodes, args.txns, args.backend)
     if args.json:
         print(json.dumps(stats))

@@ -450,7 +450,7 @@ def derive_summary(folds: dict[str, dict], span_s: float,
         elif gen.get("mean") is not None:
             section["proof_gen_ms_mean"] = _ms(gen["mean"])
         # per-kind envelope bytes: what a verified read costs the client
-        # to download — the bytes-per-read A/B (config13) reads THESE
+        # to download (an MPT-vs-Verkle comparison reads THESE)
         for kind in ("state", "state_multi", "merkle", "verkle",
                      "verkle_multi"):
             pb = folds.get(f"read_plane.proof_bytes_{kind}", {})

@@ -31,7 +31,7 @@ splits exactly for this.
 
 Output: one JSON line with per-category exclusive seconds and per-txn
 milliseconds for the busiest node, plus the offloadable fraction and the
-implied Amdahl ceiling.  docs/performance.md quotes this table.
+implied Amdahl ceiling (docs/performance.md "The Amdahl budget").
 
     python -m plenum_tpu.tools.perf_budget [--nodes 4] [--txns 300]
 """
@@ -220,8 +220,8 @@ def run_differential(n_nodes: int = 4, lo: int = 100, hi: int = 400,
     Caveat recorded in the output: cProfile inflates Python-call-dense
     categories (~2x observed wall slowdown) but not time spent inside a
     single C call, so the crypto fractions below are LOWER bounds; the
-    unprofiled primitive microbenches in docs/performance.md bracket them
-    from the other side.
+    unprofiled seam timers of tools/micro_costs bracket them from the
+    other side.
     """
     a = run_budget(n_nodes, lo, timeout)
     b = run_budget(n_nodes, hi, timeout)

@@ -29,6 +29,34 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 TRUSTEE_SEED = b"tcp-pool-trustee".ljust(32, b"\0")
+# what a child gets between SIGTERM and SIGKILL: a node flushes its
+# metrics tail and closes its ring in well under this
+STOP_GRACE_S = 5.0
+
+
+def _spawn(cmd: list, **kwargs) -> subprocess.Popen:
+    """Every child stays in the launcher's process group and session: a
+    caller that has to end a launcher it cannot ask (chip_smoke's phase
+    deadline, a test runner's timeout) SIGKILLs that one group, and no
+    node or chip-owning service outlives the launcher that started it."""
+    return subprocess.Popen(cmd, cwd=REPO, **kwargs)
+
+
+def stop_processes(procs) -> None:
+    """THE teardown: SIGTERM every live child, give them STOP_GRACE_S
+    between them, SIGKILL each that is still there, and reap every one —
+    nothing is left running and nothing is left a zombie. Entries may be
+    None (a service that was never started)."""
+    live = [p for p in procs if p is not None and p.poll() is None]
+    for p in live:
+        p.send_signal(signal.SIGTERM)
+    deadline = time.monotonic() + STOP_GRACE_S
+    for p in live:
+        try:
+            p.wait(timeout=max(0.0, deadline - time.monotonic()))
+        except subprocess.TimeoutExpired:
+            p.kill()
+            p.wait()
 
 
 def _free_ports(n: int) -> list[int]:
@@ -115,11 +143,11 @@ def _start_crypto_service(inner: str, sock_path: str, min_batch: int,
             return fh.read().decode(errors="replace")[-2000:]
 
     with open(log_path, "wb") as log:
-        proc = subprocess.Popen(
+        proc = _spawn(
             [sys.executable, "-m", "plenum_tpu.parallel.crypto_service",
              "--socket", sock_path, "--backend", inner,
              "--min-batch", str(min_batch)],
-            env=env, cwd=REPO, stdout=log, stderr=subprocess.STDOUT)
+            env=env, stdout=log, stderr=subprocess.STDOUT)
     deadline = time.perf_counter() + 240.0      # backend init included
     while time.perf_counter() < deadline:
         with open(log_path, "rb") as fh:
@@ -131,8 +159,7 @@ def _start_crypto_service(inner: str, sock_path: str, min_batch: int,
             raise RuntimeError("crypto service died during startup: "
                                + tail())
         time.sleep(0.2)
-    proc.kill()
-    proc.wait()
+    stop_processes([proc])
     raise RuntimeError("crypto service never bound its socket: " + tail())
 
 
@@ -218,9 +245,8 @@ def run_tcp_pool(n_nodes: int = 4, n_txns: int = 200, backend: str = "cpu",
             if profile_dir:
                 cmd += ["--profile",
                         os.path.join(profile_dir, f"{name}.pstats")]
-            procs.append(subprocess.Popen(
-                cmd, env=env, cwd=REPO, stdout=subprocess.PIPE,
-                stderr=subprocess.STDOUT))
+            procs.append(_spawn(cmd, env=env, stdout=subprocess.PIPE,
+                                stderr=subprocess.STDOUT))
         _wait_all_started(procs, deadline_s=60.0)
 
         if stages is None:
@@ -272,14 +298,7 @@ def run_tcp_pool(n_nodes: int = 4, n_txns: int = 200, backend: str = "cpu",
         }
         # bytes-on-wire + loss accounting from a node's flushed metrics
         # history (SIGTERM first so the tail flush carries final totals)
-        for p in procs:
-            if p.poll() is None:
-                p.send_signal(signal.SIGTERM)
-        for p in procs:
-            try:
-                p.wait(timeout=5)
-            except subprocess.TimeoutExpired:
-                p.kill()
+        stop_processes(procs)
         try:
             from plenum_tpu.tools.metrics_report import (derive_summary,
                                                          fold_rows,
@@ -327,20 +346,7 @@ def run_tcp_pool(n_nodes: int = 4, n_txns: int = 200, backend: str = "cpu",
             pass                     # byte accounting is best-effort extra
         return result
     finally:
-        for p in procs:
-            if p.poll() is None:
-                p.send_signal(signal.SIGTERM)
-        for p in procs:
-            try:
-                p.wait(timeout=5)
-            except subprocess.TimeoutExpired:
-                p.kill()
-        if service_proc is not None:
-            service_proc.terminate()
-            try:
-                service_proc.wait(timeout=5)
-            except subprocess.TimeoutExpired:
-                service_proc.kill()
+        stop_processes(procs + [service_proc])
         if base_dir is None:
             shutil.rmtree(tmp, ignore_errors=True)
 

@@ -240,48 +240,6 @@ def test_pt_cache_bounded():
     assert len(v._pt_cache) == 4
 
 
-# --- coalescing crypto plane (co-hosted nodes, one dispatch) --------------
-
-def test_coalescing_verifier_merges_batches():
-    from plenum_tpu.crypto.ed25519 import _PLANE_VERDICTS, CoalescingVerifier
-    _PLANE_VERDICTS.clear()   # flush() asserts below depend on a cold cache
-    inner = JaxEd25519Verifier(min_batch=8)
-    plane = CoalescingVerifier(inner)
-    signers = [Ed25519Signer(bytes([i + 1]) * 32) for i in range(3)]
-    batches, expects = [], []
-    for k, s in enumerate(signers):   # three "nodes" stage batches
-        items, expect = [], []
-        for i in range(2 + k):
-            m = b"node%d-msg%d" % (k, i)
-            good = (i + k) % 3 != 0
-            sig = s.sign(m) if good else b"\x01" * 64
-            items.append((m, sig, s.verkey))
-            expect.append(good)
-        batches.append(plane.submit_batch(items))
-        expects.append(expect)
-    # nothing dispatched yet; a flush sends ONE combined dispatch
-    assert plane._in_flight is None
-    assert plane.flush()
-    for tok, expect in zip(batches, expects):
-        got = plane.collect_batch(tok, wait=True)
-        assert list(got) == expect
-    # collect without explicit flush also works (self-dispatching)
-    tok = plane.submit_batch([(b"x", signers[0].sign(b"x"), signers[0].verkey)])
-    assert list(plane.collect_batch(tok, wait=True)) == [True]
-
-
-def test_coalescing_verifier_staged_while_in_flight():
-    from plenum_tpu.crypto.ed25519 import CoalescingVerifier
-    plane = CoalescingVerifier(JaxEd25519Verifier(min_batch=4))
-    s = Ed25519Signer(b"\x21" * 32)
-    t1 = plane.submit_batch([(b"a", s.sign(b"a"), s.verkey)])
-    plane.flush()
-    # second submitter stages while the first dispatch is in flight
-    t2 = plane.submit_batch([(b"b", s.sign(b"b"), s.verkey)])
-    assert list(plane.collect_batch(t1, wait=True)) == [True]
-    assert list(plane.collect_batch(t2, wait=True)) == [True]
-
-
 # --- compressed dispatch: device-side key decompression (round 5) ---------
 
 def test_decompress_kernel_matches_host():

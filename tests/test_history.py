@@ -1,13 +1,11 @@
-"""Fleet history plane: durable time-series ring, growth verdicts,
-footprint accounting, and the perf-regression sentinel.
+"""Fleet history plane: durable time-series ring, growth verdicts and
+footprint accounting.
 
 Covers observability/history.py (HistoryRecorder + GrowthWatch), the
 node's resource-footprint gauges (Node.footprint() -> telemetry
 "footprint" section -> aggregator growth trends), the history ring's
-replay determinism (the telemetry twin of the tracer guard), the
-correlate.py control-ledger + history-context merge, and
-tools/perf_sentinel.py's variance-aware regression gating over a
-BENCH_r*.json trajectory.
+replay determinism (the telemetry twin of the tracer guard), and the
+correlate.py control-ledger + history-context merge.
 """
 import json
 import os
@@ -232,85 +230,3 @@ def test_incident_timeline_merges_control_and_history():
     assert [r["t"] for r in ctx] == [7.0, 8.0, 9.0]
     lines = format_incidents(incidents)
     assert any("walked in from:" in ln for ln in lines)
-
-
-# --- perf sentinel ----------------------------------------------------------
-
-def test_perf_sentinel_self_check():
-    from plenum_tpu.tools import perf_sentinel
-    assert perf_sentinel.self_check() == []
-    assert perf_sentinel.main(["--check"]) == 0
-
-
-# the sentinel-relevant figures of the five pre-PR-1 driver rounds, as
-# fixture rows (the records themselves left the tree together with the
-# remote device attachment they were measured through)
-_ROUNDS = {
-    "r01": {"value": 4827.7},
-    "r02": {"value": 279.0, "cpu_tps": 279.0, "tcp_tps": 230.1},
-    "r03": {"value": 231.0, "cpu_tps": 271.8, "tcp_tps": 231.0,
-            "tcp7_tps": 108.9},
-    "r04": {"value": 370.1, "cpu_tps": 567.2, "tcp_tps": 350.7,
-            "tcpsvc_tps": 370.1, "tcp7_tps": 164.4, "jax_tps": 312.4,
-            "distinct_signers_tps": 461.2,
-            "config3_proof_reads_per_s": 14218.5},
-    "r05": {"value": 515.8, "headline_config": "tcpsvc",
-            "spread": {"min": 404.7, "max": 535.5, "n": 3},
-            "cpu_tps": 705.0,
-            "cpu_spread": {"min": 699.0, "max": 764.7, "n": 3},
-            "tcp_tps": 434.2,
-            "tcp_spread": {"min": 394.2, "max": 437.5, "n": 3},
-            "tcpsvc_tps": 515.8,
-            "tcpsvc_spread": {"min": 404.7, "max": 535.5, "n": 3},
-            "tcp7_tps": 175.7, "distinct_signers_tps": 431.9,
-            "config3_proof_reads_per_s": 10627.2},
-}
-
-
-def test_perf_sentinel_round_history_no_false_regressions(tmp_path):
-    """Over the shape of the driver's r01..r05 history the sentinel must
-    emit ZERO regression verdicts: the r01->r02 headline drop is an
-    honesty switch (in-process -> TCP, different headline_config ->
-    not_comparable) and the r04->r05 reads drop has no spread baseline
-    (-> warn at most)."""
-    from plenum_tpu.tools import perf_sentinel
-    for label, parsed in _ROUNDS.items():
-        (tmp_path / f"BENCH_{label}.json").write_text(
-            json.dumps({"parsed": parsed}))
-    rep = perf_sentinel.report(bench_dir=str(tmp_path))
-    assert len(rep["rows"]) == 5
-    assert rep["regressions"] == [], rep["regressions"]
-    assert any(v["verdict"] == "not_comparable"
-               for v in rep["verdicts"] if v["config"] == "headline")
-    # r04 carries a device figure and predates provenance tagging: the
-    # lint must say so, and only about that round
-    assert [p for p in rep["lint"] if "device provenance" in p] \
-        and all(p.startswith("r04") for p in rep["lint"]
-                if "device provenance" in p)
-
-
-def test_perf_sentinel_flags_synthetic_regression_and_gates_single_pass():
-    from plenum_tpu.tools.perf_sentinel import verdicts
-    base = {"label": "r1", "configs": {"tcp": {
-        "value": 1000.0, "spread_frac": 0.1}}}
-    cliff = {"label": "r2", "configs": {"tcp": {"value": 500.0}}}
-    vs = verdicts([base, cliff])
-    assert [v["verdict"] for v in vs] == ["regression"]
-    # the same cliff off a single-pass (no spread) baseline caps at warn
-    vs = verdicts([{"label": "r1", "configs": {"tcp": {"value": 1000.0}}},
-                   cliff])
-    assert [v["verdict"] for v in vs] == ["warn"]
-
-
-def test_perf_sentinel_trajectory_append_roundtrip(tmp_path):
-    from plenum_tpu.tools.perf_sentinel import append_trajectory, load_rows
-    path = str(tmp_path / "BENCH_trajectory.jsonl")
-    parsed = {"tcp_tps": 1234.0, "headline": 1234.0,
-              "headline_config": "tcp", "host_cores": 8}
-    row = append_trajectory(parsed, path, label="run-x")
-    assert row["configs"]["tcp"]["value"] == 1234.0
-    rows = load_rows(bench_dir=str(tmp_path), trajectory=path)
-    assert rows[-1]["label"] == "run-x"
-    assert rows[-1]["host_cores"] == 8
-    from plenum_tpu.tools.perf_sentinel import lint_provenance
-    assert lint_provenance([rows[-1]]) == []

@@ -274,22 +274,20 @@ def test_pool_jax_backend_end_to_end():
 
 def test_pool_sharded_crypto_plane_end_to_end():
     """REAL node traffic through the multi-chip plane: a 4-node pool shares
-    one CoalescingVerifier whose device program is ShardedCryptoPlane over
-    the suite's 8 virtual CPU devices (2x4 'inst'x'sig' mesh) — the same
+    one verifier whose device program is ShardedCryptoPlane over the
+    suite's 8 virtual CPU devices (2x4 'inst'x'sig' mesh) — the same
     SPMD program dryrun_multichip compiles, now fed by client authN instead
     of synthetic batches (SURVEY.md §2.3 distributed-comm row)."""
-    from plenum_tpu.crypto.ed25519 import CoalescingVerifier
     from plenum_tpu.parallel.crypto_plane import make_sharded_verifier
 
     sharded = make_sharded_verifier(min_batch=8)
-    shared = CoalescingVerifier(sharded)
     pool = Pool(config=Config(Max3PCBatchWait=0.05,
                               crypto_backend="jax-sharded"),
-                verifier=shared)
+                verifier=sharded)
     # every node's authenticator feeds the ONE shared plane
     for n in pool.names:
         assert pool.nodes[n].c.authenticator.core_authenticator.verifier \
-            is shared
+            is sharded
 
     user = Ed25519Signer(seed=b"sharded-user".ljust(32, b"\0"))
     pool.submit(signed_nym(pool.trustee, user, 1))

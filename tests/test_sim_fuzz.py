@@ -28,7 +28,6 @@ FAST = dict(Max3PCBatchWait=0.05,
             PRIMARY_HEALTH_CHECK_FREQ=0.5,
             ORDERING_PROGRESS_TIMEOUT=2.0,
             STATE_FRESHNESS_UPDATE_INTERVAL=3.0,
-            VIEW_CHANGE_TIMEOUT=8.0,
             NEW_VIEW_TIMEOUT=4.0)
 
 N_SEEDS = 100

@@ -211,6 +211,30 @@ def test_config_layering():
     assert cfg2.DELTA == 0.5 and cfg.DELTA == 0.1
 
 
+def test_every_config_field_is_read_by_the_program():
+    """A field nothing reads is a knob an operator can set to no effect
+    (`load_config` rejects only names that are not fields). Read = an
+    attribute access (`config.NAME`) or the bare name as a string
+    (`getattr(config, "NAME", ...)`) somewhere in the program; a comment,
+    a docstring or a keyword that only SETS the field is not one."""
+    import ast
+    import dataclasses
+    import pathlib
+    pkg = pathlib.Path(__file__).resolve().parent.parent / "plenum_tpu"
+    read = set()
+    for path in pkg.rglob("*.py"):
+        if path == pkg / "config.py":
+            continue
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Attribute):
+                read.add(node.attr)
+            elif isinstance(node, ast.Constant):
+                read.add(node.value)
+    unread = [f.name for f in dataclasses.fields(Config)
+              if f.name not in read]
+    assert unread == []
+
+
 # --- KV stores ------------------------------------------------------------
 
 @pytest.mark.parametrize("backend", ["memory", "file"])

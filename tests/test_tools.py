@@ -146,13 +146,142 @@ def test_four_process_pool_orders_nym(tmp_path):
         assert dom["size"] == 2            # genesis NYM + the ordered one
         assert replayed["last_ordered_3pc"][1] >= 1
     finally:
-        for p in procs:
-            p.send_signal(signal.SIGTERM)
-        for p in procs:
-            try:
-                p.wait(timeout=5)
-            except subprocess.TimeoutExpired:
-                p.kill()
+        from plenum_tpu.tools.tcp_pool import stop_processes
+        stop_processes(procs)
+
+
+# --- the launcher's teardown (stub children, no pool) -----------------------
+
+# traps SIGTERM and sleeps: only SIGKILL ends it. The line it prints
+# says the handler is installed (and passes for a service's start line).
+_DEAF_CHILD = ("import signal, time; "
+               "signal.signal(signal.SIGTERM, lambda *a: None); "
+               "print('{\"crypto_service\": {}, \"started\": 1}', "
+               "flush=True); time.sleep(600)")
+# says nothing and dies of SIGTERM, as a node that has not started yet
+_MUTE_CHILD = "import time; time.sleep(600)"
+# exits, with a last word, once the file named exists
+_DYING_CHILD = ("import os, time\n"
+                "while not os.path.exists({!r}): time.sleep(0.01)\n"
+                "print('boom', flush=True)")
+
+
+def _gone(pid: int) -> bool:
+    """Not running: no such process, or a zombie whoever its parent is
+    (a child this process reaped is the first; an orphan that pid 1 has
+    not collected yet may be the second)."""
+    try:
+        with open(f"/proc/{pid}/stat", "rb") as fh:
+            return fh.read().rpartition(b")")[2].split()[0] in (b"Z", b"X")
+    except (FileNotFoundError, ProcessLookupError):
+        return True
+
+
+@pytest.fixture
+def stub_children(monkeypatch):
+    """`tcp_pool` spawns `python -c <stub>` instead of nodes and services;
+    everything else about the spawn is the launcher's own. -> (stubs: the
+    queue of stub sources, spawned: [(Popen, its process group)])."""
+    from plenum_tpu.tools import tcp_pool
+    real_popen = subprocess.Popen
+    stubs, spawned = [], []
+
+    def popen(cmd, **kwargs):
+        proc = real_popen([sys.executable, "-c", stubs.pop(0)], **kwargs)
+        spawned.append((proc, os.getpgid(proc.pid)))
+        return proc
+
+    monkeypatch.setattr(tcp_pool.subprocess, "Popen", popen)
+    monkeypatch.setattr(tcp_pool, "STOP_GRACE_S", 0.5)
+    yield stubs, spawned
+    for proc, _pgid in spawned:
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+
+
+def test_stop_processes_kills_and_reaps_a_child_deaf_to_sigterm(
+        stub_children):
+    from plenum_tpu.tools import tcp_pool
+    stubs, _ = stub_children
+    stubs.append(_DEAF_CHILD)
+    proc = tcp_pool._spawn(["a", "node"], stdout=subprocess.PIPE)
+    assert b"started" in proc.stdout.readline()     # the trap is set
+    t0 = time.monotonic()
+    tcp_pool.stop_processes([proc, None])
+    assert proc.returncode == -signal.SIGKILL
+    assert time.monotonic() - t0 < 5.0
+    with pytest.raises(ProcessLookupError):         # reaped, not a zombie
+        os.kill(proc.pid, 0)
+    proc.stdout.close()
+    tcp_pool.stop_processes([proc])                 # a no-op on the dead
+
+
+# what chip_smoke's `spawn_phase` makes of a phase: a launcher in a group
+# of its own that starts children through `tcp_pool`, says who they are,
+# and then never gets to its `finally`
+_LAUNCHER = ("import json, subprocess, sys, time\n"
+             "from plenum_tpu.tools import tcp_pool\n"
+             "kids = [tcp_pool._spawn([sys.executable, '-c', sys.argv[1]],\n"
+             "                        stdout=subprocess.PIPE)\n"
+             "        for _ in range(2)]\n"
+             "for kid in kids: kid.stdout.readline()\n"
+             "print(json.dumps([kid.pid for kid in kids]), flush=True)\n"
+             "time.sleep(600)")
+
+
+def test_killing_the_launchers_group_leaves_no_child():
+    """A caller that cannot ask the launcher to stop (a phase past its
+    deadline, a hung test) SIGKILLs the launcher's group; the nodes and
+    the service that holds the chip must be in it."""
+    launcher = subprocess.Popen(
+        [sys.executable, "-c", _LAUNCHER, _DEAF_CHILD], cwd=REPO,
+        env=dict(os.environ, PYTHONPATH=REPO), stdout=subprocess.PIPE,
+        start_new_session=True)
+    kids = []
+    try:
+        kids = json.loads(launcher.stdout.readline())
+        assert len(kids) == 2
+    finally:
+        os.killpg(launcher.pid, signal.SIGKILL)
+        launcher.wait()
+        launcher.stdout.close()
+    deadline = time.monotonic() + 5.0
+    while not all(map(_gone, kids)) and time.monotonic() < deadline:
+        time.sleep(0.01)
+    survivors = [pid for pid in kids if not _gone(pid)]
+    for pid in survivors:               # a failure must not leak them
+        os.kill(pid, signal.SIGKILL)
+    assert survivors == []
+
+
+def test_run_tcp_pool_leaves_no_child_when_a_node_dies_starting(
+        stub_children, tmp_path):
+    pytest.importorskip(
+        "cryptography",
+        reason="setup_pool_dir's keygen needs cryptography")
+    from plenum_tpu.tools import tcp_pool
+    stubs, spawned = stub_children
+    # the third node dies only after the second has set its trap
+    trap_set = str(tmp_path / "trap_set")
+    stubs.extend([
+        _MUTE_CHILD,
+        _DEAF_CHILD.replace("time.sleep(600)",
+                            f"open({trap_set!r}, 'w').close(); "
+                            "time.sleep(600)"),
+        _DYING_CHILD.format(trap_set), _MUTE_CHILD])
+    with pytest.raises(RuntimeError, match="exited before starting"):
+        tcp_pool.run_tcp_pool(n_nodes=4, backend="cpu",
+                              base_dir=str(tmp_path))
+    assert len(spawned) == 4
+    for proc, pgid in spawned:
+        assert pgid == os.getpgid(0)    # one SIGKILL of the group ends all
+        assert proc.returncode is not None
+        with pytest.raises(ProcessLookupError):
+            os.kill(proc.pid, 0)
+    # TERM ended the mute ones, KILL the deaf one, the third by itself
+    assert [p.returncode for p, _pgid in spawned] == [
+        -signal.SIGTERM, -signal.SIGKILL, 0, -signal.SIGTERM]
 
 
 @pytest.mark.slow
@@ -306,15 +435,45 @@ def test_metrics_report_batch_cut_reasons(tmp_path):
                                      "forced": 0}
 
 
+# the first is the retired per-call A/B arm's name
+@pytest.mark.parametrize("backend", ["jax-percall", "tpu"])
+def test_local_pool_rejects_a_backend_it_does_not_build(backend):
+    """Such a string used to fall through to the CPU verifier and label
+    the run with the name it was given."""
+    import plenum_tpu.tools.local_pool as lp
+    with pytest.raises(ValueError, match="cpu, jax"):
+        lp.build_pool(4, backend)
+    with pytest.raises(SystemExit):
+        lp.main(["--backend", backend])
+    assert lp.build_pool(4, "cpu").pipeline is None
+
+
 def test_distinct_signers_config_orders_owner_writes():
-    """config1b: n distinct client keys on the authN hot path — every
-    ATTRIB owner-signed by its own DID (authorization: owner-or-trustee),
-    so the figure reflects diverse-client traffic, not one amortized
-    trustee key."""
-    from plenum_tpu.tools.bench_configs import config1b_distinct_signers
-    r = config1b_distinct_signers(n_txns=40, timeout=60.0)
-    assert r.get("txns_ordered") == 40, r
-    assert r["distinct_signers"] == 40
+    """n distinct client keys on the authN hot path: phase 1 creates n
+    DIDs (trustee-signed NYMs), phase 2 has every DID owner-sign an
+    ATTRIB on itself (authorization: owner-or-trustee), so the traffic
+    is diverse-client, not one amortized trustee key."""
+    import plenum_tpu.tools.local_pool as lp
+    from plenum_tpu.common.request import Request
+    from plenum_tpu.execution.txn import ATTRIB
+    n = 40
+    pool = lp.build_pool(4, "cpu")
+    nyms, users = lp.signed_nyms(pool.trustee, n, tag=b"ds")
+    first_reply, _, _ = lp.drive(pool, nyms, timeout=60.0)
+    assert len(first_reply) == n, "setup incomplete"
+    attribs = []
+    for i, u in enumerate(users):
+        req = Request(u.identifier, 1,
+                      {"type": ATTRIB, "dest": u.identifier,
+                       "raw": json.dumps({"endpoint": str(i)})})
+        req.signature = u.sign_b58(req.signing_bytes())
+        attribs.append(req)
+    assert len({r.identifier for r in attribs}) == n
+    first_reply, _, _ = lp.drive(pool, attribs, timeout=60.0)
+    assert len(first_reply) == n
+    assert lp.pool_roots(pool)["agree"]
+    assert {pool.nodes[name].c.db.get_ledger(pool.domain_ledger_id).size
+            for name in pool.names} == {1 + 2 * n}
 
 
 def test_replay_reproduces_span_sequence():
@@ -454,18 +613,33 @@ def test_start_node_chunked_backend_is_durable(tmp_path):
     node.c.db.close()
 
 
-@pytest.mark.slow
-def test_config18_autopilot_heals_zipfian_flood_hands_off():
-    """The ISSUE 18 acceptance bench: config12's zipfian hot-range
-    flood with AUTOPILOT=True and ZERO test-driven actuation — the
-    autopilot must split the hot shard on its own cadence and the run
-    must recover to >= 0.8x pre-flood TPS with a clean control-ledger
-    audit."""
-    from plenum_tpu.tools.bench_configs import config18_autopilot
-    out = config18_autopilot()
-    assert "error" not in out, out
-    assert out["test_driven_actuations"] == 0
-    assert out["recovery_ratio"] >= 0.8, out
-    assert out["audit_problems"] == [], out
-    assert out["split_evidence"]["hot_shard"] == 0
-    assert out["migration"]["phase"] == "done", out
+# --- the documents describe the tree that exists ---------------------------
+
+def test_docs_name_only_what_exists():
+    """Every file and module README.md and docs/*.md name is there: a
+    `dir/name.py` path resolves against the root or `plenum_tpu/`, a bare
+    `name.py` is some file's name in the tree, and a `python -m
+    plenum_tpu.x` module can be run."""
+    import pathlib
+    import re
+    repo = pathlib.Path(REPO)
+    tree = {p.name for top in ("plenum_tpu", "tests", "benchmarks", "probes",
+                               "baseline")
+            for p in (repo / top).rglob("*.py")}
+    tree |= {p.name for p in repo.glob("*.py")}
+    missing = []
+    for doc in [repo / "README.md", *sorted((repo / "docs").glob("*.md"))]:
+        text = doc.read_text()
+        for m in re.finditer(r"(?<![\w/.])((?:\w+/)*)(\w+\.py)\b", text):
+            path = m.group(1) + m.group(2)
+            found = ((repo / path).is_file()
+                     or (repo / "plenum_tpu" / path).is_file()
+                     if m.group(1) else m.group(2) in tree)
+            if not found:
+                missing.append((doc.name, path))
+        for m in re.finditer(r"python3? -m (plenum_tpu(?:\.\w+)+)", text):
+            rel = m.group(1).replace(".", "/")
+            if not ((repo / f"{rel}.py").is_file()
+                    or (repo / rel / "__main__.py").is_file()):
+                missing.append((doc.name, m.group(0)))
+    assert missing == []

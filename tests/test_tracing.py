@@ -198,7 +198,6 @@ def test_anomalies_recorded_across_view_change():
                               PRIMARY_HEALTH_CHECK_FREQ=0.5,
                               ORDERING_PROGRESS_TIMEOUT=2.0,
                               STATE_FRESHNESS_UPDATE_INTERVAL=3.0,
-                              VIEW_CHANGE_TIMEOUT=8.0,
                               NEW_VIEW_TIMEOUT=4.0))
     from plenum_tpu.network import Discard, match_dst, match_frm
     primary = pool.nodes["Alpha"].master_replica.data.primary_name
