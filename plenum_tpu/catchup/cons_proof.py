@@ -27,14 +27,19 @@ class ConsProofService:
                  retry_timeout: float = 5.0,
                  config=None,
                  rtt: Optional[RttEstimator] = None,
-                 salt: str = ""):
+                 salt: str = "",
+                 on_unbacked: Optional[Callable[[int, int], None]] = None):
         """on_target(ledger_id, None) = already up to date;
-        on_target(ledger_id, (size, root_hex, (view_no, pp_seq_no)))."""
+        on_target(ledger_id, (size, root_hex, (view_no, pp_seq_no)));
+        on_unbacked(ledger_id, size): a rejoining node's ledger runs past
+        what f+1 validators hold, which ends at `size` (see start)."""
         self.ledger_id = ledger_id
         self._db = db
         self._quorums = quorums_provider
         self._send = send
         self._on_target = on_target
+        self._on_unbacked = on_unbacked
+        self._rejoin = False
         self._running = False
         self._timer = timer
         self._retry_timeout = retry_timeout
@@ -56,6 +61,10 @@ class ConsProofService:
         self.rounds = 0          # status broadcasts this catchup round
         self._retry_armed = False
         self._same_status: set[str] = set()
+        # rejoin: the sizes of the peers that hold a prefix of our ledger
+        # or the whole of it, and the peers ahead of us
+        self._held_by: dict[str, int] = {}
+        self._ahead: set[str] = set()
         self._proofs: dict[tuple[int, str], set[str]] = {}
         # (size, root) -> {(view_no, pp_seq_no) -> voters}: the 3PC position
         # needs its own f+1 quorum — a single Byzantine peer echoing the
@@ -64,8 +73,20 @@ class ConsProofService:
         self._last_3pc_votes: dict[tuple[int, str],
                                    dict[tuple[int, int], set[str]]] = {}
 
-    def start(self) -> None:
+    def start(self, rejoin: bool = False) -> None:
+        """rejoin: this node has just started from its disk and holds no
+        3PC certificate for anything on it. Its own word is then not
+        enough for the tail of its ledger: it is current only if f other
+        validators hold that tail too (f+1 with itself, the number whose
+        REPLYs acknowledge a write). A tail that n-f others lack can have
+        been acknowledged to nobody, no quorum can be brought to it, and
+        the pool will order other batches in its place: it is reported
+        through on_unbacked, for the node to cut. With fewer answers the
+        node waits and asks again: it cannot tell."""
         self._running = True
+        self._rejoin = rejoin and self._on_unbacked is not None
+        self._held_by.clear()
+        self._ahead.clear()
         self._same_status.clear()
         self._proofs.clear()
         self._last_3pc_votes.clear()
@@ -138,8 +159,31 @@ class ConsProofService:
                 (msg.txn_seq_no < ledger.size or
                  msg.merkle_root == ledger.root_hash.hex()):
             self._same_status.add(frm)
+            if self._rejoin:
+                if msg.txn_seq_no and msg.merkle_root != \
+                        ledger.tree.merkle_tree_hash(
+                            0, msg.txn_seq_no).hex():
+                    return               # not a prefix of ours: no witness
+                self._held_by[frm] = msg.txn_seq_no
+                if not self._tail_is_backed(ledger.size):
+                    return
             if self._quorums().checkpoint.is_reached(len(self._same_status)):
                 self._finish(None)       # n-f-1 peers agree we are current
+
+    def _tail_is_backed(self, size: int) -> bool:
+        """Rejoin: whether f other validators hold our ledger to its end.
+        If n-f hold less, reports where f+1 of us end (the f-th longest
+        of theirs) and stops: the node cuts its ledger and starts again."""
+        f = self._quorums().f
+        full = [p for p, held in self._held_by.items() if held >= size]
+        if len(full) + len(self._ahead) >= f:
+            return True
+        if self._quorums().commit.is_reached(len(self._held_by)):
+            backed = sorted(self._held_by.values(), reverse=True)[f - 1]
+            self._running = False
+            self._cancel_retry()
+            self._on_unbacked(self.ledger_id, backed)
+        return False
 
     def process_consistency_proof(self, msg: ConsistencyProof, frm: str) -> None:
         if not self._running or msg.ledger_id != self.ledger_id:
@@ -148,6 +192,7 @@ class ConsProofService:
         ledger = self._db.get_ledger(self.ledger_id)
         if msg.seq_no_end <= ledger.size:
             return
+        self._ahead.add(frm)
         key = (msg.seq_no_end, msg.new_merkle_root)
         self._proofs.setdefault(key, set()).add(frm)
         if msg.view_no is not None and msg.pp_seq_no is not None:

@@ -38,22 +38,24 @@ class LedgerLeecherService:
                  on_complete: Callable[[int, Optional[tuple[int, int]]], None],
                  config=None,
                  rtt: Optional[RttEstimator] = None,
-                 salt: str = ""):
+                 salt: str = "",
+                 on_unbacked: Optional[Callable[[int, int], None]] = None):
         self.ledger_id = ledger_id
         self._on_complete = on_complete
         self._last_3pc: Optional[tuple[int, int]] = None
         self.cons_proof = ConsProofService(
             ledger_id, db, quorums_provider, send, self._on_target,
-            timer=timer, config=config, rtt=rtt, salt=salt)
+            timer=timer, config=config, rtt=rtt, salt=salt,
+            on_unbacked=on_unbacked)
         self.rep = CatchupRepService(
             ledger_id, db, send, timer, peers_provider, on_txn_added,
             self._on_rep_complete, config=config, rtt=rtt, salt=salt)
         self.is_active = False
 
-    def start(self) -> None:
+    def start(self, rejoin: bool = False) -> None:
         self.is_active = True
         self._last_3pc = None
-        self.cons_proof.start()
+        self.cons_proof.start(rejoin)
 
     def stop(self) -> None:
         self.is_active = False
@@ -84,7 +86,8 @@ class NodeLeecherService:
                  on_txn_added: Callable[[int, dict], None],
                  on_catchup_complete: Callable[[Optional[tuple[int, int]]], None],
                  config=None, salt: str = "",
-                 rtt: Optional[RttEstimator] = None):
+                 rtt: Optional[RttEstimator] = None,
+                 on_unbacked: Optional[Callable[[int, int], None]] = None):
         # ONE RTT estimate shared by every ledger's services (and, via the
         # node, by the view-change timeout): round-trip time is a property
         # of the network, not of a ledger id
@@ -95,9 +98,14 @@ class NodeLeecherService:
             lid: LedgerLeecherService(lid, db, send, timer, quorums_provider,
                                       peers_provider, on_txn_added,
                                       self._ledger_done, config=config,
-                                      rtt=self.rtt, salt=salt)
+                                      rtt=self.rtt, salt=salt,
+                                      # the audit ledger is the record of
+                                      # batches: the others follow its cut
+                                      on_unbacked=on_unbacked
+                                      if lid == AUDIT_LEDGER_ID else None)
             for lid in CATCHUP_ORDER if db.get_ledger(lid) is not None}
         self.is_running = False
+        self._rejoin = False
         self._order: list[int] = [lid for lid in CATCHUP_ORDER
                                   if lid in self.leechers]
         self._idx = 0
@@ -105,10 +113,13 @@ class NodeLeecherService:
 
     # --- control -----------------------------------------------------------
 
-    def start(self) -> None:
+    def start(self, rejoin: bool = False) -> None:
+        """rejoin: the node's first catch-up after a start from its disk
+        (ConsProofService.start)."""
         if self.is_running:
             return
         self.is_running = True
+        self._rejoin = rejoin
         self._idx = 0
         self._last_3pc = None
         self._start_current()
@@ -169,7 +180,7 @@ class NodeLeecherService:
             self.is_running = False
             self._on_catchup_complete(self._last_3pc)
             return
-        self.leechers[self._order[self._idx]].start()
+        self.leechers[self._order[self._idx]].start(self._rejoin)
 
     def _ledger_done(self, ledger_id: int,
                      last_3pc: Optional[tuple[int, int]]) -> None:

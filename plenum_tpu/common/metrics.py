@@ -67,6 +67,14 @@ class MetricsName:
     COMMIT_APPLY_TIME = "commit_path.apply_time"
     COMMIT_DURABLE_TIME = "commit_path.durable_time"
     COMMIT_REPLY_TIME = "commit_path.reply_time"
+    # durable stores only (the memory store flushes nothing): of one group
+    # commit, the seconds spent closing the write batches (one flush a
+    # store), and the stores' own cumulative counters, sampled at flush
+    STORAGE_FLUSH_TIME = "storage.flush_time"
+    STORAGE_ROWS = "storage.rows_written"
+    STORAGE_BYTES = "storage.bytes_written"
+    STORAGE_FLUSHES = "storage.flushes"
+    STORAGE_FILE_GETS = "storage.file_gets"
     # fused commit-wave drain (parallel/commit_wave.py): wall time of the
     # two-phase triple-root wave per ordered batch (sampled -> p50/p95)
     COMMIT_WAVE_TIME = "commit_path.commit_wave_time"
@@ -410,6 +418,7 @@ SAMPLED_NAMES = frozenset({
     MetricsName.COMMIT_BLS_VERIFY_TIME, MetricsName.COMMIT_APPLY_TIME,
     MetricsName.COMMIT_WAVE_TIME,
     MetricsName.COMMIT_DURABLE_TIME, MetricsName.COMMIT_REPLY_TIME,
+    MetricsName.STORAGE_FLUSH_TIME,
     MetricsName.BLS_PAIRINGS_PER_BATCH,
     MetricsName.CRYPTO_DISPATCH_BUDGET,
     MetricsName.PIPELINE_VERDICT_WAIT,

@@ -154,12 +154,18 @@ class BlsBftReplica:
             ms = MultiSignature.from_list(list(pre_prepare.bls_multi_sig))
         except (ValueError, TypeError, IndexError, KeyError):
             return self.PPR_BLS_MULTISIG_WRONG
+        return None if self.multi_sig_holds(ms) \
+            else self.PPR_BLS_MULTISIG_WRONG
+
+    def multi_sig_holds(self, ms: MultiSignature) -> bool:
+        """Whether `ms` is a quorum multi-signature over its value, by the
+        keys and the quorum of the pool state it cites."""
         # Participants must be DISTINCT registered validators: aggregation is
         # plain point addition, so one colluding node's signature repeated
         # n-f times would otherwise verify as a quorum multi-sig (rogue
         # self-aggregation).
         if len(set(ms.participants)) != len(ms.participants):
-            return self.PPR_BLS_MULTISIG_WRONG
+            return False
         # A multi-sig we aggregated (or fully verified) OURSELVES passed the
         # quorum rules in force when it was created. This shortcut must come
         # BEFORE the current-quorum check: the first PRE-PREPARE after a pool
@@ -168,7 +174,7 @@ class BlsBftReplica:
         # re-judging it with the new quorums would mark every honest primary
         # suspicious and storm view changes on every pool growth.
         if self._ms_key(ms) in self._verified_ms_keys:
-            return None
+            return True
         # keys AND quorum AS OF the sig's cited pool state — the same
         # epoch resolution process_order aggregates under, so an honest
         # aggregate passes here BY CONSTRUCTION (each node's aggregate can
@@ -177,20 +183,32 @@ class BlsBftReplica:
         key_of, reg, quorums = self._epoch_of(ms.value.pool_state_root_hash)
         vk_of = {n: key_of(n) for n in ms.participants}
         if any(v is None for v in vk_of.values()):
-            return self.PPR_BLS_MULTISIG_WRONG
+            return False
         if reg is not None and not set(ms.participants) <= set(reg):
-            return self.PPR_BLS_MULTISIG_WRONG
+            return False
         if not quorums.bls_signatures.is_reached(len(ms.participants)):
-            return self.PPR_BLS_MULTISIG_WRONG
+            return False
         ok = self._verifier.verify_multi_sig(ms.signature,
                                              ms.value.as_single_value(),
                                              [vk_of[n] for n in
                                               ms.participants])
         self._drop_stale_points(vk_of)
-        if not ok:
-            return self.PPR_BLS_MULTISIG_WRONG
-        self._remember_verified(ms)
-        return None
+        if ok:
+            self._remember_verified(ms)
+        return ok
+
+    def adopt_multi_sig(self, ms: MultiSignature) -> bool:
+        """A multi-signature a peer sent for a root this node reached by
+        catch-up (it saw no COMMITs for that batch): checked like one a
+        PRE-PREPARE carries, then kept and announced like one aggregated
+        here."""
+        if not self.multi_sig_holds(ms):
+            return False
+        if self._store is not None:
+            self._store.put(ms)
+        if self.on_multi_sig is not None:
+            self.on_multi_sig(ms)
+        return True
 
     # --- COMMIT -----------------------------------------------------------
 

@@ -981,6 +981,18 @@ class OrderingService:
         self.revert_unordered_batches()
         self._data.is_participating = False
 
+    def rolled_back_to_3pc(self, last_3pc: tuple[int, int]) -> None:
+        """A node that has just started from its disk cut its ledgers
+        back to `last_3pc` (node.py _on_unbacked_tail): it has ordered
+        nothing in this life, so the position is taken as a first
+        restore would take it, lower than it was."""
+        chk = max(1, self._config.CHK_FREQ)
+        boundary = last_3pc[1] // chk * chk
+        self._data.last_ordered_3pc = last_3pc
+        self._data.pp_seq_no = last_3pc[1]
+        self._data.low_watermark = boundary
+        self._data.stable_checkpoint = boundary
+
     def caught_up_till_3pc(self, last_3pc: tuple[int, int]) -> None:
         """Adopt the 3PC position reached through catchup (ref :2223).
 

@@ -161,7 +161,13 @@ class CompactMerkleTree:
             return self.hash_store.get_leaf(idx)
         h = self.hash_store.try_get_node(level, idx)
         if h is None:
-            raise KeyError((level, idx))
+            # a crash can leave a scope's leaves on disk without the
+            # interior nodes over them (the engine writes a prefix):
+            # recompute the complete subtree from what is below it
+            if (idx + 1) << level > self.tree_size:
+                raise KeyError((level, idx))
+            h = self._range_root(idx << level, (idx + 1) << level)
+            self.hash_store.put_node(level, idx, h)
         return h
 
     def _range_root(self, lo: int, hi: int) -> bytes:

@@ -66,8 +66,23 @@ class HashStore:
         return lo
 
     def reset(self) -> None:
-        for k in list(self._kv.iterator(include_value=False)):
-            self._kv.remove(k)
+        self.truncate(0)
+
+    def truncate(self, leaf_count: int) -> None:
+        """Drop every leaf at or past `leaf_count` and every interior node
+        whose subtree reaches past it: what is left is exactly what
+        appending `leaf_count` leaves would have stored."""
+        doomed = []
+        for k in self._kv.iterator(include_value=False):
+            if k[:1] == b"l":
+                reach = int.from_bytes(k[1:], "big") + 1
+            else:
+                reach = (int.from_bytes(k[2:], "big") + 1) << k[1]
+            if reach > leaf_count:
+                doomed.append(k)
+        with self._kv.write_batch():
+            for k in doomed:
+                self._kv.remove(k)
 
     def close(self) -> None:
         self._kv.close()

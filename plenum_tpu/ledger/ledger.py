@@ -70,11 +70,31 @@ class Ledger:
             self.tree.extend_batch([txn_to_leaf(t) for t in missing])
             return
         if self.tree.tree_size > log_size:
-            # hash store ahead of (or inconsistent with) the log: rebuild
-            self.tree.hash_store.reset()
-            self.tree = CompactMerkleTree(self.hasher, self.tree.hash_store)
-            all_txns = [self.get_by_seq_no(i) for i in range(1, log_size + 1)]
-            self.tree.extend_batch([txn_to_leaf(t) for t in all_txns])
+            # hash store ahead of the log (a crash flushed its rows and
+            # not the log's): cut it back to the log
+            self._cut_tree(log_size)
+
+    def _cut_tree(self, size: int) -> None:
+        self.tree.hash_store.truncate(size)
+        self.tree = CompactMerkleTree.recover(self.hasher,
+                                              self.tree.hash_store)
+
+    def truncate(self, size: int) -> list[dict]:
+        """Drop every committed txn past `size` (restart recovery: a batch
+        that not every store of this validator holds, or a tail no quorum
+        of the pool backs). -> the dropped txns, oldest first."""
+        if not 0 <= size <= self.seq_no:
+            raise ValueError(f"truncate to {size} of {self.seq_no}")
+        dropped = [self.get_by_seq_no(i)
+                   for i in range(size + 1, self.seq_no + 1)]
+        if dropped:
+            self.reset_uncommitted()
+            self._log.do_ops_in_batch(
+                [("remove", i, b"")
+                 for i in range(size + 1, self.seq_no + 1)])
+            self.seq_no = size
+            self._cut_tree(size)
+        return dropped
 
     # --- committed appends ------------------------------------------------
 

@@ -9,8 +9,10 @@ genesis-seeded) node starts from consistent roots.
 from __future__ import annotations
 
 import os
+import time
 from typing import NamedTuple, Optional, Sequence
 
+from plenum_tpu.common.serialization import pack, unpack
 from plenum_tpu.common.node_messages import (AUDIT_LEDGER_ID,
                                              CONFIG_LEDGER_ID,
                                              DOMAIN_LEDGER_ID, POOL_LEDGER_ID)
@@ -44,6 +46,149 @@ from plenum_tpu.node.client_authn import CoreAuthNr, ReqAuthenticator
 from plenum_tpu.node.pool_manager import TxnPoolManager
 from plenum_tpu.storage.kv_file import KvFile
 from plenum_tpu.storage.kv_memory import KvMemory
+from plenum_tpu.state.trie import BLANK_ROOT
+
+
+# A group commit flushes every store before the next one opens, so one
+# validator's stores differ by at most one scope, and a scope holds at most
+# Config.GROUP_COMMIT_MAX_BATCHES (32) batches: how far back a restart looks
+# for the batch a lagging named store stopped at.
+RECONCILE_BATCHES = 64
+
+
+def last_whole_batch(db: DatabaseManager) -> int:
+    """The newest audit txn whose batch every ledger of this validator
+    holds in full (0: none). A crash in mid-commit leaves the stores at
+    different batches: each is flushed on its own, and the native engine
+    can leave a prefix of a scope."""
+    audit = db.get_ledger(AUDIT_LEDGER_ID)
+    keep = audit.size
+    while keep > 0:
+        sizes = txn_lib.txn_data(audit.get_by_seq_no(keep))["ledgerSize"]
+        if all(db.get_ledger(int(lid)).size >= size
+               for lid, size in sizes.items()):
+            break
+        keep -= 1
+    return keep
+
+
+def roll_back_to_audit(db: DatabaseManager, wm: WriteRequestManager,
+                       keep: int, floor: dict) -> dict:
+    """Bring every store of this validator to the batch of audit txn
+    `keep`: ledgers and the audit ledger cut back to it, each state at the
+    root it records (moved there, or replayed from the ledger where the
+    trie lacks it), the seq-no and timestamp stores holding its txns and
+    none past them. `floor`: ledger id -> size below which nothing is cut
+    (the genesis), used when no audit txn is kept. Idempotent.
+    -> what it did, for the start line."""
+    audit = db.get_ledger(AUDIT_LEDGER_ID)
+    report = {"audit_txns_dropped": audit.size - keep, "txns_dropped": {},
+              "state": {}}
+    kept = txn_lib.txn_data(audit.get_by_seq_no(keep)) if keep else {}
+    sizes = {int(lid): size
+             for lid, size in kept.get("ledgerSize", floor).items()}
+    seq_no_db = db.get_store(SEQ_NO_DB_LABEL)
+    for lid, ledger in db.ledgers():
+        want = sizes.get(lid)
+        if lid == AUDIT_LEDGER_ID or want is None or want >= ledger.size:
+            continue
+        report["txns_dropped"][lid] = len(ledger.truncate(want))
+    audit.truncate(keep)
+    if seq_no_db is not None and (report["audit_txns_dropped"]
+                                  or report["txns_dropped"]):
+        # entries of txns that are gone (cut above, or never flushed to
+        # their log): a resend would be answered from a seqNo that is
+        # not theirs
+        gone = []
+        for key, raw in seq_no_db.iterator():
+            lid, seq_no = unpack(raw)[:2]
+            if seq_no > db.get_ledger(lid).size:
+                gone.append(key)
+        for key in gone:
+            seq_no_db.remove(key)
+
+    for lid, ledger in db.ledgers():
+        state = db.get_state(lid)
+        if state is None:
+            continue
+        root_hex = kept.get("stateRoot", {}).get(str(lid))
+        if not hasattr(state, "has_root"):
+            continue        # a backend with no addressable roots: as found
+        if root_hex is None:
+            # no batch kept: the state is the genesis's, rebuilt below
+            # (_replay_genesis_state) if txns were cut from under it
+            if report["txns_dropped"].get(lid):
+                state.commit(BLANK_ROOT)
+                state.revert_to_head(BLANK_ROOT)
+                report["state"][lid] = "reset"
+            continue
+        wanted = bytes.fromhex(root_hex)
+        if state.committed_head_hash == wanted:
+            continue
+        if state.has_root(wanted):
+            state.commit(wanted)
+            state.revert_to_head(wanted)
+            report["state"][lid] = "moved"
+            continue
+        # the trie lacks the batch: replay the ledger from the newest
+        # batch it does hold
+        start, at = 0, BLANK_ROOT
+        for seq in range(keep, max(0, keep - RECONCILE_BATCHES), -1):
+            data = txn_lib.txn_data(audit.get_by_seq_no(seq))
+            root = bytes.fromhex(data["stateRoot"][str(lid)])
+            if state.has_root(root):
+                start, at = data["ledgerSize"][str(lid)], root
+                break
+        state.commit(at)
+        state.revert_to_head(at)
+        for seq in range(start + 1, ledger.size + 1):
+            wm.apply_committed_txn(lid, ledger.get_by_seq_no(seq),
+                                   committed=False)
+        if state.head_hash != wanted:
+            raise RuntimeError(
+                f"ledger {lid}: its txns replay to state root "
+                f"{state.head_hash.hex()}, the audit ledger records "
+                f"{root_hex} at batch {keep}")
+        state.commit(wanted)
+        report["state"][lid] = {"replayed_txns": ledger.size - start}
+
+    # the named stores behind the ledgers: rows of the last scope
+    ts_store = db.get_store(TS_STORE_LABEL)
+    if keep and ts_store is not None:
+        at = txn_lib.txn_time(audit.get_by_seq_no(keep))
+        late = [k for k in ts_store.kv.iterator(include_value=False)
+                if int.from_bytes(k[2:], "big") > at]
+        for k in late:
+            ts_store.kv.remove(k)
+    first = max(1, keep - RECONCILE_BATCHES + 1)
+    prev = txn_lib.txn_data(audit.get_by_seq_no(first - 1))["ledgerSize"] \
+        if first > 1 else {str(lid): size for lid, size in floor.items()}
+    rows, roots_at = 0, {}
+    for seq in range(first, keep + 1):
+        txn = audit.get_by_seq_no(seq)
+        data = txn_lib.txn_data(txn)
+        lid = data["ledgerId"]
+        root_hex = data.get("stateRoot", {}).get(str(lid))
+        if root_hex is not None:
+            # the store keys by whole seconds: the last batch of one wins
+            roots_at[(lid, txn_lib.txn_time(txn))] = bytes.fromhex(root_hex)
+        ledger = db.get_ledger(lid)
+        for n in range(prev.get(str(lid), 0) + 1,
+                       data["ledgerSize"][str(lid)] + 1):
+            done = ledger.get_by_seq_no(n)
+            digest = txn_lib.txn_payload_digest(done)
+            if seq_no_db is not None and digest \
+                    and not seq_no_db.has_key(digest.encode()):
+                seq_no_db.put(digest.encode(), pack(
+                    (lid, n, txn_lib.txn_time(done))))
+                rows += 1
+        prev = data["ledgerSize"]
+    for (lid, at), root in roots_at.items():
+        if ts_store is not None and ts_store.get(lid, at) != root:
+            ts_store.set(lid, at, root)
+            rows += 1
+    report["named_store_rows_restored"] = rows
+    return report
 
 
 class NodeComponents(NamedTuple):
@@ -63,6 +208,8 @@ class NodeComponents(NamedTuple):
     # fused crypto pipeline (parallel/pipeline.py) the node's crypto
     # seams ride when constructed with one; co-hosted nodes share it
     pipeline: object = None
+    # what a start on durable stores found and reconciled (None on memory)
+    recovery: Optional[dict] = None
 
 
 class NodeBootstrap:
@@ -116,12 +263,23 @@ class NodeBootstrap:
         self.state_commitment_per_ledger = \
             dict(state_commitment_per_ledger or {})
         self.verkle_width = verkle_width
+        self.opened: dict[str, dict] = {}   # durable stores, by label
 
     # --- storage factories -------------------------------------------------
 
     def _kv(self, label: str):
         if self.data_dir is None:
             return KvMemory()
+        t0 = time.perf_counter()
+        kv = self._durable_kv(label)
+        # for the start line: which engine holds the store, the rows its
+        # log replayed into the index, the seconds that took
+        self.opened[label] = {"engine": getattr(kv, "engine", "chunked"),
+                              "rows": kv.size,
+                              "open_s": round(time.perf_counter() - t0, 4)}
+        return kv
+
+    def _durable_kv(self, label: str):
         os.makedirs(self.data_dir, exist_ok=True)
         path = os.path.join(self.data_dir, label)
         has_native = os.path.exists(os.path.join(path, "kv.kvn"))
@@ -234,7 +392,14 @@ class NodeBootstrap:
         self.effective_plugins = install_plugins(
             db, write_manager, read_manager, self.plugins)
 
-        self._replay_genesis_state(db, nym, node_handler, write_manager)
+        recovery = self._recover(db, write_manager)
+        t0 = time.perf_counter()
+        replayed = self._replay_genesis_state(db, nym, node_handler,
+                                              write_manager)
+        if recovery is not None:
+            recovery["state_replayed_from_ledger"] = replayed
+            recovery["seconds"]["replay_state"] = round(
+                time.perf_counter() - t0, 3)
 
         # client authN over the Ed25519 provider seam (cpu | jax); with a
         # pipeline the batches stage into the shared ring instead of
@@ -262,27 +427,64 @@ class NodeBootstrap:
                               authnr, pool_manager, nym, node_handler,
                               bls_signer, bls_register, bls_store,
                               self.effective_plugins, action_manager,
-                              self.pipeline)
+                              self.pipeline, recovery)
 
-    def _replay_genesis_state(self, db, nym, node_handler, wm) -> None:
+    def genesis_sizes(self) -> dict:
+        return {lid: len(txns) for lid, txns in self.genesis.items() if txns}
+
+    def _recover(self, db, wm) -> Optional[dict]:
+        """On durable stores: bring this validator's stores to one batch
+        (a first start finds nothing to do) and say what was found.
+        None on memory stores, which recover nothing."""
+        if not self.opened:
+            return None
+        t0 = time.perf_counter()
+        sizes_found = {lid: ledger.size for lid, ledger in db.ledgers()}
+        keep = last_whole_batch(db)
+        done = roll_back_to_audit(db, wm, keep, self.genesis_sizes())
+        engines = {o["engine"] for o in self.opened.values()}
+        return {
+            "engine": engines.pop() if len(engines) == 1 else sorted(engines),
+            "restarted": any(o["rows"] for o in self.opened.values()),
+            "stores": self.opened,
+            "ledger_sizes_found": sizes_found,
+            "ledger_sizes": {lid: ledger.size
+                             for lid, ledger in db.ledgers()},
+            "reconciled_to_audit_txn": keep,
+            "genesis_sizes": self.genesis_sizes(),
+            "reconcile": done,
+            "seconds": {
+                "open_stores": round(sum(o["open_s"]
+                                         for o in self.opened.values()), 3),
+                "reconcile": round(time.perf_counter() - t0, 3)}}
+
+    def _replay_genesis_state(self, db, nym, node_handler, wm) -> dict:
         """Replay committed ledger txns through handlers into state (restart
-        recovery / genesis seeding; ref ledgers_bootstrap init_state_from_ledger)."""
+        recovery / genesis seeding; ref ledgers_bootstrap init_state_from_ledger).
+        -> {ledger id: txns replayed}, empty when every state was built."""
         handlers = {NYM: nym, NODE: node_handler}
         for h in wm._handlers.values():
             handlers.setdefault(h.txn_type, h)
+        replayed = {}
         for lid in (POOL_LEDGER_ID, CONFIG_LEDGER_ID, DOMAIN_LEDGER_ID):
             ledger = db.get_ledger(lid)
             state = db.get_state(lid)
             if state is None or ledger.size == 0:
                 continue
-            if len(state.as_dict(committed=True)) > 0:
+            if hasattr(state, "has_root"):
+                built = state.committed_head_hash != BLANK_ROOT
+            else:
+                built = len(state.as_dict(committed=True)) > 0
+            if built:
                 continue                      # persistent state already built
+            replayed[lid] = ledger.size
             for seq_no in range(1, ledger.size + 1):
                 txn = ledger.get_by_seq_no(seq_no)
                 handler = handlers.get(txn_lib.txn_type_of(txn))
                 if handler is not None:
                     handler.update_state(txn, is_committed=True)
             state.commit(state.head_hash)
+        return replayed
 
     @staticmethod
     def _sync_bls_register(register: BlsKeyRegister,

@@ -25,6 +25,11 @@ PREPREPARE = "PREPREPARE"
 OLD_VIEW_PREPREPARE = "OLD_VIEW_PREPREPARE"
 VIEW_CHANGE = "VIEW_CHANGE"
 NEW_VIEW = "NEW_VIEW"
+# the multi-signature over a committed state root, for a node that reached
+# the root by catch-up (params: state_root). Not a wire message of its own:
+# the reply's `msg` is {"multi_sig": MultiSignature.to_list()}, and the
+# asker verifies it against the pool's BLS keys before keeping it.
+MULTI_SIG = "MULTI_SIG"
 
 
 class MessageReqProcessor:
@@ -68,6 +73,15 @@ class MessageReqProcessor:
     # ------------------------------------------------------------------ #
 
     def process_message_req(self, msg: MessageReq, frm: str) -> None:
+        if msg.msg_type == MULTI_SIG:
+            bls_store = self._node.c.db.bls_store
+            ms = bls_store.get(str(msg.params.get("state_root"))) \
+                if bls_store is not None else None
+            if ms is not None:
+                self._node.node_bus.send(MessageRep(
+                    msg_type=MULTI_SIG, params=msg.params,
+                    msg={"multi_sig": list(ms.to_list())}), [frm])
+            return
         server = {
             PROPAGATE: self._serve_propagate,
             PREPREPARE: self._serve_preprepare,
@@ -148,6 +162,14 @@ class MessageReqProcessor:
 
     def process_message_rep(self, msg: MessageRep, frm: str) -> None:
         if msg.msg is None:
+            return
+        if msg.msg_type == MULTI_SIG:
+            from plenum_tpu.crypto.multi_signature import MultiSignature
+            try:
+                ms = MultiSignature.from_list(list(msg.msg["multi_sig"]))
+            except (ValueError, TypeError, IndexError, KeyError):
+                return
+            self._node.on_requested_multi_sig(ms)
             return
         try:
             inner = message_from_dict(dict(msg.msg))

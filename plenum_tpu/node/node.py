@@ -360,7 +360,18 @@ class Node:
                                     if n != self.name],
             on_txn_added=self._on_catchup_txn,
             on_catchup_complete=self._on_catchup_complete,
-            config=self.config, salt=name, rtt=self.catchup_rtt)
+            config=self.config, salt=name, rtt=self.catchup_rtt,
+            on_unbacked=self._on_unbacked_tail)
+        # a start from durable stores (bootstrap's record of it; None on
+        # memory stores): `rejoining` from rejoin_after_restart() until
+        # the first catch-up has brought this node to the pool
+        self.recovery = components.recovery
+        self.rejoining = False
+        self._caught_up_txns: dict[int, int] = {}
+        # durable stores keep a record of their own traffic (storage/
+        # kv_native.py, kv_file.py); none on memory stores
+        self._durable_kvs = [kv for kv in components.db.iter_kv_stores()
+                             if getattr(kv, "io", None) is not None]
         # catchup progress watchdog: a stalled round (frozen progress key
         # across one interval) gets kicked — forced provider rotation +
         # immediate re-request; repeated kicks restart the round outright.
@@ -491,6 +502,7 @@ class Node:
         # audit ledger's 3PC position and primaries instead of view 0 / seq 0
         # (ref node.py:1830,1875 — the same restore catchup applies later)
         self._restore_3pc_from_audit()
+        self.read_plane.restore_anchors()
         self._restore_backup_last_sent_pp()
 
         # live fleet telemetry (observability/snapshot.py): a periodic
@@ -862,6 +874,13 @@ class Node:
             self._sample_queue_gauges()
             self._sample_crypto_gauges()
             self._sample_footprint_gauges()
+            if self._durable_kvs:
+                io = self._storage_io()
+                for name, key in ((MetricsName.STORAGE_ROWS, "rows"),
+                                  (MetricsName.STORAGE_BYTES, "bytes"),
+                                  (MetricsName.STORAGE_FLUSHES, "flushes"),
+                                  (MetricsName.STORAGE_FILE_GETS, "gets")):
+                    self.metrics.add_event(name, io[key])
             self.metrics.flush()
         finally:
             self._in_metrics_flush = False
@@ -1437,7 +1456,8 @@ class Node:
         if self._catchup_kicks >= self.config.CATCHUP_WATCHDOG_RESTART_KICKS:
             self._catchup_kicks = 0
             self.leecher.stop()
-            self.leecher.start()        # fresh targets, fresh providers
+            # fresh targets, fresh providers
+            self.leecher.start(rejoin=self.rejoining)
         else:
             self.leecher.kick()
 
@@ -1504,7 +1524,70 @@ class Node:
             self.tracer.anomaly("catchup", None)
         for replica in self.replicas:
             replica.ordering.catchup_started()
-        self.leecher.start()
+        self.leecher.start(rejoin=self.rejoining)
+
+    def rejoin_after_restart(self) -> None:
+        """The process entry's call after a start that found ledgers on
+        disk: catch up before ordering, as soon as f+1 peers are reachable
+        (upstream's Node.start does the same). The validators of a pool
+        that crashed stop at different batches, and a node that takes its
+        own disk for the pool's would order from the wrong place."""
+        self.rejoining = True
+        self._needs_resync = True
+        self._maybe_resync_after_partition()
+
+    def _on_unbacked_tail(self, ledger_id: int, backed_size: int) -> None:
+        """Rejoin (catchup/cons_proof.py): this node's audit ledger runs
+        past what f+1 validators hold, and n-f others hold less. Those
+        batches were acknowledged to no client, and the pool is about to
+        order others in their place: cut every store back to the batch
+        f+1 hold (node/bootstrap.py roll_back_to_audit), take the 3PC
+        position from there, and ask the pool again."""
+        from plenum_tpu.execution.handlers import audit as audit_lib
+        from plenum_tpu.node.bootstrap import roll_back_to_audit
+        self.leecher.stop()
+        done = roll_back_to_audit(
+            self.c.db, self.c.write_manager, backed_size,
+            (self.recovery or {}).get("genesis_sizes", {}))
+        audit = self.c.db.get_ledger(AUDIT_LEDGER_ID)
+        view_no, pp_seq_no, _ = audit_lib.last_audited_view(audit)
+        self._last_executed_pp_seq = pp_seq_no
+        self.master_replica.ordering.rolled_back_to_3pc((view_no, pp_seq_no))
+        self.spylog.append(("unbacked_tail_rolled_back",
+                            (backed_size, done["txns_dropped"])))
+        if self.recovery is not None:
+            self.recovery["unbacked_tail_rolled_back"] = done
+        self.leecher.start(rejoin=True)
+
+    def _fetch_missing_multi_sigs(self) -> None:
+        """A root reached by catch-up came without the COMMITs that carry
+        its BLS signatures, so no multi-signature for it is in the store
+        and reads at it would go out without proof until the next batch.
+        Peers that ordered the batch hold one: ask (MessageReq MULTI_SIG;
+        the answer is checked like a PRE-PREPARE's)."""
+        from plenum_tpu.node.message_req_processor import MULTI_SIG
+        bls_store = self.c.db.bls_store
+        if bls_store is None or not self.c.db.get_ledger(
+                AUDIT_LEDGER_ID).size:
+            return
+        for lid in self.c.db.ledger_ids:
+            state = self.c.db.get_state(lid)
+            if state is None:
+                continue
+            root = state.committed_head_hash.hex()
+            if bls_store.get(root) is None:
+                self.message_req.request(MULTI_SIG, {"state_root": root})
+
+    def on_requested_multi_sig(self, ms) -> None:
+        """A peer's answer to MULTI_SIG: kept only for a root this node
+        has committed, and only if it verifies."""
+        state = self.c.db.get_state(ms.value.ledger_id)
+        bls = self.master_replica.bls
+        if state is None or bls is None or ms.value.state_root_hash \
+                != state.committed_head_hash.hex():
+            return
+        if bls.adopt_multi_sig(ms):
+            self.read_plane.restore_anchors()
 
     def _receive_ledger_status(self, msg: LedgerStatus, frm: str) -> None:
         # queries go to the seeder; acknowledgments feed our cons-proof
@@ -1519,6 +1602,8 @@ class Node:
         """A catchup txn was committed to the ledger: replay it into state
         and bookkeeping (ref node.py:1748 postTxnFromCatchupAddedToLedger)."""
         self.c.write_manager.apply_committed_txn(ledger_id, txn)
+        self._caught_up_txns[ledger_id] = \
+            self._caught_up_txns.get(ledger_id, 0) + 1
         digest = txn_lib.txn_digest(txn)
         if digest:
             self.propagator.requests.mark_executed(digest)
@@ -1587,6 +1672,16 @@ class Node:
                 (view_no, pp_seq_no) if replica.is_master
                 else replica.last_ordered_3pc)
         self.spylog.append(("catchup_complete", (view_no, pp_seq_no)))
+        # proofs from what the stores hold: anchors live in memory, and a
+        # root reached by catch-up came without its multi-signature
+        self.read_plane.restore_anchors()
+        self._fetch_missing_multi_sigs()
+        if self.rejoining:
+            self.rejoining = False
+            if self.recovery is not None:
+                self.recovery["rejoined"] = {
+                    "txns_caught_up": dict(self._caught_up_txns),
+                    "seconds": duration, "last_3pc": [view_no, pp_seq_no]}
 
     def _forward_to_replicas(self, digest: str) -> None:
         self.monitor.request_finalized(digest)
@@ -2169,6 +2264,7 @@ class Node:
                 committed_per_msg: list[list[dict]] = []
                 t0 = time.perf_counter()
                 t0_timer = self.timer.get_current_time()
+                io0 = self._storage_io() if self._durable_kvs else None
                 with self.c.executor.group_commit():
                     for msg in chunk:
                         self.metrics.add_event(MetricsName.ORDERED_BATCH_SIZE,
@@ -2181,6 +2277,14 @@ class Node:
                                        time.perf_counter() - t0)
                 self.metrics.add_event(MetricsName.GROUP_COMMIT_BATCHES,
                                        len(chunk))
+                flushed = None
+                if io0 is not None:
+                    # of the scope above, what closing the write batches
+                    # took: each durable store times its own flush
+                    io1 = self._storage_io()
+                    flushed = {k: io1[k] - io0[k] for k in io1}
+                    self.metrics.add_event(MetricsName.STORAGE_FLUSH_TIME,
+                                           flushed["flush_s"])
                 if (self.batch_controller is not None
                         and self.replicas.master.data.is_primary):
                     # flush span on the injectable timer (0 under mock
@@ -2197,6 +2301,9 @@ class Node:
                     # digest); wall duration only when the tracer allows it —
                     # perf_counter deltas are not replay-deterministic
                     data = {"seqs": [m.pp_seq_no for m in chunk]}
+                    if flushed is not None:
+                        data["rows"] = flushed["rows"]
+                        data["bytes"] = flushed["bytes"]
                     if self.tracer.wall_durations:
                         data["dur"] = time.perf_counter() - t0
                     self.tracer.emit(tracing.DURABLE, "", data)
@@ -2204,6 +2311,15 @@ class Node:
                     for msg, committed in zip(chunk, committed_per_msg):
                         self._reply_batch(msg, committed)
         return done
+
+    def _storage_io(self) -> dict:
+        """The durable stores' own counters, summed (storage/kv_store.py
+        new_io): rows, bytes, flushes, flush_s, gets."""
+        total = dict.fromkeys(self._durable_kvs[0].io, 0)
+        for kv in self._durable_kvs:
+            for k, v in kv.io.items():
+                total[k] += v
+        return total
 
     def _commit_ordered(self, msg: Ordered) -> list[dict]:
         """Durable half of executeBatch:2661 — commit the ordered batch's
@@ -2352,4 +2468,9 @@ class Node:
             # (parallel/pipeline.py plane_state); None without a ring
             "plane": (self.c.pipeline.plane_state()
                       if self.c.pipeline is not None else None),
+            # durable stores: their own counters since the start, and
+            # what the start found on disk and did about it; both None
+            # on memory stores
+            "storage": self._storage_io() if self._durable_kvs else None,
+            "recovery": self.recovery,
         }

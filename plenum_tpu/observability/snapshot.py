@@ -77,6 +77,9 @@ SNAPSHOT_SCHEMA: dict[str, frozenset] = {
         MetricsName.COMMIT_BLS_VERIFY_TIME, MetricsName.COMMIT_APPLY_TIME,
         MetricsName.COMMIT_DURABLE_TIME, MetricsName.COMMIT_REPLY_TIME,
         MetricsName.COMMIT_WAVE_TIME,
+        MetricsName.STORAGE_FLUSH_TIME, MetricsName.STORAGE_ROWS,
+        MetricsName.STORAGE_BYTES, MetricsName.STORAGE_FLUSHES,
+        MetricsName.STORAGE_FILE_GETS,
     }),
     "crypto": frozenset({
         MetricsName.SIG_BATCH_SIZE, MetricsName.SIG_BATCH_TIME,

@@ -107,6 +107,27 @@ class ReadPlane:
             if ms is not None:
                 self._adopt(ms)
 
+    def restore_anchors(self) -> list[int]:
+        """After a start on durable stores, or a catch-up: anchors live in
+        memory, the multi-signatures in the BLS store. A ledger whose
+        committed state root has one there, over the txn root the ledger
+        is at, serves proofs again before any new batch is ordered.
+        -> the ledger ids anchored."""
+        bls_store = self._db.bls_store
+        out = []
+        for lid in self._db.ledger_ids if bls_store is not None else ():
+            state, ledger = self._db.get_state(lid), self._db.get_ledger(lid)
+            if state is None:
+                continue
+            ms = bls_store.get(state.committed_head_hash.hex())
+            if ms is None or ms.value.ledger_id != lid \
+                    or ms.value.txn_root_hash != ledger.root_hash.hex():
+                continue
+            self._root_sizes[ms.value.txn_root_hash] = ledger.size
+            self._adopt(ms)
+            out.append(lid)
+        return out
+
     def on_multi_sig(self, ms: MultiSignature) -> None:
         """A multi-sig aggregated (possibly late, via the pending-order
         retry). Anchor it once its txn root's size is known."""

@@ -66,6 +66,12 @@ class PruningState:
                     cache=self._node_cache) if committed else self._trie
         return trie.to_dict()
 
+    def has_root(self, root_hash: bytes) -> bool:
+        """Whether the trie under `root_hash` is in the node store. Nodes
+        are written children first and the root last, into an append-only
+        store, so a root that is there has its whole trie below it."""
+        return root_hash == BLANK_ROOT or self._db.has_key(root_hash)
+
     # --- heads ------------------------------------------------------------
 
     @property

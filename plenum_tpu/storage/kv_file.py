@@ -9,10 +9,11 @@ from __future__ import annotations
 
 import os
 import struct
+import time
 from contextlib import contextmanager
 from typing import Iterator, Optional
 
-from .kv_store import KeyValueStorage, encode_key
+from .kv_store import KeyValueStorage, encode_key, new_io
 from .kv_memory import KvMemory
 
 # _BATCH is the group-commit record: key empty, value = the concatenated
@@ -79,10 +80,13 @@ def read_log_readonly(path: str, name: str = "kv") -> list[tuple[bytes, bytes]]:
 
 
 class KvFile(KeyValueStorage):
+    engine = "file"
+
     def __init__(self, path: str, name: str = "kv"):
         os.makedirs(path, exist_ok=True)
         self._file_path = os.path.join(path, name + ".kvlog")
         self._mem = KvMemory()
+        self.io = new_io()      # `gets` stays 0: values live in memory
         self._fh = None
         self._batch: Optional[list[bytes]] = None   # staged records in scope
         self._replay()
@@ -103,21 +107,29 @@ class KvFile(KeyValueStorage):
                 fh.truncate(off)
 
     def _append(self, op: int, key: bytes, value: bytes = b"") -> None:
+        self.io["rows"] += 1
         if self._batch is not None:
             self._batch.append(pack_record(op, key, value))
             return
-        self._fh.write(pack_record(op, key, value))
+        self._write(pack_record(op, key, value))
+
+    def _write(self, data: bytes) -> None:
+        t0 = time.perf_counter()
+        self._fh.write(data)
         self._fh.flush()
+        io = self.io
+        io["bytes"] += len(data)
+        io["flushes"] += 1
+        io["flush_s"] += time.perf_counter() - t0
 
     def _flush_batch(self, records: list[bytes]) -> None:
         """One append, one flush, all-or-nothing on replay."""
         if not records:
             return
         if len(records) == 1:
-            self._fh.write(records[0])      # a 1-op batch IS atomic already
+            self._write(records[0])         # a 1-op batch IS atomic already
         else:
-            self._fh.write(pack_record(_BATCH, b"", b"".join(records)))
-        self._fh.flush()
+            self._write(pack_record(_BATCH, b"", b"".join(records)))
 
     @contextmanager
     def write_batch(self):

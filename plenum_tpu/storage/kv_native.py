@@ -11,12 +11,14 @@ from __future__ import annotations
 
 import ctypes
 import os
+import time
 from contextlib import contextmanager
 from typing import Iterator, Optional
 
-from .kv_store import KeyValueStorage, encode_key
+from .kv_store import KeyValueStorage, encode_key, new_io
 
 COMPACT_GARBAGE_RATIO = 0.5
+_HDR = 13       # crc32 | op | klen | vlen: kvstore.cpp's record header
 
 
 def _load():
@@ -74,19 +76,31 @@ def native_available() -> bool:
 
 
 class KvNative(KeyValueStorage):
+    engine = "native"
+
     def __init__(self, path: str, name: str = "kv"):
         if not native_available():
             raise RuntimeError("native kvstore engine unavailable")
         os.makedirs(path, exist_ok=True)
         self._file_path = os.path.join(path, name + ".kvn")
+        self._in_batch = False
+        self.io = new_io()
         self._h = _LIB.kvn_open(self._file_path.encode())
         if not self._h:
             raise IOError(f"kvn_open failed for {self._file_path}")
+
+    def _appended(self, nbytes: int) -> None:
+        io = self.io
+        io["rows"] += 1
+        io["bytes"] += _HDR + nbytes
+        if not self._in_batch:
+            io["flushes"] += 1      # the engine fflushes every lone record
 
     def put(self, key, value: bytes) -> None:
         k = encode_key(key)
         if _LIB.kvn_put(self._h, k, len(k), bytes(value), len(value)) != 0:
             raise IOError("kvn_put failed")
+        self._appended(len(k) + len(value))
 
     @contextmanager
     def write_batch(self):
@@ -95,16 +109,22 @@ class KvNative(KeyValueStorage):
         Reads inside the scope stay exact (the engine flushes lazily on
         read). Nesting joins the outer scope."""
         begin = getattr(_LIB, "kvn_begin_batch", None)
-        if begin is None or getattr(self, "_in_batch", False):
+        if begin is None or self._in_batch:
             yield self
             return
         self._in_batch = True
+        rows = self.io["rows"]
         begin(self._h)
         try:
             yield self
         finally:
             self._in_batch = False
-            if _LIB.kvn_end_batch(self._h) != 0:
+            t0 = time.perf_counter()
+            rc = _LIB.kvn_end_batch(self._h)
+            if self.io["rows"] != rows:
+                self.io["flushes"] += 1
+                self.io["flush_s"] += time.perf_counter() - t0
+            if rc != 0:
                 raise IOError("kvn_end_batch failed")
 
     def get(self, key) -> bytes:
@@ -116,12 +136,14 @@ class KvNative(KeyValueStorage):
         got = _LIB.kvn_get(self._h, k, len(k), buf, int(n) or 1)
         if got != n:
             raise IOError("kvn_get failed")
+        self.io["gets"] += 1
         return buf.raw[:n]
 
     def remove(self, key) -> None:
         k = encode_key(key)
         if _LIB.kvn_del(self._h, k, len(k)) != 0:
             raise IOError("kvn_del failed")
+        self._appended(len(k))
 
     def iterator(self, start=None, end=None,
                  include_value: bool = True) -> Iterator:

@@ -22,6 +22,13 @@ def encode_key(key) -> bytes:
     raise TypeError(f"unsupported key type {type(key)}")
 
 
+def new_io() -> dict:
+    """What a durable backend counts of its own traffic: rows and bytes
+    appended, flushes handed to the OS and the seconds they took, `get`s
+    answered from the file. The memory store keeps no such record."""
+    return {"rows": 0, "bytes": 0, "flushes": 0, "flush_s": 0.0, "gets": 0}
+
+
 def decode_int_key(key: bytes) -> int:
     return int.from_bytes(key, "big")
 

@@ -7,7 +7,14 @@ the node until killed. A 4-node localhost pool is four of these processes
 scripted version.
 
     python -m plenum_tpu.tools.start_node --name Node1 --base-dir /tmp/pool \
-        [--backend cpu|jax] [--kv file|memory]
+        [--backend cpu|jax|service] [--kv file|memory|native|chunked]
+
+--kv file is "durable, on the best engine": the native log-structured store
+(storage/kv_native.py), or the Python KvFile where the native library did
+not build. Which one a validator got is in its start line (`engine`) and in
+VALIDATOR_INFO (`recovery`). A start that finds ledgers on disk reconciles
+its stores, catches up with the pool, and only then prints the start line,
+with what it recovered (docs/durability.md).
 """
 from __future__ import annotations
 
@@ -17,6 +24,11 @@ import json
 import os
 import time
 from collections import deque
+
+
+# how long a restarted validator waits for its catch-up before it serves
+# anyway (its peers may be down; the node keeps trying meanwhile)
+REJOIN_WAIT_S = 60.0
 
 
 class _DurableSpylog(deque):
@@ -351,10 +363,20 @@ def main(argv=None):
     looper.add(prodable)
 
     async def forever():
-        print(json.dumps({"started": args.name,
-                          "node_port": prodable.node_stack.port,
-                          "client_port": prodable.client_stack.port}),
-              flush=True)
+        recovery = node.recovery
+        if recovery is not None and recovery["restarted"]:
+            # ledgers on disk: first to where the pool is, then serve
+            t0 = time.monotonic()
+            node.rejoin_after_restart()
+            while node.rejoining and time.monotonic() - t0 < REJOIN_WAIT_S:
+                await asyncio.sleep(0.02)
+            recovery["seconds"]["rejoin"] = round(time.monotonic() - t0, 3)
+        started = {"started": args.name,
+                   "node_port": prodable.node_stack.port,
+                   "client_port": prodable.client_stack.port}
+        if recovery is not None:
+            started.update(engine=recovery["engine"], recovery=recovery)
+        print(json.dumps(started), flush=True)
         last_status = time.monotonic()
         while True:
             await asyncio.sleep(0.25)
