@@ -312,7 +312,7 @@ class CatchupRepService:
                     return
                 self._request_missing()
                 return
-            committed, _ = ledger.commit_txns(len(txns))
+            committed = ledger.commit_txns(len(txns))
             for txn in committed:
                 self._on_txn_added(self.ledger_id, txn)
         if ledger.size >= self._target_size:

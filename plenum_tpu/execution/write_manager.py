@@ -434,7 +434,7 @@ class WriteRequestManager:
                 f"got {batch.pp_seq_no}")
         undo = self._batches.pop(0)
         ledger = self.db.get_ledger(undo.ledger_id)
-        committed, _ = ledger.commit_txns(undo.n_txns)
+        committed = ledger.commit_txns(undo.n_txns)
         state = self.db.get_state(undo.ledger_id)
         if state is not None:
             state.commit(batch.state_root or None)

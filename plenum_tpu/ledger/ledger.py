@@ -1,8 +1,9 @@
 """Append-only transaction ledger: KV txn log + compact Merkle tree.
 
 Reference behavior: ledger/ledger.py:17 — txns keyed by 1-based seq_no in a KV
-log, every append updates the Merkle tree and returns merkle info (root + audit
-path); supports an uncommitted staging area (appendTxns → commitTxns /
+log, every append updates the Merkle tree (unlike the reference it returns no
+merkle info: a proof is built by `merkle_info` when GET_TXN asks for one);
+supports an uncommitted staging area (appendTxns → commitTxns /
 discardTxns) used by 3PC dynamic validation, genesis loading, and recovery from
 the hash store with txn-log replay as fallback (ledger.py:70-113).
 
@@ -98,11 +99,14 @@ class Ledger:
 
     # --- committed appends ------------------------------------------------
 
-    def append(self, txn: dict) -> dict:
-        """Append one committed txn; returns merkle info for the REPLY."""
-        return self.append_batch([txn])[0]
+    def append(self, txn: dict) -> None:
+        """Append one committed txn (`append_batch` of one)."""
+        self.append_batch([txn])
 
-    def append_batch(self, txns: Sequence[dict]) -> list[dict]:
+    def append_batch(self, txns: Sequence[dict]) -> None:
+        """Append committed txns: txn-log rows, leaf and interior hashes,
+        `seq_no`. Reads nothing back and builds no audit path: no REPLY
+        carries one, and `merkle_info` builds a proof on demand."""
         leaves = [txn_to_leaf(t) for t in txns]
         start = self.seq_no
         # one atomic KV batch for the txn-log rows and one for the Merkle
@@ -114,7 +118,6 @@ class Ledger:
         with self.tree.hash_store.kv.write_batch():
             self.tree.extend_batch(leaves)
         self.seq_no += len(txns)
-        return [self.merkle_info(start + 1 + i) for i in range(len(txns))]
 
     @property
     def txn_log(self) -> KeyValueStorage:
@@ -154,16 +157,16 @@ class Ledger:
             self._uncommitted_tree.extend_batch(
                 [txn_to_leaf(t) for t in pending])
 
-    def commit_txns(self, count: int) -> tuple[list[dict], list[dict]]:
-        """Commit the first `count` staged txns; returns (txns, merkle_infos)."""
+    def commit_txns(self, count: int) -> list[dict]:
+        """Commit the first `count` staged txns; returns them."""
         if count > len(self._uncommitted):
             raise ValueError(f"commit {count} > {len(self._uncommitted)} staged")
         txns = self._uncommitted[:count]
         self._uncommitted = self._uncommitted[count:]
         self._uncommitted_tree = None
         self._shadow_pending = []
-        infos = self.append_batch(txns)
-        return txns, infos
+        self.append_batch(txns)
+        return txns
 
     def discard_txns(self, count: int) -> None:
         """Drop the LAST `count` staged txns (revert on 3PC reject)."""

@@ -10,6 +10,10 @@ import random
 
 import pytest
 
+from plenum_tpu.ledger.compact_merkle_tree import CompactMerkleTree
+from plenum_tpu.ledger.hash_store import HashStore
+from plenum_tpu.ledger.ledger import Ledger, txn_to_leaf
+from plenum_tpu.ledger.merkle_verifier import MerkleVerifier
 from plenum_tpu.storage.kv_memory import KvMemory
 from plenum_tpu.storage.kv_native import KvNative, native_available
 
@@ -109,3 +113,64 @@ def test_compaction_shrinks_file_and_preserves_content(tmp_path):
     kv2 = KvNative(str(tmp_path))
     assert kv2.get(b"new") == b"x" and kv2.size == 21
     kv2.close()
+
+
+# --- a ledger over the engine: what a committed write reads -------------------
+
+
+def _native_ledger(path):
+    return Ledger(CompactMerkleTree(hash_store=HashStore(
+        KvNative(str(path / "hashes")))), KvNative(str(path / "log")))
+
+
+def _commit_batches(ledger, batches: int, per_batch: int) -> float:
+    """Stage and commit as 3PC does -> file reads of the hash store a txn."""
+    io = ledger.tree.hash_store.kv.io
+    gets = io["gets"]
+    for _ in range(batches):
+        first = ledger.uncommitted_size
+        ledger.append_txns_to_uncommitted(
+            [{"n": first + i} for i in range(per_batch)])
+        assert len(ledger.commit_txns(per_batch)) == per_batch
+    return (io["gets"] - gets) / (batches * per_batch)
+
+
+def _proofs_hold(ledger, seq_nos) -> None:
+    for seq_no in seq_nos:
+        info = ledger.merkle_info(seq_no)
+        path = ledger.tree.inclusion_proof(seq_no - 1)
+        assert info["auditPath"] == [h.hex() for h in path]
+        assert info["treeSize"] == ledger.size
+        assert MerkleVerifier().verify_inclusion(
+            txn_to_leaf(ledger.get_by_seq_no(seq_no)), seq_no - 1,
+            ledger.size, path, ledger.root_hash)
+
+
+def test_committed_write_reads_the_hash_store_less_than_once(tmp_path):
+    """On a hash store of 16 384 leaves a committed txn costs under one
+    file read (an append's left siblings); with an audit path built per
+    committed txn it cost ~12. A proof is built when it is asked for."""
+    ledger = _native_ledger(tmp_path)
+    ledger.append_batch([{"n": i} for i in range(16384)])
+    assert _commit_batches(ledger, 64, 16) < 1.0
+    assert ledger.size == 16384 + 1024
+    _proofs_hold(ledger, (1, 9000, ledger.size))
+    ledger.close()
+
+
+def test_truncated_ledger_commits_and_proves_as_a_whole_one(tmp_path):
+    """The same after Ledger.truncate and a re-append (the restart's
+    reconcile path: a tail cut back to the last whole batch, then caught
+    up again)."""
+    ledger = _native_ledger(tmp_path)
+    ledger.append_batch([{"n": i} for i in range(16384 + 40)])
+    dropped = ledger.truncate(16384)
+    assert [t["n"] for t in dropped] == list(range(16384, 16384 + 40))
+    assert _commit_batches(ledger, 64, 16) < 1.0
+    assert ledger.size == 16384 + 1024
+    _proofs_hold(ledger, (1, 16384, 16385, ledger.size))
+    whole = _native_ledger(tmp_path / "whole")
+    whole.append_batch([{"n": i} for i in range(16384 + 1024)])
+    assert ledger.root_hash == whole.root_hash
+    whole.close()
+    ledger.close()

@@ -155,8 +155,9 @@ def _txn(i):
 
 def test_ledger_append_and_read(tdir):
     l = Ledger()
-    infos = l.append_batch([_txn(i) for i in range(10)])
+    assert l.append_batch([_txn(i) for i in range(10)]) is None
     assert l.size == 10
+    infos = [l.merkle_info(s) for s in range(1, 11)]
     assert infos[0]["seqNo"] == 1 and infos[9]["seqNo"] == 10
     assert l.get_by_seq_no(5)["txnMetadata"]["seqNo"] == 5
     v = MerkleVerifier()
@@ -186,9 +187,74 @@ def test_ledger_uncommitted_staging():
     assert l.uncommitted_size == 3
     assert l.uncommitted_root_hash == root1
     # commit the rest
-    txns, infos = l.commit_txns(2)
+    txns = l.commit_txns(2)
+    assert txns == [_txn(1), _txn(2)]
     assert l.size == 3 and l.root_hash == root1
+    infos = [l.merkle_info(s) for s in (2, 3)]
     assert [i["seqNo"] for i in infos] == [2, 3]
+
+
+def test_commit_builds_no_audit_path_and_get_txn_builds_one(monkeypatch):
+    """The commit path ends at the append: a WriteRequestManager applies
+    and commits three batches without one call of inclusion_proof (no
+    REPLY carries an audit path), and GET_TXN builds the proof it
+    answers with, which verifies."""
+    from plenum_tpu.common.node_messages import (AUDIT_LEDGER_ID,
+                                                 DOMAIN_LEDGER_ID)
+    from plenum_tpu.common.request import Request
+    from plenum_tpu.execution import (DatabaseManager, ReadRequestManager,
+                                      ThreePcBatch, WriteRequestManager)
+    from plenum_tpu.execution.handlers import GetTxnHandler, NymHandler
+    from plenum_tpu.execution.txn import GET_TXN, NYM, TRUSTEE
+    from plenum_tpu.ledger.ledger import txn_to_leaf
+    from plenum_tpu.state.pruning_state import PruningState
+
+    calls = []
+    real = CompactMerkleTree.inclusion_proof
+    monkeypatch.setattr(
+        CompactMerkleTree, "inclusion_proof",
+        lambda self, m, n=None: calls.append(m) or real(self, m, n))
+    db = DatabaseManager()
+    db.register_ledger(DOMAIN_LEDGER_ID, Ledger(), PruningState())
+    db.register_ledger(AUDIT_LEDGER_ID, Ledger(), None)
+    wm = WriteRequestManager(db)
+    wm.register_handler(NymHandler(db))
+    rm = ReadRequestManager()
+    rm.register_handler(GetTxnHandler(db))
+    trustee = "trusteeTrusteeTrustee1"
+    req_id = 0
+    for pp_seq_no in (1, 2, 3):
+        reqs = []
+        for _ in range(4):
+            req_id += 1
+            dest = trustee if req_id == 1 else "did%019d" % req_id
+            reqs.append(Request(trustee, req_id,
+                                {"type": NYM, "dest": dest, "verkey": "vk",
+                                 "role": TRUSTEE}, signature="sig"))
+        valid, rejected, roots = wm.apply_batch(
+            DOMAIN_LEDGER_ID, reqs, 1000.0 + pp_seq_no, 0, pp_seq_no)
+        assert len(valid) == 4 and not rejected
+        committed = wm.commit_batch(ThreePcBatch(
+            DOMAIN_LEDGER_ID, 0, pp_seq_no, 1000.0 + pp_seq_no,
+            tuple(r.digest for r in valid),
+            bytes.fromhex(roots["state_root"]),
+            bytes.fromhex(roots["txn_root"]),
+            bytes.fromhex(roots["audit_txn_root"])))
+        assert len(committed) == 4
+    ledger = db.get_ledger(DOMAIN_LEDGER_ID)
+    assert ledger.size == 12
+    assert db.get_ledger(AUDIT_LEDGER_ID).size == 3
+    assert calls == []
+    res = rm.get_result(Request("x", 1, {"type": GET_TXN, "data": 7,
+                                         "ledgerId": DOMAIN_LEDGER_ID}))
+    assert len(calls) >= 1
+    proof = res["merkle_proof"]
+    assert proof["seqNo"] == 7 and proof["treeSize"] == 12
+    assert MerkleVerifier().verify_inclusion(
+        txn_to_leaf(res["data"]), 6, 12,
+        [bytes.fromhex(h) for h in proof["auditPath"]],
+        bytes.fromhex(proof["rootHash"]))
+    assert bytes.fromhex(proof["rootHash"]) == ledger.root_hash
 
 
 def test_ledger_uncommitted_root_matches_direct_append():
