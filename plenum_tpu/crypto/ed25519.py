@@ -47,6 +47,11 @@ class _JaxToken:
         self.idxs = idxs
         self.n = n
 
+    @property
+    def lanes(self) -> int:
+        """Padded rows of the dispatch: which program ran it."""
+        return int(self.ok.shape[0])
+
 
 class Ed25519Signer:
     """Deterministic Ed25519 signing from a 32-byte seed.
@@ -263,6 +268,13 @@ class CpuEd25519Verifier(Ed25519Verifier):
         return out
 
 
+# The small bucket: the key table's small size, the rings' first pad bucket,
+# and the lane count of the small verify program a service holds beside its
+# large one (parallel/crypto_service.py). 64 and 128 lanes take the same
+# 4.7 ms on a v5e (PERF.md section 5), so nothing smaller is worth a load.
+SMALL_LANES = 64
+
+
 def _bytes_avals(m_pad: int, u_pad: int) -> tuple:
     """The abstract signature of one compressed dispatch, as
     `_dispatch_bytes` stages it: S, h, key table, key index, R."""
@@ -313,6 +325,10 @@ class JaxEd25519Verifier(Ed25519Verifier):
         # (m_pad, u_pad) -> the executable preload() obtained for it;
         # _device_verify_bytes looks here before the jitted kernel
         self._preloaded: dict[tuple[int, int], object] = {}
+        # their lane counts, ascending: what _pad_sizes pads to. Replaced
+        # whole by preload(), because a dispatch reads it from another
+        # thread (the service's worker) than the one that preloads
+        self._held_lanes: tuple[int, ...] = ()
 
     def _neg_a_limbs(self, vk: bytes) -> Optional[np.ndarray]:
         if vk in self._pt_cache:
@@ -404,18 +420,35 @@ class JaxEd25519Verifier(Ed25519Verifier):
         ok = self._device_verify_bytes(s_u8, h_u8, k_u8, idx, r_u8)
         return _JaxToken(ok, idxs, n)
 
+    @property
+    def min_batch(self) -> int:
+        return self._min_batch
+
+    @staticmethod
+    def _program_for(m: int, n_keys: int) -> tuple[int, int]:
+        """The tightest program that fits a wave: rows to the next pow-2;
+        the unique-key table to exactly TWO buckets per batch shape —
+        {64-key, full} — so a drifting active-client count costs at most
+        two multi-minute compiles, not one per pow-2 step."""
+        m_pad = 1
+        while m_pad < m:
+            m_pad *= 2
+        small = min(SMALL_LANES, m_pad)    # u <= m <= m_pad always holds
+        return m_pad, (small if n_keys <= small else m_pad)
+
     def _pad_sizes(self, m: int, n_keys: int) -> tuple[int, int]:
         """THE batch-shape bucketing policy, shared by both staging paths
         (a divergence would double the compile-shape set): batch rows pad
-        to the next pow-2 >= min_batch; the unique-key table pads to
-        exactly TWO buckets per batch shape — {64-key, full} — so a
-        drifting active-client count costs at most two multi-minute
-        compiles, not one per pow-2 step."""
-        m_pad = 1
-        while m_pad < max(m, self._min_batch):
-            m_pad *= 2
-        small = min(64, m_pad)             # u <= m <= m_pad always holds
-        return m_pad, (small if n_keys <= small else m_pad)
+        to the smallest lane count among the programs preload() HOLDS
+        that fits the wave; where none is held or none fits, to the next
+        pow-2 >= min_batch. The key table keeps its two buckets a batch
+        shape. So a service that holds 64 and 512 lanes runs a wave of
+        nine in the 64-lane program; a ring packs its waves to its own
+        pinned ladder before they get here, so its m is a held lane
+        count already."""
+        m_pad = next((lanes for lanes in self._held_lanes if lanes >= m),
+                     max(m, self._min_batch))
+        return self._program_for(m_pad, n_keys)
 
     def _device_verify_bytes(self, s_u8, h_u8, k_u8, idx, r_u8):
         kernel = self._preloaded.get((s_u8.shape[0], k_u8.shape[0]),
@@ -429,7 +462,7 @@ class JaxEd25519Verifier(Ed25519Verifier):
         than compile. Touches the backend: the chip's owner only."""
         return {shape: _aot.has_entry(_ops.verify_kernel_bytes,
                                       _bytes_avals(*shape), self.device)
-                for shape in sorted({self._pad_sizes(n, keys)
+                for shape in sorted({self._program_for(n, keys)
                                      for n, keys in waves})}
 
     def preload(self, waves: Iterable[tuple[int, int]]) -> list:
@@ -441,6 +474,10 @@ class JaxEd25519Verifier(Ed25519Verifier):
         prove each one answers; a shape nobody preloaded still traces
         and compiles on its first dispatch.
         -> the (m_pad, u_pad) shapes obtained by this call.
+
+        Each wave asks for the tightest program that fits it, whatever
+        min_batch says: what is held here is what `_pad_sizes` pads to,
+        and min_batch is only the floor where nothing held fits.
 
         Stored programs are loaded ON THE CALLING THREAD, one after the
         other; the rest compile at once, one thread each (XLA compiles
@@ -474,9 +511,14 @@ class JaxEd25519Verifier(Ed25519Verifier):
             except _aot.ClaimedElsewhere:
                 return None
 
+        def hold(shape, exe):
+            self._preloaded[shape] = exe
+            self._held_lanes = tuple(sorted(
+                {lanes for lanes, _ in self._preloaded}))
+
         for shape, held in stored.items():
             if held:
-                self._preloaded[shape] = obtain(shape)
+                hold(shape, obtain(shape))
         missing = [shape for shape, held in stored.items() if not held]
         if missing:
             with ThreadPoolExecutor(len(missing)) as pool:
@@ -485,7 +527,7 @@ class JaxEd25519Verifier(Ed25519Verifier):
                 # another process of this machine is compiling that one
                 # (validators started together want the same programs):
                 # wait for its entry and load it HERE, not in a worker
-                self._preloaded[shape] = exe or obtain(shape)
+                hold(shape, exe or obtain(shape))
         return list(stored)
 
     def _dispatch_limbs(self, items: Sequence[VerifyItem]):
@@ -587,8 +629,9 @@ class JaxEd25519Verifier(Ed25519Verifier):
 def make_verifier(backend: str, min_batch: int = 1,
                   supervised: Optional[bool] = None) -> Ed25519Verifier:
     """min_batch (jax only): pad every dispatch to at least this power of
-    two. A pool node should pick one bucket covering its receive quotas so
-    XLA compiles exactly ONE program shape — a recompile at a novel shape
+    two, unless a smaller program that fits it was preloaded (`_pad_sizes`).
+    A pool node should pick one bucket covering its receive quotas so
+    no novel program shape appears under load — a recompile at one
     costs minutes (tracing + XLA:TPU compilation) and starves the prod loop.
 
     Every DEVICE-backed verifier (jax, jax-sharded, service) comes wrapped

@@ -21,10 +21,12 @@ for node A is free for nodes B..N.
 Wire: 4-byte big-endian length frames, msgpack maps.
   request  {"id": u64, "items": [[msg, sig, vk], ...]}
   reply    {"id": u64, "verdicts": [0|1, ...]}
-  request  {"op": "stats"} -> server counters (ops tooling), the device
-           the owner process runs on as JAX reports it ("device":
-           {platform, kind, count}; null for the cpu backend) and the
-           process's compile counters ("compile": plenum_tpu.ops
+  request  {"op": "stats"} -> server counters (ops tooling; among them
+           "dispatches_by_lanes": {"64": n, "512": m}, the device
+           dispatches by the padded lane count of the program that ran
+           each), the device the owner process runs on as JAX reports it
+           ("device": {platform, kind, count}; null for the cpu backend)
+           and the process's compile counters ("compile": plenum_tpu.ops
            .compile_stats()).
   request  {"id": u64, "items": [...], "wave": 1} -> verdicts; the batch
            dispatches VERBATIM as its own wave (no dedup/coalescing, pad
@@ -38,7 +40,12 @@ Wire: 4-byte big-endian length frames, msgpack maps.
            each bucket's FULL key-table shape (a wave of all-distinct
            verkeys) — what a plain client's coalesced waves dispatch
            once more than 64 signers share one. A warm wave the device
-           did not answer (raised, hedged) is an error reply.
+           did not answer (raised, hedged) is an error reply. A
+           device-backed inner whose min_batch is above the small bucket
+           (64 lanes) also obtains and proves the small program, in the
+           same preload: the inner pads each wave to the smallest program
+           it holds, so short waves run there and not in min_batch lanes.
+           "warmed" names only buckets that were asked for.
   request  {"id": u64, "op": "pin"} -> {"id", "pinned"}: warmup over.
 
 Server:  python -m plenum_tpu.parallel.crypto_service --socket PATH \
@@ -63,7 +70,8 @@ from typing import Optional, Sequence
 import numpy as np
 
 from plenum_tpu.common.serialization import pack, unpack
-from plenum_tpu.crypto.ed25519 import Ed25519Verifier, VerifyItem
+from plenum_tpu.crypto.ed25519 import (SMALL_LANES, Ed25519Verifier,
+                                       VerifyItem)
 
 _LEN = struct.Struct(">I")
 MAX_FRAME = 64 * 1024 * 1024
@@ -107,6 +115,9 @@ class CryptoPlaneServer:
         self._cache_size = cache_size
         self.stats = {"batches": 0, "items": 0, "cache_hits": 0,
                       "dispatches": 0, "dispatched_items": 0}
+        # padded lane count -> device dispatches the program of that size
+        # ran (host verdicts have no lanes); the worker writes, stats reads
+        self._by_lanes: collections.Counter = collections.Counter()
         self._server = None
         self._worker: Optional[threading.Thread] = None
         self._stop = threading.Event()
@@ -135,6 +146,19 @@ class CryptoPlaneServer:
         `_bucketed`, answered server-side during prewarm negotiation)."""
         from plenum_tpu.parallel.pipeline import _device_backed
         return _device_backed(self._inner)
+
+    def _small_bucket(self) -> Optional[int]:
+        """Lanes of the small verify program every prewarm holds beside
+        the buckets it is asked for, or None: a device inner pads a wave
+        to the smallest program it holds (`_pad_sizes`), and one started
+        with a large min_batch would hold nothing else and run a wave of
+        nine signatures in 512 lanes. A host inner pads nothing, and a
+        min_batch at or under the small bucket is small enough."""
+        from plenum_tpu.parallel.pipeline import _device_verifier
+        dev = _device_verifier(self._inner)
+        if dev is None or dev.min_batch <= SMALL_LANES:
+            return None
+        return SMALL_LANES
 
     def _drain(self, first) -> list:
         jobs = [first]
@@ -209,6 +233,9 @@ class CryptoPlaneServer:
                 recent[wave["seq"]] = verdicts
             else:
                 self.stats["dispatches"] += 1
+                lanes = getattr(wave["token"], "lanes", None)
+                if lanes is not None:
+                    self._by_lanes[lanes] += 1
                 # wave frames dispatch verbatim (pads included), so their
                 # honest width is the batch, not the distinct digests
                 self.stats["dispatched_items"] += wave.get(
@@ -417,7 +444,12 @@ class CryptoPlaneServer:
         try:
             if req.get("op") == "stats":
                 from plenum_tpu.ops import compile_stats
+                # dict() of a dict is one step under the GIL: the worker
+                # may add a lane count meanwhile
+                by_lanes = sorted(dict(self._by_lanes).items())
                 out = dict(self.stats, cache_size=len(self._cache),
+                           dispatches_by_lanes={str(k): v
+                                                for k, v in by_lanes},
                            device=self.device, compile=compile_stats())
                 sup = getattr(self._inner, "supervisor_stats", None)
                 if callable(sup):
@@ -439,7 +471,8 @@ class CryptoPlaneServer:
                     fallback_growth, find_supervisor)
                 sup = find_supervisor(self._inner)
                 waves = []
-                for b in [int(x) for x in req.get("buckets", []) if x]:
+                buckets = [int(x) for x in req.get("buckets", []) if x]
+                for b in buckets:
                     waves.append((b, [PREWARM_ITEM] * b))
                     if req.get("full_keys"):
                         # b distinct (junk) verkeys: past 64 of them the
@@ -447,6 +480,9 @@ class CryptoPlaneServer:
                         waves.append((b, [
                             (*PREWARM_ITEM[:2], i.to_bytes(32, "little"))
                             for i in range(b)]))
+                small = self._small_bucket()
+                if small is not None and small not in buckets:
+                    waves.append((small, [PREWARM_ITEM] * small))
                 # every wave's program first: the executable store loads
                 # what this machine compiled before, the rest compile at
                 # once. ON THIS THREAD, the loop's and the process's main
@@ -479,7 +515,7 @@ class CryptoPlaneServer:
                         payload = pack({"id": rid, "error":
                                         f"prewarm bucket {b}: {result}"})
                         break
-                    if b not in warmed:
+                    if b in buckets and b not in warmed:
                         warmed.append(b)
                 if payload is None:
                     self.stats["prewarms"] = \
@@ -881,7 +917,8 @@ class FederatedEd25519Client(ServiceEd25519Verifier):
         # the cold ceiling per compile, not the per-item budget: this
         # request IS the multi-minute first-compile the budget's cold_max
         # exists for
-        n_waves = len(want) * (2 if full_keys else 1)
+        # + 1: a device-backed remote adds its small program's wave
+        n_waves = len(want) * (2 if full_keys else 1) + 1
         return self._rpc({"op": "prewarm", "buckets": want,
                           "full_keys": int(full_keys)},
                          n_items=max(1, sum(want)),

@@ -64,7 +64,7 @@ from __future__ import annotations
 
 import hashlib
 import time
-from collections import OrderedDict, deque
+from collections import Counter, OrderedDict, deque
 from typing import Optional, Sequence
 
 import numpy as np
@@ -85,22 +85,26 @@ KIND_CMT = "cmt"                 # state-commitment updates / proof gen
 _CTL_WINDOW = 256
 
 
-def _device_backed(verifier) -> bool:
-    """Does this verifier chain end in a device (jax) verifier? Walks the
+def _device_verifier(verifier):
+    """The device (jax) verifier this chain ends in, or None. Walks the
     supervisor/coalescer wrappers the same bounded way find_supervisor
     does."""
     from plenum_tpu.crypto.ed25519 import JaxEd25519Verifier
     obj = verifier
     for _ in range(4):
         if isinstance(obj, JaxEd25519Verifier):
-            return True
+            return obj
         if not hasattr(obj, "__dict__"):
-            return False
+            return None
         obj = (obj.__dict__.get("_device")
                or obj.__dict__.get("_inner"))
         if obj is None:
-            return False
-    return False
+            return None
+    return None
+
+
+def _device_backed(verifier) -> bool:
+    return _device_verifier(verifier) is not None
 
 
 # the commit-wave pad ladder every warm-up pins (`prewarm_cmt`): level
@@ -414,6 +418,11 @@ class CryptoPipeline:
             "cmt_waves": 0, "cmt_levels": 0, "cmt_host_fallbacks": 0,
             "unpinned_shapes": 0,
         }
+        # padded lane count -> ed waves the device program of that size
+        # ran, read off the inner's token at dispatch (a wave the CPU
+        # answered has no lanes, and a threaded lane of the multi-device
+        # ring submits from its worker, so its waves are not in here)
+        self._by_lanes: Counter = Counter()
 
     # --- shared plumbing ---------------------------------------------------
 
@@ -797,6 +806,9 @@ class CryptoPipeline:
             lane.dispatch(wave)
         wave.t_dispatched = self._now()
         self.stats["dispatches"] += 1
+        lanes = getattr(wave.inner_tok, "lanes", None)
+        if lanes is not None:
+            self._by_lanes[lanes] += 1
         self.stats["dispatched_items"] += wave.n_real
         self.stats["coalesced_items"] += wave.coalesced
         if lane is not None:
@@ -1275,6 +1287,8 @@ class CryptoPipeline:
         d = self.stats["dispatches"]
         out = {
             "dispatches": d,
+            "dispatches_by_lanes": {str(k): v for k, v
+                                    in sorted(self._by_lanes.items())},
             "dispatched_items": self.stats["dispatched_items"],
             "coalesced_items": self.stats["coalesced_items"],
             "items_per_dispatch": round(

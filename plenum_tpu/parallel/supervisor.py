@@ -240,6 +240,12 @@ class _SupToken:
         self.verdicts = verdicts
         self.budget = budget
 
+    @property
+    def lanes(self) -> Optional[int]:
+        """Padded rows of the device dispatch behind this token; None
+        where none was made and the CPU answered."""
+        return getattr(self.inner, "lanes", None)
+
 
 def _item_bytes(items: Sequence[VerifyItem]) -> int:
     total = 0

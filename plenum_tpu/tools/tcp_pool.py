@@ -210,10 +210,10 @@ def run_tcp_pool(n_nodes: int = 4, n_txns: int = 200, backend: str = "cpu",
             service_env = dict(env)
             if on_device:
                 service_env.pop("JAX_PLATFORMS", None)
-            # a device dispatch pays a fixed launch + transfer cost that
-            # dwarfs padded compute, so the jax plane pads to ONE large
-            # bucket; min_batch only pads — it never waits — so latency
-            # is unaffected
+            # ONE large bucket covers every wave a window can coalesce;
+            # min_batch only pads, it never waits. The service holds the
+            # 64-lane program beside it and runs short waves there (a
+            # 512-lane execution is ~10.6 ms on a v5e, a 64-lane one 4.7)
             min_batch = service_min_batch or (512 if on_device else 128)
             t_setup = time.perf_counter()
             service_proc, started = _start_crypto_service(
@@ -221,7 +221,8 @@ def run_tcp_pool(n_nodes: int = 4, n_txns: int = 200, backend: str = "cpu",
             service = {"device": started.get("device")}
             if on_device:
                 # warm BEFORE traffic: every shape a window <= min_batch
-                # can coalesce into (one row bucket x both key tables). A
+                # can coalesce into (one row bucket x both key tables; the
+                # service adds its small program to the same prewarm). A
                 # compile landing inside a run was measured at 2.7 TPS /
                 # p99 97 s; a warm wave the device did not answer raises.
                 from plenum_tpu.parallel.crypto_service import \

@@ -510,6 +510,133 @@ def test_preload_loads_on_the_calling_thread_and_compiles_in_others(
                                  (256, 64): "exe256"}
 
 
+def _holding(monkeypatch, min_batch, waves):
+    """A verifier whose preload() 'obtained' the programs of `waves`
+    (the store is stubbed: nothing compiles)."""
+    from plenum_tpu.crypto.ed25519 import JaxEd25519Verifier
+    monkeypatch.setattr(aot, "has_entry", lambda *a, **k: True)
+    monkeypatch.setattr(
+        aot, "obtain", lambda jitted, avals, device=None, wait=True:
+        f"exe{avals[0].shape[0]}x{avals[2].shape[0]}")
+    device = JaxEd25519Verifier(min_batch=min_batch)
+    device.preload(waves)
+    return device
+
+
+def _todays_rule(m, n_keys, min_batch):
+    """The padding rule as it stood before a verifier looked at what it
+    holds (PR 33's `_pad_sizes`, word for word)."""
+    m_pad = 1
+    while m_pad < max(m, min_batch):
+        m_pad *= 2
+    small = min(64, m_pad)
+    return m_pad, (small if n_keys <= small else m_pad)
+
+
+@pytest.mark.parametrize("m, n_keys, want", [
+    (1, 1, (64, 64)), (9, 9, (64, 64)), (9, 2, (64, 64)),
+    (64, 1, (64, 64)), (64, 64, (64, 64)),
+    (65, 10, (512, 64)), (65, 65, (512, 512)),
+    (100, 64, (512, 64)), (100, 100, (512, 512)),
+    (512, 3, (512, 64)), (512, 512, (512, 512)),
+    # past every held program: the rule of before
+    (513, 8, (1024, 64)), (600, 600, (1024, 1024))])
+def test_a_service_pads_each_wave_to_the_smallest_program_it_holds(
+        monkeypatch, m, n_keys, want):
+    """The served plane's verifier (`--min-batch 512`) after the prewarm
+    the service gives it: the small program and the large one with both
+    key tables. Rows pad to the smallest HELD lane count that fits; the
+    key table keeps its two buckets a batch shape."""
+    device = _holding(monkeypatch, 512, [(512, 1), (512, 512), (64, 1)])
+    assert sorted(device._preloaded) == [(64, 64), (512, 64), (512, 512)]
+    assert device._pad_sizes(m, n_keys) == want
+
+
+@pytest.mark.parametrize("min_batch", [1, 8, 64, 512])
+@pytest.mark.parametrize("m", [1, 5, 9, 63, 64, 65, 100, 512, 513, 4096])
+def test_a_verifier_that_holds_nothing_pads_as_before(min_batch, m):
+    """No preload, no change: the next power of two >= max(m, min_batch),
+    bit for bit, for every key count a wave of m can carry. So does a
+    verifier whose dispatch is re-routed (the sharded plane, the
+    limb-staged path): its preload obtains nothing."""
+    from plenum_tpu.crypto.ed25519 import JaxEd25519Verifier
+
+    class Limbs(JaxEd25519Verifier):
+        _compressed_dispatch = False
+
+    rerouted = Limbs(min_batch=min_batch)
+    assert rerouted.preload([(64, 1), (512, 1)]) == []
+    for device in (JaxEd25519Verifier(min_batch=min_batch), rerouted):
+        for n_keys in sorted({1, min(m, 64), min(m, 65), m}):
+            assert device._pad_sizes(m, n_keys) \
+                == _todays_rule(m, n_keys, min_batch)
+
+
+@pytest.mark.parametrize("m, n_keys, want", [
+    (64, 1, (64, 64)), (64, 40, (64, 64)), (128, 1, (128, 64)),
+    (128, 64, (128, 64)),
+    # past the ladder: as before (a fresh shape, which pin() counts)
+    (200, 3, (256, 64)), (129, 129, (256, 256)),
+    # a short batch handed to the verifier directly, not through the
+    # ring's packing: the held 64-lane program now, not a fresh 16-lane one
+    (9, 9, (64, 64)), (65, 2, (128, 64))])
+def test_a_rings_verifier_keeps_its_ladder(monkeypatch, m, n_keys, want):
+    """A ring builds its verifier with min_batch 1 and pads its waves to
+    its own pinned ladder (64, 128) before the verifier sees them: the
+    lengths it sends are held lane counts and pad to themselves."""
+    device = _holding(monkeypatch, 1, [(64, 1), (128, 1)])
+    assert sorted(device._preloaded) == [(64, 64), (128, 64)]
+    assert device._pad_sizes(m, n_keys) == want
+
+
+def test_preload_asks_for_the_tightest_program_whatever_min_batch(
+        monkeypatch):
+    """preload() decides what is held, so its waves name programs by
+    their own size: (64, 1) on a min_batch-512 verifier is the 64-lane
+    program, and asking again once 512 is held does not turn it into
+    the 512-lane one."""
+    device = _holding(monkeypatch, 512, [(512, 1)])
+    assert device.in_store([(64, 1), (9, 9), (100, 100)]) \
+        == {(16, 16): True, (64, 64): True, (128, 128): True}
+    assert device.preload([(64, 1), (512, 1)]) == [(64, 64)]
+    assert device.preload([(64, 1)]) == []                  # held already
+    assert sorted(device._preloaded) == [(64, 64), (512, 64)]
+
+
+def test_a_dispatch_pads_while_another_thread_preloads(monkeypatch):
+    """The service preloads on its loop's thread while its worker stages
+    waves: `_pad_sizes` reads the held lane counts as one tuple, never a
+    dict another thread is adding to, and every answer is a held shape
+    that fits the wave."""
+    device = _holding(monkeypatch, 512, [(512, 1)])
+    stop, seen, errors = threading.Event(), set(), []
+
+    def stage_waves():
+        try:
+            while not stop.is_set():
+                seen.add(device._pad_sizes(9, 9))
+        except Exception as e:              # asserted on below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    workers = [threading.Thread(target=stage_waves) for _ in range(4)]
+    try:
+        for w in workers:
+            w.start()
+        for lanes in (8192, 4096, 2048, 1024, 256, 128):
+            device.preload([(lanes, 1)])
+            device.preload([(lanes, lanes)])
+    finally:
+        stop.set()
+        for w in workers:
+            w.join(timeout=30)
+        sys.setswitchinterval(old)
+    assert not any(w.is_alive() for w in workers) and not errors
+    assert device._pad_sizes(9, 9) == (128, 64)
+    assert (512, 64) in seen and seen <= {(512, 64), (256, 64), (128, 64)}
+
+
 def test_preload_leaves_rerouted_dispatch_alone():
     """A subclass that never runs the stored kernel (the sharded plane,
     the limb-staged path, a test double) obtains nothing; a host

@@ -562,6 +562,146 @@ def test_prewarm_rpc_preloads_both_key_tables_before_its_waves(service):
                      ("wave", 16, 16)]
 
 
+def _recording(calls, base, **kwargs):
+    """`base` with its preload and its waves written down; a device
+    (jax) double answers every lane False without a device."""
+    from plenum_tpu.crypto.ed25519 import JaxEd25519Verifier
+
+    class Recording(base):
+        def preload(self, waves):
+            calls.append(("preload", sorted(waves)))
+            return []
+
+        def submit_batch(self, items):
+            calls.append(("wave", len(items),
+                          len({vk for _, _, vk in items})))
+            if issubclass(base, JaxEd25519Verifier):
+                return np.zeros(len(items), dtype=bool)
+            return super().submit_batch(items)
+
+    return Recording(**kwargs)
+
+
+@pytest.mark.parametrize("inner, small", [
+    ("jax-512", True), ("jax-128-supervised", True), ("jax-64", False),
+    ("jax-16", False), ("host", False)])
+def test_prewarm_holds_the_small_program_beside_a_large_min_batch(
+        service, inner, small):
+    """A device-backed inner whose min_batch is above the small bucket
+    gets the 64-lane program in the SAME preload as the buckets asked
+    for (one call, on the loop's thread: a load from any other costs
+    50-75 s), proven by an all-pad wave like the others, before pin is
+    answered. A host inner, or a min_batch of 64 or less, gets nothing
+    added. `warmed` names only what was asked for."""
+    from plenum_tpu.crypto.ed25519 import (CpuEd25519Verifier,
+                                           JaxEd25519Verifier)
+    from plenum_tpu.parallel.crypto_service import FederatedEd25519Client
+    from plenum_tpu.parallel.supervisor import supervise
+    calls = []
+    server, connect = service
+    if inner == "host":
+        server._inner = _recording(calls, CpuEd25519Verifier)
+    else:
+        device = _recording(calls, JaxEd25519Verifier,
+                            min_batch=int(inner.split("-")[1]))
+        server._inner = supervise(device) if "supervised" in inner \
+            else device
+    fed = FederatedEd25519Client(socket_path=connect().socket_path)
+    reply = fed.prewarm([512], full_keys=True)
+    assert reply["warmed"] == [512]
+    assert reply["bucketed"] is (inner != "host")
+    at_prewarm = list(calls)
+    assert fed.pin()["pinned"] is True
+    fed.close()
+    assert calls == at_prewarm              # nothing is obtained after pin
+    want_waves = [(512, 1), (512, 512)] + ([(64, 1)] if small else [])
+    assert calls == [("preload", sorted(want_waves))] \
+        + [("wave", *w) for w in want_waves]
+
+
+def test_prewarm_asked_for_the_small_bucket_warms_it_once(service):
+    from plenum_tpu.crypto.ed25519 import JaxEd25519Verifier
+    from plenum_tpu.parallel.crypto_service import FederatedEd25519Client
+    calls = []
+    server, connect = service
+    server._inner = _recording(calls, JaxEd25519Verifier, min_batch=512)
+    fed = FederatedEd25519Client(socket_path=connect().socket_path)
+    assert fed.prewarm([64, 512])["warmed"] == [64, 512]
+    fed.close()
+    assert calls == [("preload", [(64, 1), (512, 1)]), ("wave", 64, 1),
+                     ("wave", 512, 1)]
+
+
+def _forged(items, at):
+    msg, sig, vk = items[at]
+    items[at] = (msg, bytes([sig[0] ^ 1]) + sig[1:], vk)    # a bit in R
+    return items
+
+
+@pytest.mark.parametrize("n, signers, lanes", [
+    (9, 4, "64"), (64, 64, "64"), (65, 8, "512"), (100, 100, "512")])
+def test_a_wave_runs_in_the_smallest_held_program_and_is_counted_there(
+        service, monkeypatch, n, signers, lanes):
+    """The served plane as the benchmark starts it (min_batch 512,
+    prewarm [512] x both key tables, pin), the store stubbed with
+    programs that answer from a table of CpuEd25519Verifier's verdicts:
+    a wave of nine lands in the 64-lane program and a wave of a hundred
+    in the 512-lane one, `dispatches_by_lanes` says so, and the verdicts
+    are the CPU's, the forged item's included."""
+    import jax.numpy as jnp
+    from plenum_tpu.crypto.ed25519 import (CpuEd25519Verifier,
+                                           JaxEd25519Verifier)
+    from plenum_tpu.ops import aot
+    from plenum_tpu.parallel.crypto_service import FederatedEd25519Client
+    from plenum_tpu.parallel.supervisor import supervise
+    items = _forged(_make_items(n, signers=signers, tag=b"lanes%d" % n),
+                    n // 2)
+    want = CpuEd25519Verifier().verify_batch(items)
+    assert list(want) == [i != n // 2 for i in range(n)]
+    truth = {sig: bool(ok) for (_, sig, _), ok in zip(items, want)}
+    ran = []
+
+    def program(jitted, avals, device=None, wait=True):
+        shape = (avals[0].shape[0], avals[2].shape[0])
+
+        def run(s, h, keys, idx, r):
+            assert (s.shape[0], keys.shape[0]) == shape
+            ran.append(shape)
+            s, r = np.asarray(s), np.asarray(r)
+            return jnp.asarray([truth.get(bytes(r[j]) + bytes(s[j]), False)
+                                for j in range(shape[0])])
+        return run
+    monkeypatch.setattr(aot, "has_entry", lambda *a, **k: True)
+    monkeypatch.setattr(aot, "obtain", program)
+    server, connect = service
+    server._inner = supervise(JaxEd25519Verifier(min_batch=512))
+    client = connect()
+    fed = FederatedEd25519Client(socket_path=client.socket_path)
+    assert fed.prewarm([512], full_keys=True)["warmed"] == [512]
+    fed.pin()
+    fed.close()
+    assert client.stats()["dispatches_by_lanes"] == {"64": 1, "512": 2}
+    assert ran == [(512, 64), (512, 512), (64, 64)]
+    got = client.verify_batch(items)
+    assert (got == want).all()
+    st = client.stats()
+    by_lanes = {"64": 1, "512": 2}
+    by_lanes[lanes] += 1
+    assert st["dispatches_by_lanes"] == by_lanes
+    assert st["dispatches"] == sum(by_lanes.values())
+    assert ran[-1] == (int(lanes), 64 if signers <= 64 else int(lanes))
+    assert not any(st["plane"][k] for k in ("fallback_batches",
+                                            "device_errors"))
+
+
+def test_a_host_inner_counts_no_lanes(service):
+    server, connect = service
+    client = connect()
+    assert client.verify_batch(_make_items(9, tag=b"host-lanes")).all()
+    st = client.stats()
+    assert st["dispatches"] == 1 and st["dispatches_by_lanes"] == {}
+
+
 def test_federated_pipeline_rides_remote_lane(service):
     """End-to-end: a FederatedCryptoPipeline with one REAL remote lane
     over the service socket — prewarm negotiation turns padding off for

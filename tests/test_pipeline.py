@@ -255,6 +255,58 @@ def test_real_jax_wave_verdicts():
     assert list(CpuEd25519Verifier().verify_batch(items)) == list(got)
 
 
+@pytest.mark.parametrize("supervised", [False, True])
+def test_ring_counts_its_dispatches_by_the_program_that_ran(monkeypatch,
+                                                            supervised):
+    """`summary()` (VALIDATOR_INFO `plane.ring` where a node owns its
+    chip) carries `dispatches_by_lanes`, counted where `dispatches` is,
+    from the lane count of the program behind each wave's token. The
+    ring pads to its own pinned ladder, so the verifier's rule (pad to
+    the smallest held program) finds each wave at a held length."""
+    import jax.numpy as jnp
+    from plenum_tpu.ops import aot
+    from plenum_tpu.parallel.supervisor import supervise
+    ran = []
+
+    def program(jitted, avals, device=None, wait=True):
+        shape = (avals[0].shape[0], avals[2].shape[0])
+
+        def run(s, h, keys, idx, r):
+            ran.append((s.shape[0], keys.shape[0]))
+            return jnp.ones(shape[0], dtype=bool)
+        return run
+    monkeypatch.setattr(aot, "has_entry", lambda *a, **k: True)
+    monkeypatch.setattr(aot, "obtain", program)
+    device = JaxEd25519Verifier(min_batch=1)
+    pipe = CryptoPipeline(
+        ed_inner=supervise(device) if supervised else device,
+        config=_fast_config())
+    assert pipe.prewarm([16, 32]) == [16, 32]
+    pipe.pin()
+    assert sorted(device._preloaded) == [(16, 16), (32, 32)]
+    assert pipe.summary()["dispatches_by_lanes"] == {}  # warm-up is not
+    rng = random.Random(5)                              # a ring dispatch
+    for size in (3, 20, 9, 16):
+        tok = pipe.submit_verify(_junk_items(rng, size))
+        pipe.flush()
+        assert pipe.collect_verify(tok) is not None
+    summary = pipe.summary()
+    assert summary["dispatches_by_lanes"] == {"16": 3, "32": 1}
+    assert summary["dispatches"] == 4 and summary["unpinned_shapes"] == 0
+    assert ran[2:] == [(16, 16), (32, 32), (16, 16), (16, 16)]
+
+
+def test_a_ring_over_a_host_double_counts_no_lanes():
+    rng = random.Random(6)
+    pipe = CryptoPipeline(ed_inner=FakeDeviceVerifier(),
+                          config=_fast_config())
+    tok = pipe.submit_verify(_junk_items(rng, 5))
+    pipe.flush()
+    assert pipe.collect_verify(tok) is not None
+    assert pipe.summary()["dispatches"] == 1
+    assert pipe.summary()["dispatches_by_lanes"] == {}
+
+
 def test_double_buffer_packs_while_inflight():
     """Host packs wave N+1 while the device runs wave N; the packed wave
     dispatches the moment N resolves — without any new flush call."""
