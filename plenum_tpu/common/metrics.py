@@ -25,16 +25,12 @@ class MetricsName:
     """Namespaced metric names (subset of the reference's ~300, the ones this
     node actually emits; extend freely — collectors are name-agnostic)."""
     # node event loop
-    PROD_TIME = "node.prod_time"
     CLIENT_MSGS = "node.client_msgs"
     PROPAGATES = "node.propagates"
     ORDERED_BATCH_SIZE = "node.ordered_batch_size"
     EXECUTE_BATCH_TIME = "node.execute_batch_time"
     BACKUP_ORDERED = "node.backup_ordered"
     # crypto planes
-    SIG_BATCH_SIZE = "crypto.sig_batch_size"
-    SIG_BATCH_TIME = "crypto.sig_batch_time"
-    BLS_VERIFY_TIME = "crypto.bls_verify_time"
     # pairing accounting (cumulative bn254.PAIRING_STATS gauges sampled at
     # flush, read back via max like gc_pause_time) + the per-ordered-batch
     # Miller-loop count the batched-BLS acceptance rides on
@@ -67,6 +63,20 @@ class MetricsName:
     COMMIT_APPLY_TIME = "commit_path.apply_time"
     COMMIT_DURABLE_TIME = "commit_path.durable_time"
     COMMIT_REPLY_TIME = "commit_path.reply_time"
+    # a write's residence on this node, split where the span sites are
+    # (common/tracing.py StageClock): seconds on perf_counter, sampled,
+    # `count` and `sum` weighted per request (a batch-keyed stage adds its
+    # span once a request the batch carries, and one sample a batch), so
+    # the seven waits of the requests that have them all sum to
+    # `residence`
+    STAGE_INBOX_WAIT = "stage.inbox_wait"
+    STAGE_AUTH_WAIT = "stage.auth_wait"
+    STAGE_PROPAGATE_WAIT = "stage.propagate_wait"
+    STAGE_QUEUE_WAIT = "stage.queue_wait"
+    STAGE_ORDERING_WAIT = "stage.ordering_wait"
+    STAGE_COMMIT_WAIT = "stage.commit_wait"
+    STAGE_REPLY_WAIT = "stage.reply_wait"
+    STAGE_RESIDENCE = "stage.residence"
     # durable stores only (the memory store flushes nothing): of one group
     # commit, the seconds spent closing the write batches (one flush a
     # store), and the stores' own cumulative counters, sampled at flush
@@ -203,7 +213,6 @@ class MetricsName:
     SUSPICIONS = "consensus.suspicions"
     BACKUP_INSTANCE_REMOVED = "consensus.backup_instance_removed"
     CATCHUPS = "consensus.catchups"
-    MASTER_3PC_BATCH_TIME = "consensus.master_3pc_batch_time"
     # per-phase 3PC timings on the master (perf debugging: where does a
     # batch spend its life — prepare quorum, commit quorum, or end to end)
     PREPARE_PHASE_TIME = "consensus.prepare_phase_time"
@@ -237,9 +246,6 @@ class MetricsName:
     CLIENT_INBOX_DEPTH = "node.client_inbox_depth"
     PROPAGATE_INBOX_DEPTH = "node.propagate_inbox_depth"
     REQUEST_QUEUE_DEPTH = "consensus.request_queue_depth"
-    # shared crypto plane
-    SIG_BATCH_FILL_TIME = "crypto.sig_batch_fill_time"
-    SIG_DISPATCH_TIME = "crypto.sig_dispatch_time"
     # fused crypto pipeline (parallel/pipeline.py): one event per device
     # wave (coalesced caller items riding it, occupancy at dispatch, pad
     # waste), cumulative dedup/dispatch gauges sampled at flush, and the
@@ -279,8 +285,6 @@ class MetricsName:
     PIPELINE_FED_REMOTE_BREAKERS_OPEN = "pipeline_fed.remote_breakers_open"
     PIPELINE_FED_SHIP_MS_P95 = "pipeline_fed.ship_ms_p95"
     # transport
-    NODE_MSGS_IN = "transport.node_msgs_in"
-    NODE_FRAMES_OUT = "transport.node_frames_out"
     # silent-loss accounting + byte totals, sampled from TcpStack.stats as
     # cumulative gauges (read back via max, like gc_pause_time); per-type
     # rows flush under dynamic names "transport.tx.<OP>" / "transport.rx.<OP>"
@@ -419,6 +423,10 @@ SAMPLED_NAMES = frozenset({
     MetricsName.COMMIT_WAVE_TIME,
     MetricsName.COMMIT_DURABLE_TIME, MetricsName.COMMIT_REPLY_TIME,
     MetricsName.STORAGE_FLUSH_TIME,
+    MetricsName.STAGE_INBOX_WAIT, MetricsName.STAGE_AUTH_WAIT,
+    MetricsName.STAGE_PROPAGATE_WAIT, MetricsName.STAGE_QUEUE_WAIT,
+    MetricsName.STAGE_ORDERING_WAIT, MetricsName.STAGE_COMMIT_WAIT,
+    MetricsName.STAGE_REPLY_WAIT, MetricsName.STAGE_RESIDENCE,
     MetricsName.BLS_PAIRINGS_PER_BATCH,
     MetricsName.CRYPTO_DISPATCH_BUDGET,
     MetricsName.PIPELINE_VERDICT_WAIT,
@@ -454,6 +462,17 @@ def percentile(values, q: float) -> Optional[float]:
     return ordered[idx]
 
 
+def span_report(count: int, total_s: float, samples) -> dict:
+    """How a timed wait is reported (VALIDATOR_INFO `stages`, the crypto
+    service's `waits`): cumulative count and seconds, so a window's mean
+    is a growth, and the reservoir's quantiles."""
+    def ms(v):
+        return None if v is None else round(v * 1e3, 4)
+    return {"count": count, "sum_s": total_s,
+            "p50_ms": ms(percentile(samples, 0.5)),
+            "p95_ms": ms(percentile(samples, 0.95))}
+
+
 class Accumulator:
     """Fold of all events for one name since the last flush.
 
@@ -467,31 +486,42 @@ class Accumulator:
     "the earliest events" would be wrong. Seeded + replay-stable: the
     same add() sequence always keeps the same sample set."""
 
-    __slots__ = ("count", "total", "min", "max", "samples", "_rng")
+    __slots__ = ("count", "total", "min", "max", "samples", "_rng",
+                 "_events")
 
     def __init__(self, keep_samples: bool = False, seed: int = 0):
         self.count = 0
+        self._events = 0
         self.total = 0.0
         self.min: Optional[float] = None
         self.max: Optional[float] = None
         self.samples: Optional[list[float]] = [] if keep_samples else None
         self._rng = (seed ^ 0x9E3779B9) & 0xFFFFFFFF
 
-    def add(self, value: float) -> None:
-        self.count += 1
-        self.total += value
-        self.min = value if self.min is None else min(self.min, value)
-        self.max = value if self.max is None else max(self.max, value)
-        if self.samples is not None:
-            if len(self.samples) < SAMPLE_CAP:
-                self.samples.append(value)
+    def add(self, value: float, weight: int = 1) -> None:
+        """`weight` > 1: one event that stands for that many (a batch's
+        span, once a request it carries): `count` and `total` take the
+        weight, the reservoir one sample."""
+        self.count += weight
+        self.total += value * weight
+        self._events += 1
+        if self.min is None:
+            self.min = self.max = value
+        elif value < self.min:
+            self.min = value
+        elif value > self.max:
+            self.max = value
+        samples = self.samples
+        if samples is not None:
+            if len(samples) < SAMPLE_CAP:
+                samples.append(value)
             else:
                 # Algorithm R: event i (1-based) replaces a reservoir slot
                 # with probability CAP/i — a uniform sample over all events
                 self._rng = (self._rng * 1664525 + 1013904223) & 0xFFFFFFFF
-                j = self._rng % self.count
+                j = self._rng % self._events
                 if j < SAMPLE_CAP:
-                    self.samples[j] = value
+                    samples[j] = value
 
     def to_dict(self) -> dict:
         avg = self.total / self.count if self.count else 0.0
@@ -509,7 +539,8 @@ class MetricsCollector:
         self._now = now or time.time
         self.accumulators: dict[str, Accumulator] = {}
 
-    def add_event(self, name: str, value: float = 1.0) -> None:
+    def add_event(self, name: str, value: float = 1.0,
+                  weight: int = 1) -> None:
         acc = self.accumulators.get(name)
         if acc is None:
             keep = name in SAMPLED_NAMES
@@ -518,7 +549,7 @@ class MetricsCollector:
             acc = self.accumulators[name] = Accumulator(
                 keep_samples=keep,
                 seed=zlib.crc32(name.encode()) if keep else 0)
-        acc.add(value)
+        acc.add(value, weight)
 
     @contextmanager
     def measure_time(self, name: str):
@@ -539,7 +570,8 @@ class MetricsCollector:
 class NullMetricsCollector(MetricsCollector):
     """Zero-cost sink for benchmarks that must not pay the dict updates."""
 
-    def add_event(self, name: str, value: float = 1.0) -> None:
+    def add_event(self, name: str, value: float = 1.0,
+                  weight: int = 1) -> None:
         pass
 
     @contextmanager

@@ -62,7 +62,7 @@ class OrderingService:
                  bls: Optional[BlsBftReplica] = None,
                  config: Optional[Config] = None,
                  get_request: Optional[Callable[[str], Optional[Request]]] = None,
-                 metrics=None, tracer=None, controller=None):
+                 metrics=None, tracer=None, controller=None, stages=None):
         self._data = data
         self._timer = timer
         # per-phase 3PC timing (ref metrics_collector.py's 3PC names):
@@ -71,6 +71,11 @@ class OrderingService:
         # tracing plane: batch-keyed span events (pp send/recv, prepare
         # quorum, commit send, ordered, apply) — master instance only
         self._tracer = tracer if tracer is not None else tracing.NULL_TRACER
+        # the request- and batch-keyed sites among them (pp send/recv,
+        # ordered) go through the node's stage clock: the ring event and
+        # the stage's duration in one call
+        self._stages = (stages if stages is not None
+                        else tracing.NULL_STAGE_CLOCK)
         self._phase_ts: dict[tuple[int, int], list] = {}
         self._bus = bus
         self._network = network
@@ -372,12 +377,7 @@ class OrderingService:
         if self._metrics is not None:
             self._phase_ts[key] = [self._timer.get_current_time(), None]
             self._metrics.add_event(CUT_METRICS[reason], self.cuts[reason])
-        if self._tracer.enabled:
-            # reqs list links request digests -> this batch for waterfall
-            # assembly; seq links the batch -> the durable flush event
-            self._tracer.emit(tracing.PP_SENT, pre_prepare.digest,
-                              {"seq": pp_seq_no, "ledger": ledger_id,
-                               "reqs": list(all_digests)})
+        self._stages.pp_sent(pre_prepare)
         batch_id = BatchID(view_no, _orig_view(pre_prepare),
                            pp_seq_no, pre_prepare.digest)
         self._data.preprepare_batch(batch_id)
@@ -601,10 +601,7 @@ class OrderingService:
         self.prePrepares[key] = msg
         if self._metrics is not None:
             self._phase_ts[key] = [self._timer.get_current_time(), None]
-        if self._tracer.enabled:
-            self._tracer.emit(tracing.PP_RECV, msg.digest,
-                              {"seq": msg.pp_seq_no, "frm": sender,
-                               "reqs": list(msg.req_idr)})
+        self._stages.pp_recv(msg, sender)
         self._data.preprepare_batch(batch_id)
         # Commits that raced ahead of this pre-prepare: validate their BLS
         # sigs now that we know the signed roots; evict liars.
@@ -906,10 +903,7 @@ class OrderingService:
             # sample the controller steers depth/size against
             self._controller.note_ordered(
                 self._timer.get_current_time() - t_cut)
-        if self._tracer.enabled:
-            self._tracer.emit(tracing.ORDERED, pp.digest,
-                              {"seq": key[1],
-                               "votes": len(self.commits.get(key, {}))})
+        self._stages.ordered(key, pp, len(self.commits.get(key, {})))
         orig_key = (_orig_view(pp), pp.pp_seq_no)
         rerun = self._ordered_originals.get(orig_key) == pp.digest
         self.ordered.add(key)

@@ -49,7 +49,8 @@ class Replica:
                  ic_vote_store=None,
                  tracer=None,
                  controller=None,
-                 rtt=None):
+                 rtt=None,
+                 stages=None):
         self.name = replica_name(node_name, inst_id)
         self.inst_id = inst_id
         self.config = config or Config()
@@ -74,7 +75,7 @@ class Replica:
             data=self._data, timer=timer, bus=self.internal_bus,
             network=network, executor=executor, bls=bls, config=self.config,
             get_request=get_request, metrics=metrics, tracer=tracer,
-            controller=self.batch_controller)
+            controller=self.batch_controller, stages=stages)
         self.checkpointer = CheckpointService(
             data=self._data, bus=self.internal_bus, network=network,
             config=self.config,

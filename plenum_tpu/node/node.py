@@ -20,6 +20,8 @@ import os
 import time
 from typing import Any, Callable, Optional
 
+import jax
+
 from plenum_tpu.catchup import NodeLeecherService, SeederService
 from plenum_tpu.common.event_bus import ExternalBus
 from plenum_tpu.common.internal_messages import (MissingMessage,
@@ -148,6 +150,12 @@ class LastSentPpStore:
             pass
 
 
+def _phase(name: str, run: Callable):
+    """One phase of a prod cycle as a span on the profiler's host plane."""
+    with jax.profiler.TraceAnnotation(name):
+        return run()
+
+
 class Node:
     def __init__(self, name: str, timer: TimerService, node_bus: ExternalBus,
                  components: NodeComponents,
@@ -175,6 +183,12 @@ class Node:
         # named-metric accumulators (ref common/metrics_collector.py:331);
         # KV-backed collectors get a periodic flush so history survives
         self.metrics = metrics or MetricsCollector()
+        # the stage clock (tracing.StageClock): the request- and
+        # batch-keyed span sites below make ONE call each, which feeds
+        # the ring (when a tracer is attached) and the stage's duration
+        # onto the metrics store (`stage.*`, whole window)
+        self.stages = tracing.make_stage_clock(
+            self.metrics, self.tracer, timer.get_current_time)
         if isinstance(self.metrics, KvMetricsCollector):
             self._metrics_flush_timer = RepeatingTimer(
                 timer, self.config.METRICS_FLUSH_INTERVAL,
@@ -249,7 +263,8 @@ class Node:
             validators=lambda: self.validators,
             request_body=self._request_body,
             digest_gossip=self.config.DIGEST_GOSSIP,
-            tracer=self.tracer)
+            stages=self.stages)
+        self.stages.states = self.propagator.requests.get
         # digest -> targeted body-fetch tries so far (digest-gossip: a
         # quorum can complete before any body-carrying propagate arrives)
         self._body_fetches: dict[str, int] = {}
@@ -329,7 +344,8 @@ class Node:
         self._auth_inflight = None      # (token, items, polls)
         self._prop_inflight = None
         # inboxes (quota-drained each prod; ref zstack quotas config.py:250)
-        self._client_inbox: list[tuple[dict, str]] = []
+        # (message, client, the stage clock's stamp of the append)
+        self._client_inbox: list[tuple[dict, str, Optional[tuple]]] = []
         self._propagate_inbox: list[tuple[Propagate, str]] = []
         self._ordered_queue: list[Ordered] = []
         # digest -> {sender: body_seen}: which propagates we already counted
@@ -1075,7 +1091,8 @@ class Node:
             ic_vote_store=ic_store,
             tracer=self.tracer if inst_id == 0 else None,
             controller=self.batch_controller if inst_id == 0 else None,
-            rtt=self.catchup_rtt if inst_id == 0 else None)
+            rtt=self.catchup_rtt if inst_id == 0 else None,
+            stages=self.stages if inst_id == 0 else None)
         if bls is not None:
             bls.report_bad_signature = lambda sender, r=replica: \
                 r.internal_bus.send(RaisedSuspicion(
@@ -1849,7 +1866,7 @@ class Node:
     # --- ingress ----------------------------------------------------------
 
     def handle_client_message(self, msg: dict, frm: str) -> None:
-        self._client_inbox.append((msg, frm))
+        self._client_inbox.append((msg, frm, self.stages.arrived()))
 
     def submit_preverified(self, request: Request, frm: str) -> None:
         """Ingress-plane seam (ingress/plane.py): the request's signatures
@@ -1862,8 +1879,7 @@ class Node:
         if self.c.read_manager.is_query_type(request.txn_type):
             self._answer_queries([(request, frm)])
             return
-        if self.tracer.enabled:
-            self.tracer.emit(tracing.INGRESS, request.digest, {"frm": frm})
+        self.stages.ingress(request.digest, frm)
         self._settle_client(request, frm, True)
 
     def _receive_propagate(self, msg: Propagate, frm: str) -> None:
@@ -1885,22 +1901,32 @@ class Node:
     # --- the prod loop ----------------------------------------------------
 
     def prod(self) -> int:
-        """One event-loop cycle (ref node.py:1037). Returns work count."""
+        """One event-loop cycle (ref node.py:1037). Returns work count.
+        While a jax.profiler trace is held each phase is a span on its
+        host plane (`prod.*`: what the host was doing while the chip
+        waited); one check a cycle, so an untraced cycle pays ~0.1 us."""
         count = 0
+        traced = jax.profiler.TraceAnnotation.is_enabled()
         if self.c.pipeline is not None:
             # pump the shared ring: resolve a finished device wave,
             # promote the double-buffered packed one, pack the next
             self.c.pipeline.service()
-        n = self._service_client_msgs()
+        n = (_phase("prod.client", self._service_client_msgs) if traced
+             else self._service_client_msgs())
         if n:
             self.metrics.add_event(MetricsName.CLIENT_MSGS, n)
         count += n
-        n = self._service_propagates()
+        n = (_phase("prod.propagates", self._service_propagates) if traced
+             else self._service_propagates())
         if n:
             self.metrics.add_event(MetricsName.PROPAGATES, n)
         count += n
-        self.replicas.service_all()
-        count += self._service_ordered()
+        if traced:
+            _phase("prod.replicas", self.replicas.service_all)
+            count += _phase("prod.ordered", self._service_ordered)
+        else:
+            self.replicas.service_all()
+            count += self._service_ordered()
         # one PropagateBatch per tick instead of one wire message per vote:
         # the n^2 propagate message COUNT amortizes across the whole tick
         self.propagator.flush_outbox()
@@ -1921,7 +1947,7 @@ class Node:
                                      self._client_inbox[quota:])
         to_auth: list[tuple[Request, str]] = []
         queries: list[tuple[Request, str]] = []
-        for msg, frm in batch:
+        for msg, frm, arrived in batch:
             if msg.get("op") == "OBSERVER_REGISTER":
                 # a follower on this client connection wants BatchCommitted
                 # pushes (ref observer/observable.py; the reference wires
@@ -1954,9 +1980,7 @@ class Node:
                         identifier=request.identifier,
                         req_id=request.req_id, reason=e.reason), frm)
                     continue
-                if self.tracer.enabled:
-                    self.tracer.emit(tracing.INGRESS, request.digest,
-                                     {"frm": frm})
+                self.stages.ingress(request.digest, frm, arrived)
                 to_auth.append((request, frm))
             else:
                 self._client_send(RequestNack(
@@ -2039,8 +2063,7 @@ class Node:
                 self._settle_client(preq, pfrm, ok)
 
     def _settle_client(self, req: Request, frm: str, ok: bool) -> None:
-        if self.tracer.enabled:
-            self.tracer.emit(tracing.AUTH, req.digest, {"ok": bool(ok)})
+        entered = self.stages.auth(req.digest, ok)
         if not ok:
             self._client_send(RequestNack(identifier=req.identifier,
                                           req_id=req.req_id,
@@ -2073,7 +2096,7 @@ class Node:
             return
         self._client_send(RequestAck(identifier=req.identifier,
                                      req_id=req.req_id), frm)
-        self.propagator.propagate(req, frm)
+        self.propagator.propagate(req, frm, entered)
 
     def _executed_txn(self, req: Request) -> Optional[dict]:
         """Committed txn for a request that already executed, else None."""
@@ -2296,17 +2319,7 @@ class Node:
                     self.batch_controller.note_durable(
                         self.timer.get_current_time() - t0_timer,
                         len(chunk))
-                if self.tracer.enabled:
-                    # batch linkage rides pp_seq_no (Ordered carries no batch
-                    # digest); wall duration only when the tracer allows it —
-                    # perf_counter deltas are not replay-deterministic
-                    data = {"seqs": [m.pp_seq_no for m in chunk]}
-                    if flushed is not None:
-                        data["rows"] = flushed["rows"]
-                        data["bytes"] = flushed["bytes"]
-                    if self.tracer.wall_durations:
-                        data["dur"] = time.perf_counter() - t0
-                    self.tracer.emit(tracing.DURABLE, "", data)
+                self.stages.durable(chunk, flushed, t0)
                 with self.metrics.measure_time(MetricsName.COMMIT_REPLY_TIME):
                     for msg, committed in zip(chunk, committed_per_msg):
                         self._reply_batch(msg, committed)
@@ -2393,9 +2406,7 @@ class Node:
             state = self.propagator.requests.get(digest) if digest else None
             if state is not None and state.client_name is not None:
                 self._client_send(Reply(result=txn), state.client_name)
-                if self.tracer.enabled and digest:
-                    self.tracer.emit(tracing.REPLY, digest,
-                                     {"seq": msg.pp_seq_no})
+                self.stages.replied(digest, state, msg)
             # Executed state is RETAINED (freed later by the TTL sweep):
             # peers may still MessageReq this PROPAGATE. Durable client-resend
             # dedup lives in the seq-no DB regardless.
@@ -2414,6 +2425,7 @@ class Node:
             # the same retention as executed ones
             self.propagator.requests.mark_executed(digest)
             self._seen_propagates.pop(digest, None)
+        self.stages.retired(msg)
         if msg.ledger_id == POOL_LEDGER_ID:
             self.pool_manager.pool_changed()
 
@@ -2473,4 +2485,7 @@ class Node:
             # on memory stores
             "storage": self._storage_io() if self._durable_kvs else None,
             "recovery": self.recovery,
+            # a write's time on this node by stage (tracing.StageClock):
+            # cumulative count and sum, quantiles since the last flush
+            "stages": self.stages.report(),
         }

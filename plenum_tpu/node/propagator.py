@@ -33,15 +33,16 @@ from typing import Callable, Optional
 from plenum_tpu.common.node_messages import Propagate, PropagateBatch
 from plenum_tpu.common.quorums import Quorums
 from plenum_tpu.common.request import Request
-from plenum_tpu.common.tracing import NULL_TRACER, PROPAGATE_QUORUM
+from plenum_tpu.common.tracing import NULL_STAGE_CLOCK
 
 
 class RequestState:
     __slots__ = ("request", "propagates", "finalised", "forwarded",
                  "client_name", "executed", "added_at", "executed_at",
-                 "fetch_started")
+                 "fetch_started", "t_in", "t_mark", "t_sum")
 
-    def __init__(self, request: Optional[Request], added_at: float = 0.0):
+    def __init__(self, request: Optional[Request], added_at: float = 0.0,
+                 t_mark: Optional[float] = None):
         self.request = request                     # None until a body lands
         self.propagates: dict[str, bool] = {}      # sender node -> seen
         self.finalised = False
@@ -51,20 +52,29 @@ class RequestState:
         self.added_at = added_at                   # for unfinalized-state TTL
         self.executed_at: Optional[float] = None   # for executed-state TTL
         self.fetch_started = False                 # body fetch already queued
+        # the stage clock's stamps (common/tracing.py StageClock), on
+        # perf_counter: when a client handed the request to THIS node,
+        # where its last stage ended (first sight, until one ends), and
+        # the stage spans it has collected since `t_in`
+        self.t_in: Optional[float] = None
+        self.t_mark = t_mark
+        self.t_sum = 0.0
 
 
 class Requests(dict):
     """digest -> RequestState (ref propagator.py Requests)."""
 
-    def __init__(self, now: Callable[[], float]):
+    def __init__(self, now: Callable[[], float],
+                 stamp: Callable[[], Optional[float]] = lambda: None):
         super().__init__()
         self._now = now
+        self._stamp = stamp
 
     def add(self, request: Request) -> RequestState:
         state = self.get(request.digest)
         if state is None:
             state = self[request.digest] = RequestState(
-                request, added_at=self._now())
+                request, added_at=self._now(), t_mark=self._stamp())
         elif state.request is None:
             # digest votes arrived first; the body just landed (verified)
             state.request = request
@@ -72,7 +82,8 @@ class Requests(dict):
 
     def add_digest(self, digest: str) -> RequestState:
         if digest not in self:
-            self[digest] = RequestState(None, added_at=self._now())
+            self[digest] = RequestState(None, added_at=self._now(),
+                                        t_mark=self._stamp())
         return self[digest]
 
     def add_propagate(self, request: Request, sender: str) -> RequestState:
@@ -110,11 +121,13 @@ class Propagator:
                  validators: Optional[Callable[[], list]] = None,
                  request_body: Optional[Callable[[str, bool], None]] = None,
                  digest_gossip: bool = True,
-                 tracer=None):
-        self._tracer = tracer if tracer is not None else NULL_TRACER
+                 stages=None):
+        # the node's span sites (tracing.StageClock): the ring's
+        # PROPAGATE_QUORUM event and the propagate stage's duration
+        self._stages = stages if stages is not None else NULL_STAGE_CLOCK
         self.name = name
         self.quorums = quorums
-        self.requests = Requests(now)
+        self.requests = Requests(now, stamp=self._stages.stamp)
         self._send = send_to_nodes
         self._forward = forward_to_replicas
         self._validators = validators or (lambda: [name])
@@ -178,10 +191,16 @@ class Propagator:
     # ingress                                                            #
     # ------------------------------------------------------------------ #
 
-    def propagate(self, request: Request, client_name: Optional[str]) -> None:
+    def propagate(self, request: Request, client_name: Optional[str],
+                  entered: Optional[tuple] = None) -> None:
         """First sight of a finalizable request: record own vote + broadcast.
-        Body is present and signature-verified (client ingress path)."""
+        Body is present and signature-verified (client ingress path).
+        `entered`: the stage clock's stamps of the client copy (arrival,
+        verdict, spans so far), kept if this is the node's first sight."""
+        fresh = entered is not None and request.digest not in self.requests
         state = self.requests.add(request)
+        if fresh:
+            state.t_in, state.t_mark, state.t_sum = entered
         if client_name is not None:
             state.client_name = client_name
         if self.name not in state.propagates:
@@ -236,9 +255,7 @@ class Propagator:
             self._request_body(digest, True)
             return
         state.finalised = True
-        if self._tracer.enabled:
-            self._tracer.emit(PROPAGATE_QUORUM, digest,
-                              {"votes": len(state.propagates)})
+        self._stages.finalised(digest, state)
         if not state.forwarded:
             state.forwarded = True
             self._forward(digest)

@@ -52,7 +52,7 @@ SCHEMA_VERSION = 1
 # the lint read.
 SNAPSHOT_SCHEMA: dict[str, frozenset] = {
     "node": frozenset({
-        MetricsName.PROD_TIME, MetricsName.CLIENT_MSGS,
+        MetricsName.CLIENT_MSGS,
         MetricsName.PROPAGATES, MetricsName.ORDERED_BATCH_SIZE,
         MetricsName.EXECUTE_BATCH_TIME, MetricsName.BACKUP_ORDERED,
         MetricsName.GROUP_COMMIT_BATCHES,
@@ -66,7 +66,6 @@ SNAPSHOT_SCHEMA: dict[str, frozenset] = {
         MetricsName.BATCH_CUT_TIMEOUT, MetricsName.BATCH_CUT_FORCED,
         MetricsName.VIEW_CHANGES, MetricsName.SUSPICIONS,
         MetricsName.BACKUP_INSTANCE_REMOVED, MetricsName.CATCHUPS,
-        MetricsName.MASTER_3PC_BATCH_TIME,
         MetricsName.PREPARE_PHASE_TIME, MetricsName.COMMIT_PHASE_TIME,
         MetricsName.ORDERING_TIME,
         MetricsName.VC_DETECT_TO_VOTE, MetricsName.VC_VOTE_TO_START,
@@ -80,10 +79,14 @@ SNAPSHOT_SCHEMA: dict[str, frozenset] = {
         MetricsName.STORAGE_FLUSH_TIME, MetricsName.STORAGE_ROWS,
         MetricsName.STORAGE_BYTES, MetricsName.STORAGE_FLUSHES,
         MetricsName.STORAGE_FILE_GETS,
+        # a write's residence on the node by stage (tracing.StageClock)
+        MetricsName.STAGE_INBOX_WAIT, MetricsName.STAGE_AUTH_WAIT,
+        MetricsName.STAGE_PROPAGATE_WAIT, MetricsName.STAGE_QUEUE_WAIT,
+        MetricsName.STAGE_ORDERING_WAIT, MetricsName.STAGE_COMMIT_WAIT,
+        MetricsName.STAGE_REPLY_WAIT, MetricsName.STAGE_RESIDENCE,
     }),
     "crypto": frozenset({
-        MetricsName.SIG_BATCH_SIZE, MetricsName.SIG_BATCH_TIME,
-        MetricsName.BLS_VERIFY_TIME, MetricsName.BLS_PAIRING_CHECKS,
+        MetricsName.BLS_PAIRING_CHECKS,
         MetricsName.BLS_PAIRINGS, MetricsName.BLS_PAIRINGS_NATIVE,
         MetricsName.BLS_PAIRINGS_PER_BATCH,
         MetricsName.SIG_PLANE_DISPATCHES,
@@ -93,7 +96,6 @@ SNAPSHOT_SCHEMA: dict[str, frozenset] = {
         MetricsName.CRYPTO_HEDGE_WINS, MetricsName.CRYPTO_DEADLINE_MISSES,
         MetricsName.CRYPTO_DISPATCH_BUDGET,
         MetricsName.BLS_BATCH_FALLBACKS, MetricsName.BLS_LOCAL_FALLBACKS,
-        MetricsName.SIG_BATCH_FILL_TIME, MetricsName.SIG_DISPATCH_TIME,
     }),
     "pipeline": frozenset({
         MetricsName.PIPELINE_DISPATCHES,
@@ -207,8 +209,6 @@ EXEMPT_METRICS: dict[str, str] = {
     MetricsName.GC_GEN2_COLLECTIONS: "host gauge, not a fleet signal",
     MetricsName.GC_UNCOLLECTABLE: "host gauge, not a fleet signal",
     MetricsName.GC_PAUSE_TIME: "host gauge, not a fleet signal",
-    MetricsName.NODE_MSGS_IN: "per-link transport volume",
-    MetricsName.NODE_FRAMES_OUT: "per-link transport volume",
     MetricsName.TRANSPORT_DROPPED_FRAMES: "per-link transport volume",
     MetricsName.TRANSPORT_DROPPED_SESSIONS: "per-link transport volume",
     MetricsName.TRANSPORT_TX_BYTES: "per-link transport volume",

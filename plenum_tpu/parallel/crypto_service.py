@@ -67,8 +67,10 @@ import threading
 import time
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
+from plenum_tpu.common.metrics import Accumulator, span_report
 from plenum_tpu.common.serialization import pack, unpack
 from plenum_tpu.crypto.ed25519 import (SMALL_LANES, Ed25519Verifier,
                                        VerifyItem)
@@ -118,9 +120,24 @@ class CryptoPlaneServer:
         # padded lane count -> device dispatches the program of that size
         # ran (host verdicts have no lanes); the worker writes, stats reads
         self._by_lanes: collections.Counter = collections.Counter()
+        # how long one node's batch waits HERE, in two parts: put on the
+        # queue -> taken by the worker, and taken -> its verdicts handed
+        # back (a pure cache hit: 0; a job riding a wave: until it lands).
+        # The worker adds, `stats` reads, `pin` starts them afresh
+        self._waits = self._new_waits()
         self._server = None
         self._worker: Optional[threading.Thread] = None
         self._stop = threading.Event()
+
+    @staticmethod
+    def _new_waits() -> dict:
+        return {"queue": Accumulator(keep_samples=True, seed=1),
+                "wave": Accumulator(keep_samples=True, seed=2)}
+
+    def _waits_report(self) -> dict:
+        # list(): one step under the GIL, the worker may add meanwhile
+        return {name: span_report(acc.count, acc.total, list(acc.samples))
+                for name, acc in self._waits.items()}
 
     # --- worker thread: the only place the inner verifier runs ----------
 
@@ -186,13 +203,17 @@ class CryptoPlaneServer:
         recent: dict[int, object] = {}   # landed seq -> verdicts | error str
         next_seq = 1
 
-        def _finish(done, plan):
+        def _finish(done, plan, taken=None):
             """Resolve one job from its plan: ('v', verdict) snapshots and
             ('w', seq, digest) waits settled by landed waves. A wait on a
             wave that is NOT in `recent` as a verdict dict (errored, or —
             submit-failure path only — not yet landed) resolves the whole
-            job as an error: the job referenced a failed dispatch."""
+            job as an error: the job referenced a failed dispatch.
+            `taken`: when the worker took the job off the queue (None: it
+            waited for no wave)."""
             self.stats["batches"] += 1
+            self._waits["wave"].add(
+                0.0 if taken is None else time.perf_counter() - taken)
             out, err = [], None
             for entry in plan:
                 if entry[0] == "v":
@@ -216,6 +237,14 @@ class CryptoPlaneServer:
         def _land(block: bool) -> bool:
             """Try to retire the oldest in-flight wave. -> landed?"""
             wave = waves[0]
+            # host phases go onto the profiler's clock while a trace is
+            # held (a TraceMe: an atomic load otherwise)
+            with jax.profiler.TraceAnnotation(
+                    "svc.land", seq=wave["seq"],
+                    lanes=getattr(wave["token"], "lanes", None) or 0):
+                return _land_wave(wave, block)
+
+        def _land_wave(wave, block: bool) -> bool:
             try:
                 verdicts = self._inner.collect_batch(wave["token"],
                                                      wait=block)
@@ -247,8 +276,8 @@ class CryptoPlaneServer:
             for d in wave["todo"]:
                 if pending.get(d) == wave["seq"]:
                     del pending[d]
-            for done, plan in wave["jobs"]:
-                _finish(done, plan)
+            for job in wave["jobs"]:
+                _finish(*job)
             # a job attaches to the LAST wave it references, and references
             # only waves in flight at its intake (>= seq - _MAX_IN_FLIGHT):
             # anything 4 seqs back can no longer be referenced
@@ -261,7 +290,7 @@ class CryptoPlaneServer:
                     del self._cache[k]
             return True
 
-        def _dispatch_raw(done, batch, digests) -> None:
+        def _dispatch_raw(done, batch, digests, taken) -> None:
             """One wave-frame job: the batch dispatches VERBATIM as its
             own wave — no dedup, no coalescing, pad items preserved — so
             the shape the inner sees is exactly the bucket the federated
@@ -279,11 +308,12 @@ class CryptoPlaneServer:
                     todo[d] = i
                 plan.append(("w", seq, d))
             try:
-                token = self._inner.submit_batch(batch)
+                with jax.profiler.TraceAnnotation("svc.submit", seq=seq):
+                    token = self._inner.submit_batch(batch)
             except Exception as e:
                 recent[seq] = f"{type(e).__name__}: {e}"
                 self._plane_fault("submit_errors")
-                _finish(done, plan)
+                _finish(done, plan, taken)
                 for s in [s for s in recent if s <= seq - 4]:
                     del recent[s]
                 return
@@ -291,70 +321,75 @@ class CryptoPlaneServer:
                 self.stats["overlapped"] = self.stats.get(
                     "overlapped", 0) + 1
             waves.append({"seq": seq, "token": token, "todo": todo,
-                          "width": len(batch), "jobs": [(done, plan)]})
+                          "width": len(batch),
+                          "jobs": [(done, plan, taken)]})
             while len(waves) > self._MAX_IN_FLIGHT:
                 _land(block=True)
 
         def _cycle() -> None:
             while waves and _land(block=False):
                 pass
-            try:
-                first = self._q.get(timeout=0.2 if not waves else 0.002)
-            except queue.Empty:
-                return
             nonlocal next_seq
-            jobs = self._drain(first)   # coalesce everything queued
-            for j in jobs:
-                if j[3]:
-                    _dispatch_raw(j[0], j[1], j[2])
-            jobs = [j for j in jobs if not j[3]]
-            if not jobs:
-                return
-            seq = next_seq
-            todo: dict[bytes, int] = {}
-            items: list[VerifyItem] = []
-            wave_jobs: list = []
-            for done, batch, digests, _ in jobs:
-                self.stats["items"] += len(batch)
-                plan: list = []
-                dep = 0
-                for it, d in zip(batch, digests):
-                    hit = self._cache.get(d)
-                    if hit is not None:
-                        self.stats["cache_hits"] += 1
-                        plan.append(("v", hit))
-                        continue
-                    w = pending.get(d)
-                    if w is None:
-                        if d not in todo:
-                            todo[d] = len(items)
-                            items.append(it)
-                            pending[d] = seq
-                        w = seq
-                    plan.append(("w", w, d))
-                    dep = max(dep, w)
-                if dep == 0:
-                    _finish(done, plan)        # pure cache hit
-                elif dep == seq:
-                    wave_jobs.append((done, plan))
-                else:
-                    for w in waves:            # ride an in-flight wave
-                        if w["seq"] == dep:
-                            w["jobs"].append((done, plan))
-                            break
+            with jax.profiler.TraceAnnotation("svc.drain"):
+                try:
+                    first = self._q.get(timeout=0.2 if not waves else 0.002)
+                except queue.Empty:
+                    return
+                jobs = self._drain(first)   # coalesce everything queued
+                taken = time.perf_counter()
+                for j in jobs:
+                    self._waits["queue"].add(taken - j[4])
+                    if j[3]:
+                        _dispatch_raw(j[0], j[1], j[2], taken)
+                jobs = [j for j in jobs if not j[3]]
+                if not jobs:
+                    return
+                seq = next_seq
+                todo: dict[bytes, int] = {}
+                items: list[VerifyItem] = []
+                wave_jobs: list = []
+                for done, batch, digests, _, _ in jobs:
+                    self.stats["items"] += len(batch)
+                    plan: list = []
+                    dep = 0
+                    for it, d in zip(batch, digests):
+                        hit = self._cache.get(d)
+                        if hit is not None:
+                            self.stats["cache_hits"] += 1
+                            plan.append(("v", hit))
+                            continue
+                        w = pending.get(d)
+                        if w is None:
+                            if d not in todo:
+                                todo[d] = len(items)
+                                items.append(it)
+                                pending[d] = seq
+                            w = seq
+                        plan.append(("w", w, d))
+                        dep = max(dep, w)
+                    if dep == 0:
+                        _finish(done, plan)        # pure cache hit
+                    elif dep == seq:
+                        wave_jobs.append((done, plan, taken))
+                    else:
+                        for w in waves:            # ride an in-flight wave
+                            if w["seq"] == dep:
+                                w["jobs"].append((done, plan, taken))
+                                break
             if not items:
                 return
             next_seq += 1
             try:
-                token = self._inner.submit_batch(items)
+                with jax.profiler.TraceAnnotation("svc.submit", seq=seq):
+                    token = self._inner.submit_batch(items)
             except Exception as e:
                 recent[seq] = f"{type(e).__name__}: {e}"
                 self._plane_fault("submit_errors")
                 for d in todo:
                     if pending.get(d) == seq:
                         del pending[d]
-                for done, plan in wave_jobs:
-                    _finish(done, plan)
+                for job in wave_jobs:
+                    _finish(*job)
                 # prune here too: with a persistently broken backend _land
                 # never runs, and one error entry per failed dispatch must
                 # not grow `recent` without bound in the shared service
@@ -450,6 +485,7 @@ class CryptoPlaneServer:
                 out = dict(self.stats, cache_size=len(self._cache),
                            dispatches_by_lanes={str(k): v
                                                 for k, v in by_lanes},
+                           waits=self._waits_report(),
                            device=self.device, compile=compile_stats())
                 sup = getattr(self._inner, "supervisor_stats", None)
                 if callable(sup):
@@ -501,7 +537,7 @@ class CryptoPlaneServer:
                     self._q.put((lambda result, f=fut:
                                  loop.call_soon_threadsafe(_resolve, f,
                                                            result),
-                                 items, digests, True))
+                                 items, digests, True, time.perf_counter()))
                     result = await fut
                     if not isinstance(result, str) and sup is not None:
                         # the supervised inner answers a failed device
@@ -527,6 +563,7 @@ class CryptoPlaneServer:
                 # warmup-over marker; ladder enforcement lives in the
                 # federated lane's shape set on the client side
                 self.stats["pinned"] = 1
+                self._waits = self._new_waits()     # warm-up stays outside
                 payload = pack({"id": rid, "pinned": True})
             elif "bls" in req:
                 # [[sig_b58, msg_bytes, [verkey_b58...]], ...] -> bools.
@@ -550,7 +587,8 @@ class CryptoPlaneServer:
                 fut = loop.create_future()
                 self._q.put((lambda result, f=fut:
                              loop.call_soon_threadsafe(_resolve, f, result),
-                             batch, digests, bool(req.get("wave"))))
+                             batch, digests, bool(req.get("wave")),
+                             time.perf_counter()))
                 result = await fut
                 if isinstance(result, str):      # backend failure
                     payload = pack({"id": rid, "error": result})

@@ -67,6 +67,7 @@ import time
 from collections import Counter, OrderedDict, deque
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
 from plenum_tpu.common import tracing
@@ -765,28 +766,29 @@ class CryptoPipeline:
         the floor)."""
         if not self._ed_staged:
             return None
-        wave = _Wave()
-        wave.t_first = self._ed_first_staged
-        cap = self.config.PIPELINE_MAX_BUCKET
-        key_cap = cap
-        enforce = (self.pinned and self._bucketed
-                   and not self._device_degraded())
-        if enforce and self._ed_buckets():
-            # pinned: never pack past what can dispatch on a compiled
-            # shape — leftovers ride the next wave instead of forcing a
-            # novel mid-run XLA compile
-            cap = max(self._ed_buckets())
-            key_cap = self._key_cap()
-        wave_vks = self._plan_into_wave(self._ed_staged, wave, cap,
-                                        key_cap)
-        self._ed_first_staged = (self._now() if self._ed_staged else None)
-        # bucket pad: the controller's floor, then the smallest pinned
-        # bucket covering the wave (skipped while the breaker routes to
-        # CPU — pad lanes would be verified for real there)
-        return self._finish_wave(
-            wave, len(wave_vks),
-            self._bucketed and not self._device_degraded(),
-            enforce, self._ed_buckets(), self._shapes)
+        with jax.profiler.TraceAnnotation("ring.pack"):
+            wave = _Wave()
+            wave.t_first = self._ed_first_staged
+            cap = self.config.PIPELINE_MAX_BUCKET
+            key_cap = cap
+            enforce = (self.pinned and self._bucketed
+                       and not self._device_degraded())
+            if enforce and self._ed_buckets():
+                # pinned: never pack past what can dispatch on a compiled
+                # shape — leftovers ride the next wave instead of forcing a
+                # novel mid-run XLA compile
+                cap = max(self._ed_buckets())
+                key_cap = self._key_cap()
+            wave_vks = self._plan_into_wave(self._ed_staged, wave, cap,
+                                            key_cap)
+            self._ed_first_staged = (self._now() if self._ed_staged else None)
+            # bucket pad: the controller's floor, then the smallest pinned
+            # bucket covering the wave (skipped while the breaker routes to
+            # CPU — pad lanes would be verified for real there)
+            return self._finish_wave(
+                wave, len(wave_vks),
+                self._bucketed and not self._device_degraded(),
+                enforce, self._ed_buckets(), self._shapes)
 
     def _dispatch_wave(self, wave: _Wave, lane=None) -> None:
         """Dispatch a packed wave and account for it — shared by the
@@ -800,10 +802,15 @@ class CryptoPipeline:
                 self.note_shape(shape)
             else:
                 self._note_lane_shape(lane, shape)
-        if lane is None:
-            wave.inner_tok = self._ed_inner.submit_batch(wave.items)
-        else:
-            lane.dispatch(wave)
+        # the ring's host phases also go onto the profiler's clock while
+        # a trace is held (`ring.pack`, `ring.dispatch`, `ring.collect`,
+        # beside the stamps of the DEVICE event)
+        with jax.profiler.TraceAnnotation("ring.dispatch",
+                                          lanes=len(wave.items)):
+            if lane is None:
+                wave.inner_tok = self._ed_inner.submit_batch(wave.items)
+            else:
+                lane.dispatch(wave)
         wave.t_dispatched = self._now()
         self.stats["dispatches"] += 1
         lanes = getattr(wave.inner_tok, "lanes", None)
@@ -828,11 +835,13 @@ class CryptoPipeline:
             self._ed_inflight = wave
 
     def _resolve_wave(self, wave: _Wave, ok) -> None:
-        ok = np.asarray(ok, dtype=bool)
-        wave.verdicts = ok
-        for j, key in enumerate(wave.keys):
-            verdict_cache_put(self._ed_cache, self._CACHE_MAX, key,
-                              bool(ok[j]))
+        with jax.profiler.TraceAnnotation("ring.collect",
+                                          lanes=len(wave.items)):
+            ok = np.asarray(ok, dtype=bool)
+            wave.verdicts = ok
+            for j, key in enumerate(wave.keys):
+                verdict_cache_put(self._ed_cache, self._CACHE_MAX, key,
+                                  bool(ok[j]))
         t_done = self._now()
         if self.metrics is not None and wave.n_real:
             # first submit of the wave to its verdicts: what a client-auth
@@ -1603,22 +1612,23 @@ class MultiDeviceCryptoPipeline(CryptoPipeline):
         THIS lane's compiled-bucket ladder after pin()."""
         if not lane.staged:
             return None
-        wave = _Wave()
-        wave.lane = lane.idx
-        wave.t_first = lane.first_staged
-        cap = self.config.PIPELINE_MAX_BUCKET
-        key_cap = cap
-        enforce = (self.pinned and lane.bucketed and not lane.degraded())
-        lane_buckets = self._lane_buckets(lane)
-        if enforce and lane_buckets:
-            cap = max(lane_buckets)
-            key_cap = self._lane_key_cap(lane)
-        wave_vks = self._plan_into_wave(lane.staged, wave, cap, key_cap)
-        lane.first_staged = self._now() if lane.staged else None
-        return self._finish_wave(
-            wave, len(wave_vks),
-            lane.bucketed and not lane.degraded(),
-            enforce, lane_buckets, lane.shapes, lane_stats=lane.stats)
+        with jax.profiler.TraceAnnotation("ring.pack"):
+            wave = _Wave()
+            wave.lane = lane.idx
+            wave.t_first = lane.first_staged
+            cap = self.config.PIPELINE_MAX_BUCKET
+            key_cap = cap
+            enforce = (self.pinned and lane.bucketed and not lane.degraded())
+            lane_buckets = self._lane_buckets(lane)
+            if enforce and lane_buckets:
+                cap = max(lane_buckets)
+                key_cap = self._lane_key_cap(lane)
+            wave_vks = self._plan_into_wave(lane.staged, wave, cap, key_cap)
+            lane.first_staged = self._now() if lane.staged else None
+            return self._finish_wave(
+                wave, len(wave_vks),
+                lane.bucketed and not lane.degraded(),
+                enforce, lane_buckets, lane.shapes, lane_stats=lane.stats)
 
     def _dispatch_lane(self, lane: _DeviceLane, wave: _Wave) -> None:
         self._dispatch_wave(wave, lane=lane)

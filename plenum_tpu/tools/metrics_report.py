@@ -247,7 +247,7 @@ def derive_summary(folds: dict[str, dict], span_s: float,
     # post-ordering critical path: per-stage p50/p95 from the raw samples
     # the commit-path timers flush (bls-verify / apply / durable / reply) —
     # a latency regression must localize to a stage, not hide in a mean
-    from plenum_tpu.common.metrics import percentile
+    from plenum_tpu.common.metrics import MetricsName, percentile
     for stage in ("bls_verify", "apply", "commit_wave", "durable", "reply"):
         f = folds.get(f"commit_path.{stage}_time", {})
         samples = f.get("samples")
@@ -256,6 +256,20 @@ def derive_summary(folds: dict[str, dict], span_s: float,
             out[f"{stage}_ms_p95"] = _ms(percentile(samples, 0.95))
         elif f.get("mean") is not None:
             out[f"{stage}_ms_mean"] = _ms(f["mean"])
+    # a write's residence on the node by stage (tracing.StageClock):
+    # count and sum are weighted per request, so the seven waits' means
+    # sum to the residence's over the requests that have them all
+    from plenum_tpu.common.tracing import STAGES
+    stages = {}
+    for name in STAGES + (MetricsName.STAGE_RESIDENCE,):
+        f = folds.get(name, {})
+        if f.get("count"):
+            stages[name.split(".", 1)[1]] = {
+                "count": int(f["count"]), "mean_ms": _ms(f.get("mean")),
+                "p50_ms": _ms(percentile(f.get("samples"), 0.5)),
+                "p95_ms": _ms(percentile(f.get("samples"), 0.95))}
+    if stages:
+        out["stages"] = stages
     # batched-BLS acceptance counter: Miller loops per ordered batch
     # (amortized O(1) target: ~2 for a same-message commit set)
     ppb = folds.get("crypto.pairings_per_batch", {})
@@ -268,20 +282,9 @@ def derive_summary(folds: dict[str, dict], span_s: float,
     gcb = folds.get("node.group_commit_batches", {})
     if gcb.get("mean") is not None:
         out["group_commit_batches_mean"] = round(gcb["mean"], 2)
-    # device-plane observability: dispatch counter (sharded plane) +
-    # coalescing-verifier batch stats, which existed as attributes/events
-    # but never reached this report
+    # device-plane observability: dispatch counter (sharded plane)
     if "crypto.plane_dispatches" in folds:
         out["plane_dispatches"] = int(cum("crypto.plane_dispatches") or 0)
-    sbs = folds.get("crypto.sig_batch_size", {})
-    if sbs.get("mean") is not None:
-        out["sig_batch_size_mean"] = round(sbs["mean"], 1)
-        out["sig_batches_dispatched"] = int(sbs.get("count") or 0)
-    if mean("crypto.sig_dispatch_time") is not None:
-        out["sig_dispatch_ms_mean"] = _ms(mean("crypto.sig_dispatch_time"))
-    if mean("crypto.sig_batch_fill_time") is not None:
-        out["sig_batch_fill_ms_mean"] = _ms(
-            mean("crypto.sig_batch_fill_time"))
     # plane supervisor: the degraded-mode story an operator actually
     # checks — breaker state (latest gauge), fallback volume, hedge wins,
     # deadline misses, and the dispatch-budget distribution p50/p95

@@ -325,7 +325,7 @@ def run_tcp_pool(n_nodes: int = 4, n_txns: int = 200, backend: str = "cpu",
                                       "durable_ms", "reply_ms"))
                      or k in ("pairings_per_batch",
                               "group_commit_batches_mean",
-                              "plane_dispatches", "sig_batch_size_mean")}
+                              "plane_dispatches")}
             if stage:
                 result["commit_stage"] = stage
             # plane-supervisor health: breaker state, fallback volume,

@@ -16,9 +16,13 @@ put every node on ONE timeline:
     skew — a PRE-PREPARE cannot be received before the primary sent it,
     so any negative pp_sent→pp_recv gap shifts the receiver's offset.
 
-Per-request waterfall (stages telescope: their sum equals reply−ingress):
+Per-request waterfall (stages telescope: their sum equals reply−arrival;
+the names and the ends are the stage clock's, `stage.<name>_wait` on the
+metrics store — common/tracing.py STAGES — where `durable` is
+`commit_wait`):
 
-  crypto     ingress -> signature verdict        (auth queue + dispatch)
+  inbox      handed to the node -> popped by its client pipeline
+  auth       popped  -> signature verdict        (auth queue + dispatch)
   propagate  verdict -> f+1 propagate quorum
   queue      quorum  -> batch PRE-PREPARE        (ordering queue wait)
   ordering   PRE-PREPARE -> commit quorum        (3PC: prepare+commit)
@@ -50,9 +54,14 @@ from plenum_tpu.common.metrics import percentile
 # plane (ing_admit -> the node-pipeline ingress point: client queue wait
 # + the batched auth dispatch); requests hitting the node directly have
 # no ing_admit point and the stage folds away — totals stay exact.
+# inbox is its counterpart for a request handed to the node directly: the
+# INGRESS event carries the timer time of the append to the client inbox
+# (`queued`), which is where a request waits while an auth wave is out.
+QUEUED = "queued"
 _WATERFALL = (
     ("front_door", tracing.ING_ADMIT, tracing.INGRESS),
-    ("crypto", tracing.INGRESS, tracing.AUTH),
+    ("inbox", QUEUED, tracing.INGRESS),
+    ("auth", tracing.INGRESS, tracing.AUTH),
     ("propagate", tracing.AUTH, tracing.PROPAGATE_QUORUM),
     ("queue", tracing.PROPAGATE_QUORUM, "pp"),
     ("ordering", "pp", tracing.ORDERED),
@@ -142,6 +151,9 @@ class _NodeIndex:
             self.first.setdefault((stage, key), at)
             if stage == tracing.CONTROLLER:
                 self.control.append((at, data or {}))
+            if stage == tracing.INGRESS and isinstance(
+                    (data or {}).get(QUEUED), (int, float)):
+                self.first.setdefault((QUEUED, key), data[QUEUED] + offset)
             if stage in (tracing.PP_SENT, tracing.PP_RECV):
                 for req in (data or {}).get("reqs", ()):
                     self.batch_of_req.setdefault(
@@ -197,6 +209,7 @@ class _NodeIndex:
             t_dur = self.durable_by_seq.get(seq)
         return {
             tracing.ING_ADMIT: self.first.get((tracing.ING_ADMIT, digest)),
+            QUEUED: self.first.get((QUEUED, digest)),
             tracing.INGRESS: self.first.get((tracing.INGRESS, digest)),
             tracing.AUTH: self.first.get((tracing.AUTH, digest)),
             tracing.PROPAGATE_QUORUM:
@@ -438,7 +451,7 @@ def _synthetic_dumps() -> list[dict]:
             [0.007, tracing.CROSS_SHARD, req,
              {"shard": 1, "ok": True, "dur": 0.002}],
             [0.008, tracing.ING_ADMIT, req, {"frm": "cli"}],
-            [0.010, tracing.INGRESS, req, {"frm": "cli"}],
+            [0.010, tracing.INGRESS, req, {"frm": "cli", "queued": 0.009}],
             [0.012, tracing.AUTH, req, {"ok": True}],
             [0.015, tracing.PROPAGATE_QUORUM, req, {"votes": 2}],
             [0.020, tracing.APPLY, "", {"seq": 1, "n": 1, "dur": 0.004}],
@@ -475,7 +488,8 @@ def _synthetic_dumps() -> list[dict]:
         "node": "R", "clock_domain": "wall",
         "mono_anchor": 0.0, "wall_anchor": 149.990, "dumped_at": 1.0,
         "anomalies": 1, "events": [
-            [-49.975, tracing.INGRESS, req, {"frm": "cli"}],
+            [-49.975, tracing.INGRESS, req, {"frm": "cli",
+                                             "queued": -49.978}],
             [-49.974, tracing.AUTH, req, {"ok": True}],
             [-49.973, tracing.PROPAGATE_QUORUM, req, {"votes": 2}],
             [-49.972, tracing.PP_RECV, batch, {"seq": 1, "frm": "P",
@@ -506,7 +520,7 @@ def self_check() -> int:
         if abs(wf["total"] - span) > 1e-9:
             problems.append(f"stage sum {wf['total']} != span {span}")
     att = attribution_summary(report)
-    for need in ("network", "crypto", "ordering", "durable", "reply",
+    for need in ("network", "inbox", "auth", "ordering", "durable", "reply",
                  "apply_wall", "device_queue", "device_pack",
                  "device_dispatch", "cross_shard"):
         if need not in att:

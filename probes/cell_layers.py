@@ -11,8 +11,21 @@ the nodes are processes, the node's controller when they are in process;
 null on a checkout that has no such counter) and `probe_lanes` (the
 window's device dispatches by the lane count of the program that ran each,
 `dispatches_by_lanes` of the service's stats or of the rings' summaries,
-with the smallest program's share; null on a checkout older than PR 38).
-Nothing of the benchmark is changed: the last line is run.py's own.
+with the smallest program's share; null on a checkout older than PR 38),
+`probe_stages` (the first validator's stage clock, `stage.*`: each stage's
+count and mean over the window, from the growth of its cumulative count and
+sum in VALIDATOR_INFO `stages`; p50 and p95 from the flushed store where
+the nodes are processes, which holds the warm-up's 64 writes too, else
+from the node's reservoir; the sum of the seven waits' means beside the
+mean residence, the same over the requests that had every stage (`whole`),
+and the client's p50 beside the residence's) and `probe_service_waits`
+(the crypto service's `waits`: a job's time on its queue and from there to
+its verdicts, window growth for the means); both null on a checkout older
+than PR 39. With `--trace 1` it keeps the run's xplane under
+`chiprun_out/probe_trace/` (where it is under 24 MiB) and prints
+`probe_gaps`: each device idle gap of the sample with the host spans
+(`prod.*`, `ring.*`, `svc.*`) that overlap it and the share of it each
+covers. Nothing of the benchmark is changed: the last line is run.py's own.
 
     python3 probes/cell_layers.py --workload <cell> --seed <n> --seconds 20
 """
@@ -75,10 +88,127 @@ def window_lanes(seen: list) -> dict | None:
             if total else None}      # a host plane dispatches no program
 
 
+def first_validator_stages(topo) -> dict | None:
+    """The first validator's VALIDATOR_INFO `stages` as it stands now."""
+    if hasattr(topo, "last_infos"):             # four owners: just fetched
+        return topo.last_infos[0].get("stages")
+    pool = getattr(topo, "pool", None)
+    if pool is not None:                        # nodes in this process
+        clock = getattr(pool.nodes[topo.names[0]], "stages", None)
+        return clock.report() if clock is not None else None
+    from benchmarks.tcp_client import ask
+    from plenum_tpu.execution.action_manager import VALIDATOR_INFO_ACTION
+    msg = topo.loop.run_until_complete(ask(
+        topo.addrs[topo.names[0]],
+        topo._trustee_request({"type": VALIDATOR_INFO_ACTION})))
+    return ((msg.get("result") or {}).get("data") or {}).get("stages")
+
+
+def service_waits(topo) -> dict | None:
+    plane = getattr(topo, "plane", None)
+    return plane.stats().get("waits") if plane is not None else None
+
+
+def grown(seen: list) -> dict | None:
+    """{name: {"count", "mean_ms"}} between a window's two readings of
+    cumulative {"count", "sum_s"} records."""
+    if len(seen) < 2 or not seen[0] or not seen[1]:
+        return None
+    out = {}
+    for name, end in seen[1].items():
+        start = seen[0].get(name) or {"count": 0, "sum_s": 0.0}
+        n = end["count"] - start["count"]
+        out[name] = {"count": n, "mean_ms": round(
+            (end["sum_s"] - start["sum_s"]) / n * 1e3, 4) if n else None}
+    return out
+
+
+def window_stages(seen: list, topo, numbers: dict) -> dict | None:
+    stages = grown(seen)
+    if stages is None:
+        return None
+    whole = stages.pop("whole")
+    from plenum_tpu.common.metrics import percentile
+    folds = (getattr(topo, "metrics_folds", None) or [{}])[0]
+    for name, row in stages.items():
+        samples = folds.get(name, {}).get("samples")
+        for q, key in ((0.5, "p50_ms"), (0.95, "p95_ms")):
+            row[key] = (round(percentile(samples, q) * 1e3, 4) if samples
+                        else seen[1][name].get(key))
+    waits = [v["mean_ms"] for k, v in stages.items()
+             if k != "stage.residence"]
+    return {"stages": stages,
+            "sum_of_wait_means_ms": round(sum(waits), 4)
+            if all(w is not None for w in waits) else None,
+            "residence_mean_ms": stages["stage.residence"]["mean_ms"],
+            "whole": dict(whole, residence_count=stages[
+                "stage.residence"]["count"]),
+            "residence_p50_ms": stages["stage.residence"]["p50_ms"],
+            "client_p50_ms": numbers.get("latency_p50_ms")}
+
+
+HOST_SPANS = ("prod.", "ring.", "svc.")
+
+
+def gaps_by_host_span(xplane: str, top: int = 10) -> list:
+    """The sample's longest device idle gaps (between whole executions on
+    the `XLA Modules` line, inside the benchmark's window span, as
+    trace_reduce's `idle_gaps`), each with the host spans that overlap it:
+    [{"gap_ms", "before", "host": {span name: share of the gap}}]."""
+    from benchmarks import trace_reduce as tr
+    window, mods, host = None, {}, []
+    for plane, line, name, start, dur in tr.xplane_events(xplane):
+        if plane.startswith(tr.DEVICE_PLANE_PREFIX):
+            if line == tr.MODULE_LINE:
+                mods.setdefault(plane, []).append((start, start + dur, name))
+        elif name == tr.WINDOW_EVENT:
+            window = (start, start + dur)
+        elif name.startswith(HOST_SPANS):
+            host.append((start, start + dur, name))
+    if window is None or not mods:
+        return []
+    lo, hi = window
+    gaps = []
+    for events in mods.values():
+        prev_end = lo
+        for start, end, name in sorted(events):
+            if start > prev_end:
+                gaps.append((start - prev_end, prev_end, start,
+                             "before " + name.split("(")[0]))
+            prev_end = max(prev_end, end)
+        if hi > prev_end:
+            gaps.append((hi - prev_end, prev_end, hi,
+                         "after the last program"))
+    out = []
+    for length, g0, g1, what in sorted(gaps, reverse=True)[:top]:
+        cover: dict = {}
+        for start, end, name in host:
+            both = min(end, g1) - max(start, g0)
+            if both > 0:
+                cover[name] = cover.get(name, 0) + both
+        out.append({"gap_ms": round(length / 1e6, 3), "before": what,
+                    "host": {n: round(c / length, 4) for n, c in sorted(
+                        cover.items(), key=lambda kv: -kv[1])}})
+    return out
+
+
+def keep_xplane(xplane: str, tag: str) -> dict:
+    import shutil
+    size = os.path.getsize(xplane)
+    kept = None
+    if size <= 24 << 20:
+        out = os.path.join(ROOT, "chiprun_out", "probe_trace")
+        os.makedirs(out, exist_ok=True)
+        kept = os.path.join(out, tag + ".xplane.pb")
+        shutil.copyfile(xplane, kept)
+    return {"bytes": size, "kept": kept}
+
+
 def as_cell() -> int:
-    from benchmarks import cell, manifest, readers
-    runs, lanes_seen, windows = [], [], []
+    from benchmarks import cell, manifest, readers, trace_reduce
+    runs, lanes_seen, windows, stages_seen, waits_seen = [], [], [], [], []
     init, metrics, window = cell.Run.__init__, cell.metrics, cell.Run.window
+    collect_trace = cell.Run.collect_trace
 
     def remember(self, args):
         init(self, args)
@@ -95,6 +225,8 @@ def as_cell() -> int:
         def snapshot_and_lanes():
             got = snapshot()
             lanes_seen.append(by_lanes(topo))
+            stages_seen.append(first_validator_stages(topo))
+            waits_seen.append(service_waits(topo))
             return got
         topo.snapshot = snapshot_and_lanes
 
@@ -103,8 +235,16 @@ def as_cell() -> int:
         # set-up may have taken one already (the four owners' does)
         at = len(lanes_seen)
         got = window(self, *args, **kwargs)
-        windows.append(lanes_seen[at:at + 2])
+        windows.append((lanes_seen[at:at + 2], stages_seen[at:at + 2],
+                        waits_seen[at:at + 2]))
         return got
+
+    def trace_and_gaps(self, win, trace_dir):
+        collect_trace(self, win, trace_dir)
+        xplane = trace_reduce.find_xplane(trace_dir)
+        cell.say(probe_gaps=gaps_by_host_span(xplane),
+                 probe_xplane=keep_xplane(
+                     xplane, f"{self.args.workload}.{self.args.seed}"))
 
     def say_layers(name, group, obs):
         layers = {}
@@ -114,12 +254,19 @@ def as_cell() -> int:
                     manifest.metric_spec("per_layer", m["name"]), obs)
             except Exception as e:      # a reader that wants the trace
                 layers[m["name"]] = f"not read: {e!r}"
+        lanes, stages, waits = windows[0] if windows else ([], [], [])
         cell.say(probe_per_layer=layers, probe_cuts=cut_counts(runs[0].topo),
-                 probe_lanes=window_lanes(windows[0] if windows else []))
+                 probe_lanes=window_lanes(lanes),
+                 probe_stages=window_stages(stages, runs[0].topo,
+                                            obs["numbers"]),
+                 probe_service_waits=dict(
+                     grown(waits) or {}, since_pin=waits[1]) if len(
+                     waits) > 1 and waits[1] else None)
         return metrics(name, group, obs)
 
     cell.Run.__init__ = remember
     cell.Run.window = remember_window
+    cell.Run.collect_trace = trace_and_gaps
     cell.metrics = say_layers
     return cell.main()
 

@@ -4,6 +4,7 @@ topology it exists for."""
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import os
 import threading
 
@@ -22,14 +23,13 @@ def _make_items(n, signers=4, tag=b""):
     return out
 
 
-@pytest.fixture
-def service(tmp_path):
-    """A live server on a CPU verifier + a factory for connected clients."""
-    from plenum_tpu.crypto.ed25519 import CpuEd25519Verifier
+@contextlib.contextmanager
+def _served(tmp_path, inner):
+    """A live server on `inner` + a factory for connected clients."""
     from plenum_tpu.parallel.crypto_service import (CryptoPlaneServer,
                                                     ServiceEd25519Verifier)
     sock = str(tmp_path / "crypto.sock")
-    server = CryptoPlaneServer(CpuEd25519Verifier(), socket_path=sock)
+    server = CryptoPlaneServer(inner, socket_path=sock)
     loop = asyncio.new_event_loop()
     started = threading.Event()
 
@@ -51,11 +51,21 @@ def service(tmp_path):
         clients.append(c)
         return c
 
-    yield server, connect
-    for c in clients:
-        c.close()
-    server._stop.set()
-    t.join(timeout=5.0)
+    try:
+        yield server, connect
+    finally:
+        for c in clients:
+            c.close()
+        server._stop.set()
+        t.join(timeout=5.0)
+
+
+@pytest.fixture
+def service(tmp_path):
+    """A live server on a CPU verifier + a factory for connected clients."""
+    from plenum_tpu.crypto.ed25519 import CpuEd25519Verifier
+    with _served(tmp_path, CpuEd25519Verifier()) as served:
+        yield served
 
 
 def test_verdicts_match_direct_verification(service):
@@ -751,3 +761,80 @@ def test_federated_pipeline_rides_remote_lane(service):
     assert pipe.federation_state()["remote_lanes"] == 1
     assert pipe.federation_state()["ship_ms_p95"] > 0.0
     pipe.close()
+
+
+# --- the service's own two waits (`stats()["waits"]`) -----------------------
+
+# what `stats` answered before the waits: the benchmark's snapshot() reads
+# eight of them by name
+OLD_STATS_KEYS = ("batches", "items", "cache_hits", "dispatches",
+                  "dispatched_items", "cache_size", "dispatches_by_lanes",
+                  "device", "compile")
+
+
+def test_service_waits_count_jobs_and_pin_resets_them(service):
+    """One `queue` and one `wave` sample a job (one client's batch as it
+    came off the socket); `pin` starts both afresh so warm-up stays
+    outside; every old stats key is still there."""
+    from plenum_tpu.parallel.crypto_service import FederatedEd25519Client
+    server, connect = service
+    client = connect()
+    for i in range(5):
+        assert client.verify_batch(
+            _make_items(6, tag=b"waits-%d" % i)).all()
+    st = client.stats()
+    assert all(k in st for k in OLD_STATS_KEYS), sorted(st)
+    waits = st["waits"]
+    assert set(waits) == {"queue", "wave"}
+    for w in waits.values():
+        assert set(w) == {"count", "sum_s", "p50_ms", "p95_ms"}
+        assert w["count"] == st["batches"] == 5
+        assert w["sum_s"] >= 0 and w["p95_ms"] >= w["p50_ms"] >= 0
+    assert waits["wave"]["sum_s"] > 0           # each job rode a wave
+
+    fed = FederatedEd25519Client(socket_path=client.socket_path)
+    assert fed.pin()["pinned"] is True
+    fed.close()
+    assert all(w == {"count": 0, "sum_s": 0.0, "p50_ms": None,
+                     "p95_ms": None}
+               for w in client.stats()["waits"].values())
+    assert client.verify_batch(_make_items(6, tag=b"waits-after")).all()
+    after = client.stats()
+    assert after["waits"]["queue"]["count"] == 1
+    assert after["batches"] == 6                # the old counter runs on
+
+
+def test_a_pure_cache_hit_waits_for_no_wave(service):
+    server, connect = service
+    a, b = connect(), connect()
+    items = _make_items(10, tag=b"hit-waits")
+    assert a.verify_batch(items).all()
+    first = a.stats()["waits"]["wave"]
+    assert first["count"] == 1 and first["sum_s"] > 0
+    assert b.verify_batch(items).all()          # every verdict cached
+    second = b.stats()["waits"]
+    assert second["wave"]["count"] == 2
+    assert second["wave"]["sum_s"] == first["sum_s"]    # the hit added 0
+    assert second["queue"]["count"] == 2
+
+
+def test_a_job_riding_a_wave_in_flight_waits_until_it_lands(tmp_path):
+    """Two clients, the same content, the second while the first's wave
+    is out: ONE dispatch, and the second job's wave wait runs until that
+    wave lands (not 0, not a second dispatch)."""
+    import time
+    inner = _SlowAsyncVerifier()
+    with _served(tmp_path, inner) as (server, connect):
+        a, b = connect(), connect()
+        items = _make_items(8, tag=b"ride")
+        tok_a = a.submit_batch(items)
+        time.sleep(0.01)                # a's wave is out (30 ms)
+        tok_b = b.submit_batch(items)
+        assert b.collect_batch(tok_b, wait=True).all()
+        assert a.collect_batch(tok_a, wait=True).all()
+        st = a.stats()
+        assert st["dispatches"] == 1 and len(inner.submitted) == 1
+        wave = st["waits"]["wave"]
+        assert wave["count"] == 2
+        # a waited the wave's 30 ms, b what was left of it: both > 0
+        assert wave["sum_s"] > 0.03 and wave["p50_ms"] > 1.0

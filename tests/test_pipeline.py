@@ -1031,3 +1031,54 @@ def test_federated_zero_remote_constructs_pr14_class_exactly():
     assert per_fed < per_base * 3 + 1e-3, \
         f"zero-remote federation {per_fed * 1e6:.0f}us/op vs PR 14 " \
         f"{per_base * 1e6:.0f}us/op"
+
+
+# --- the ring's host phases on the profiler's clock --------------------------
+
+def _host_spans(log_dir, prefix):
+    """{span name: count} of the trace's host events named `prefix`*."""
+    from collections import Counter
+    from benchmarks.trace_reduce import (DEVICE_PLANE_PREFIX, find_xplane,
+                                         xplane_events)
+    return Counter(
+        name for plane, _line, name, _start, _dur
+        in xplane_events(find_xplane(str(log_dir)))
+        if not plane.startswith(DEVICE_PLANE_PREFIX)
+        and name.startswith(prefix))
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["single_ring", "two_lanes"])
+def test_ring_phases_are_spans_of_a_held_trace(tmp_path, lanes):
+    """While a jax.profiler trace is held, every wave leaves ring.pack,
+    ring.dispatch and ring.collect on the trace's host plane (beside the
+    benchmark's own window span: trace_reduce reads only that one, so the
+    device numbers of a traced run read as before); with no trace held
+    the same calls record nothing and the ring answers the same."""
+    import jax
+    rng = random.Random(91)
+    if lanes:
+        pipe, _ = _multi_pipe(lanes)
+    else:
+        pipe = CryptoPipeline(ed_inner=FakeDeviceVerifier(),
+                              config=_fast_config())
+
+    def waves(n):
+        for _ in range(n):
+            tok = pipe.submit_verify(_junk_items(rng, 8))
+            assert pipe.collect_verify(tok, wait=True).all()
+
+    waves(2)                            # no trace held: nothing recorded
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("bench_trace_window"):
+            waves(3)
+    finally:
+        jax.profiler.stop_trace()
+    assert pipe.stats["dispatches"] == 5
+    spans = _host_spans(tmp_path, "ring.")
+    assert spans == {"ring.pack": 3, "ring.dispatch": 3, "ring.collect": 3}
+    assert _host_spans(tmp_path, "bench_trace_window") == {
+        "bench_trace_window": 1}

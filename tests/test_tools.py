@@ -395,7 +395,7 @@ def test_metrics_report_commit_stage_percentiles(tmp_path):
         m.add_event(MetricsName.COMMIT_BLS_VERIFY_TIME, 0.001 * (i + 1))
         m.add_event(MetricsName.COMMIT_DURABLE_TIME, 0.002)
         m.add_event(MetricsName.BLS_PAIRINGS_PER_BATCH, 2)
-    m.add_event(MetricsName.SIG_BATCH_SIZE, 512)
+    m.add_event(MetricsName.GROUP_COMMIT_BATCHES, 3)
     m.add_event(MetricsName.SIG_PLANE_DISPATCHES, 7)   # cumulative gauge
     m.add_event(MetricsName.BLS_PAIRING_CHECKS, 100)
     m.add_event(MetricsName.BLS_PAIRINGS, 200)
@@ -409,8 +409,34 @@ def test_metrics_report_commit_stage_percentiles(tmp_path):
     assert summary["pairing_checks_total"] == 100
     assert summary["pairings_total"] == 200
     assert summary["plane_dispatches"] == 7
-    assert summary["sig_batch_size_mean"] == 512.0
+    assert summary["group_commit_batches_mean"] == 3.0
     assert "batch_cuts" not in summary     # no cut recorded, no section
+
+
+def test_metrics_report_prints_the_stage_clock(tmp_path):
+    """The eight stage names come out beside the commit-path figures:
+    count and mean from the per-request weighted fold (a batch's span once
+    a request it carries), quantiles from the samples (one a batch)."""
+    from plenum_tpu.common.metrics import KvMetricsCollector, MetricsName
+    from plenum_tpu.storage.kv_file import KvFile
+    from plenum_tpu.tools.metrics_report import report_node
+
+    mdir = tmp_path / "Node1" / "metrics"
+    m = KvMetricsCollector(KvFile(str(mdir)), now=lambda: 1000.0)
+    for i in range(10):
+        m.add_event(MetricsName.STAGE_INBOX_WAIT, 0.002)
+        m.add_event(MetricsName.STAGE_RESIDENCE, 0.100)
+    m.add_event(MetricsName.STAGE_ORDERING_WAIT, 0.040, 8)  # a batch of 8
+    m.add_event(MetricsName.STAGE_ORDERING_WAIT, 0.060, 2)
+    m.flush()
+    _, summary = report_node(str(mdir), last_s=None)
+    stages = summary["stages"]
+    assert set(stages) == {"inbox_wait", "ordering_wait", "residence"}
+    assert stages["inbox_wait"] == {"count": 10, "mean_ms": 2.0,
+                                    "p50_ms": 2.0, "p95_ms": 2.0}
+    assert stages["ordering_wait"]["count"] == 10
+    assert stages["ordering_wait"]["mean_ms"] == 44.0   # (8*40 + 2*60) / 10
+    assert stages["ordering_wait"]["p95_ms"] == 60.0    # of the 2 samples
 
 
 def test_metrics_report_batch_cut_reasons(tmp_path):
@@ -476,14 +502,22 @@ def test_distinct_signers_config_orders_owner_writes():
             for name in pool.names} == {1 + 2 * n}
 
 
-def test_replay_reproduces_span_sequence():
+@pytest.mark.parametrize("stage_samples", [True, False],
+                         ids=["stage_clock_sampling", "null_collector"])
+def test_replay_reproduces_span_sequence(stage_samples):
     """Record/replay x tracing determinism guard: replaying a recorded
     node under the mock clock reproduces a BYTE-IDENTICAL span sequence.
     Span timestamps come only from the injectable timer and payloads only
     from message content (wall_durations=False strips the perf_counter
     stage durations, the one legitimately non-deterministic field), so
     any divergence here means a span site leaked wall state into the
-    trace — the property the flight-recorder postmortems rely on."""
+    trace — the property the flight-recorder postmortems rely on. The
+    span sites go through the stage clock, whose perf_counter durations
+    land on the metrics store and never in the ring: the replayed node
+    gives the same bytes whether its clock samples or feeds a
+    NullMetricsCollector."""
+    from plenum_tpu.common.metrics import (MetricsCollector,
+                                           NullMetricsCollector)
     from plenum_tpu.common.event_bus import ExternalBus
     from plenum_tpu.common.timer import MockTimer
     from plenum_tpu.common.tracing import Tracer, span_sequence
@@ -523,6 +557,8 @@ def test_replay_reproduces_span_sequence():
         timer.advance(0.05)
     live = span_sequence(nodes["Alpha"].tracer.snapshot())
     assert b'"ordered"' in live and b'"reply"' in live
+    assert b'"queued"' in live          # the inbox stage's ring event
+    assert nodes["Alpha"].stages.report()["stage.residence"]["count"] == 1
 
     # fresh Alpha from the same genesis; feed the recorded stream back
     first_ts = next(ts for ts, *_ in recorder.iter_records())
@@ -531,10 +567,12 @@ def test_replay_reproduces_span_sequence():
     components2 = NodeBootstrap("Alpha", genesis_txns=genesis).build()
     tracer2 = Tracer("Alpha", timer2.get_current_time,
                      wall_durations=False)
+    metrics2 = MetricsCollector() if stage_samples else NullMetricsCollector()
     node2 = Node("Alpha", timer2, bus2, components2, config=config,
-                 tracer=tracer2)
+                 tracer=tracer2, metrics=metrics2)
     replay(recorder.iter_records(), node2, timer2)
     assert span_sequence(tracer2.snapshot()) == live
+    assert ("stage.residence" in metrics2.accumulators) == stage_samples
 
 
 def test_log_analyzer_unit(tmp_path):
