@@ -43,7 +43,9 @@ class InternalBus(Router):
 
 class ExternalBus(Router):
     """Network-facing bus: incoming messages arrive as (msg, frm); outgoing
-    messages go through a send handler installed by the owning stack."""
+    messages go through a send handler installed by the owning stack.
+    flush() asks that stack to put what was sent so far on the wire now;
+    a stack that delivers on send (the sim fabric, a sink) installs none."""
 
     ALL_CONNECTED = None  # dst=None == broadcast
 
@@ -53,10 +55,12 @@ class ExternalBus(Router):
     class Disconnected(NamedTuple):
         name: str
 
-    def __init__(self, send_handler: Callable[[Any, Any], None]):
+    def __init__(self, send_handler: Callable[[Any, Any], None],
+                 flush_handler: Callable[[], None] = lambda: None):
         super().__init__()
         # send_handler(msg, dst): dst is None (broadcast) or list of names
         self._send_handler = send_handler
+        self.flush = flush_handler
         self.connecteds: set[str] = set()
         # admission predicate over the sender; installed by the node to drop
         # traffic from blacklisted peers before ANY service sees it

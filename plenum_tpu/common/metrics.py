@@ -287,7 +287,10 @@ class MetricsName:
     # transport
     # silent-loss accounting + byte totals, sampled from TcpStack.stats as
     # cumulative gauges (read back via max, like gc_pause_time); per-type
-    # rows flush under dynamic names "transport.tx.<OP>" / "transport.rx.<OP>"
+    # rows flush under dynamic names "transport.tx.<OP>" / "transport.rx.<OP>";
+    # so do "transport.flushes.<cause>", "transport.tx_hold.<count|sum_s>",
+    # "transport.rx_hold.<count|sum_s>" and the Looper's
+    # "looper.wakes.<cause>" (when a message left, when a frame was seen)
     TRANSPORT_DROPPED_FRAMES = "transport.dropped_frames"
     TRANSPORT_DROPPED_SESSIONS = "transport.dropped_sessions"
     TRANSPORT_TX_BYTES = "transport.tx_bytes"

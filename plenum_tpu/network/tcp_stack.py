@@ -25,8 +25,11 @@ length-prefixed ChaCha20-Poly1305 with a counter nonce (replay-safe: a
 counter never repeats under a session key, and sessions never resume).
 
 Wire frames carry a msgpack LIST of message dicts — the outbox batching the
-reference does in common/batched.py — so one TCP segment typically carries a
-whole prod cycle's traffic to a peer.
+reference does in common/batched.py — so one TCP segment carries everything
+a phase of a prod cycle cast to a peer: the owning Prodable flushes the
+outboxes at the end of each phase that can cast a vote (flush()), and a
+frame is cut and decoded in the loop turn that read it (_Conn), so the
+peer's next cycle drains it (docs/transport.md, "Outbox batching").
 
 Dialer rule: for each pair the lexicographically SMALLER name dials; the
 other side accepts. The dialer owns the retry loop (kit_zstack semantics).
@@ -130,10 +133,10 @@ def _derive_keys(eph_priv: X25519PrivateKey, eph_peer_pub: bytes,
 class _Session:
     """One established, authenticated, encrypted peer connection."""
 
-    def __init__(self, peer: str, writer: asyncio.StreamWriter,
+    def __init__(self, peer: str, conn: "_Conn",
                  send_key: bytes, recv_key: bytes):
         self.peer = peer
-        self.writer = writer
+        self.conn = conn
         self._send_aead = ChaCha20Poly1305(send_key)
         self._recv_aead = ChaCha20Poly1305(recv_key)
         self._send_ctr = 0
@@ -151,17 +154,116 @@ class _Session:
         return self._recv_aead.decrypt(nonce, ciphertext, None)
 
 
-async def _read_exact(reader: asyncio.StreamReader, n: int) -> bytes:
+async def _read_exact(reader, n: int) -> bytes:
+    """reader: an asyncio.StreamReader or a _Conn still in its handshake."""
     data = await reader.readexactly(n)
     return data
 
 
-async def _read_frame(reader: asyncio.StreamReader) -> bytes:
+async def _read_frame(reader) -> bytes:
     hdr = await _read_exact(reader, 4)
     length = int.from_bytes(hdr, "big")
     if length > MAX_FRAME:
         raise HandshakeError(f"frame too large: {length}")
     return await _read_exact(reader, length)
+
+
+class _Conn(asyncio.Protocol):
+    """One TCP connection, read by callback instead of by a reader task.
+
+    A StreamReader hands bytes to a task that the loop wakes a turn AFTER
+    the turn that read them, and that turn runs behind whatever was ready
+    first (the Looper's next prod cycle), so a frame sat through two
+    cycles that could not see it. Here a handshake still awaits
+    readexactly(), and once deliver_frames() is called every whole
+    length-prefixed frame is cut out of the buffer and handed to
+    on_frame(payload) inside data_received: in the loop turn that read
+    it. on_frame raising closes the connection (a frame that fails to
+    decrypt desynchronises the counter nonce: the session is dead)."""
+
+    def __init__(self, on_connect: Optional[Callable[["_Conn"], None]] = None):
+        self.transport: Optional[asyncio.Transport] = None
+        self._on_connect = on_connect
+        self._buf = bytearray()
+        self._on_frame: Optional[Callable[[bytes], None]] = None
+        self._on_close: Optional[Callable[[], None]] = None
+        self._waiter: Optional[asyncio.Future] = None
+        self._lost = False
+
+    # --- asyncio.Protocol ---
+
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        if self._on_connect is not None:
+            self._on_connect(self)
+
+    def data_received(self, data: bytes) -> None:
+        self._buf += data
+        if self._on_frame is not None:
+            self._cut_frames()
+        else:
+            self._wake()
+
+    def connection_lost(self, exc) -> None:
+        self._lost = True
+        self._wake()
+        if self._on_close is not None:
+            self._on_close()
+
+    # --- handshake phase ---
+
+    def _wake(self) -> None:
+        if self._waiter is not None and not self._waiter.done():
+            self._waiter.set_result(None)
+
+    async def readexactly(self, n: int) -> bytes:
+        while len(self._buf) < n:
+            if self._lost:
+                raise asyncio.IncompleteReadError(bytes(self._buf), n)
+            self._waiter = asyncio.get_running_loop().create_future()
+            try:
+                await self._waiter
+            finally:
+                self._waiter = None
+        data = bytes(self._buf[:n])
+        del self._buf[:n]
+        return data
+
+    # --- established phase ---
+
+    def deliver_frames(self, on_frame: Callable[[bytes], None],
+                       on_close: Callable[[], None]) -> None:
+        """From now on frames go to on_frame as they are read; whatever
+        followed the handshake in the same segment is cut at once."""
+        self._on_frame, self._on_close = on_frame, on_close
+        if self._lost:
+            on_close()
+        else:
+            self._cut_frames()
+
+    def _cut_frames(self) -> None:
+        buf, pos = self._buf, 0
+        try:
+            while len(buf) - pos >= 4 and not self.transport.is_closing():
+                length = int.from_bytes(buf[pos:pos + 4], "big")
+                if length > MAX_FRAME:
+                    raise HandshakeError(f"frame too large: {length}")
+                if len(buf) - pos - 4 < length:
+                    break
+                pos += 4 + length
+                self._on_frame(bytes(buf[pos - length:pos]))
+        except Exception:
+            logger.debug("closing a connection on a refused frame",
+                         exc_info=True)
+            self.close()
+        finally:
+            del buf[:pos]
+
+    def write(self, data: bytes) -> None:
+        self.transport.write(data)
+
+    def close(self) -> None:
+        self.transport.close()
 
 
 class TcpStack:
@@ -170,7 +272,11 @@ class TcpStack:
     Lifecycle: construct -> (optionally bind() to learn the real port)
     -> start() -> ... -> stop(). All I/O runs on one asyncio loop; the
     owning Looper calls drain() each prod cycle to hand queued inbound
-    messages to the bus (per-cycle quota, like zstack.py:520).
+    messages to the bus (per-cycle quota, like zstack.py:520), and the
+    cycle writes what it cast through flush() (the bus's flush handler)
+    before it goes on to unrelated work. `arrival`, when the owner sets
+    one, is set each time a frame lands in the inbound queue: an idle
+    Looper waits on it instead of sleeping its interval out.
     """
 
     def __init__(self, name: str, host: str, port: int,
@@ -188,14 +294,17 @@ class TcpStack:
         self._sk = Ed25519PrivateKey.from_private_bytes(seed)
         self.verkey = self._sk.public_key().public_bytes(
             serialization.Encoding.Raw, serialization.PublicFormat.Raw)
-        self.bus = ExternalBus(self._enqueue_send)
+        self.bus = ExternalBus(self._enqueue_send, self.flush)
+        self.arrival: Optional[asyncio.Event] = None
         self._sessions: dict[str, _Session] = {}
         self._outboxes: dict[str, list[bytes]] = {}
-        self._inbound: deque[tuple[Any, str]] = deque()
+        # per peer, when the oldest message its outbox holds was queued
+        self._outbox_since: dict[str, float] = {}
+        self._inbound: deque[tuple[Any, str, float]] = deque()
         self._server: Optional[asyncio.AbstractServer] = None
         self._dial_tasks: dict[str, asyncio.Task] = {}
-        self._reader_tasks: set[asyncio.Task] = set()
-        self._flush_scheduled = False
+        self._accept_tasks: set[asyncio.Task] = set()
+        self._scheduled_flush: Optional[asyncio.Handle] = None
         self._quota = max_inbound_per_drain
         self._stopped = False
         # dropped_frames/dropped_sessions: silent-loss accounting — outbox
@@ -203,9 +312,17 @@ class TcpStack:
         # trace (surfaced via tools.metrics_report through the node's
         # metrics store). tx/rx maps: per-message-type [count, bytes] so
         # wire-cost claims (digest-gossip) are measured, not asserted.
+        # flushes: frames written, by who flushed them (`in_cycle`: a
+        # prod cycle's flush point; `scheduled`: the call_soon fallback
+        # for a send made outside any cycle). tx_hold: per frame, its
+        # OLDEST message's _enqueue_send -> socket write; rx_hold: per
+        # message, frame decoded -> handed to the bus.
         self.stats = {"sent_frames": 0, "recv_frames": 0, "rejected": 0,
                       "dropped_frames": 0, "dropped_sessions": 0,
-                      "tx_msgs": {}, "rx_msgs": {}}
+                      "tx_msgs": {}, "rx_msgs": {},
+                      "flushes": {"in_cycle": 0, "scheduled": 0},
+                      "tx_hold": {"count": 0, "sum_s": 0.0},
+                      "rx_hold": {"count": 0, "sum_s": 0.0}}
 
     @staticmethod
     def _count_msg(table: dict, op: str, nbytes: int, n: int = 1) -> None:
@@ -221,8 +338,8 @@ class TcpStack:
         """Start the listener; returns the actual port (use port=0 to let
         the OS pick — the tests and the local-pool runner do)."""
         if self._server is None:
-            self._server = await asyncio.start_server(
-                self._on_accept, self.host, self.port)
+            self._server = await asyncio.get_running_loop().create_server(
+                lambda: _Conn(self._accepted), self.host, self.port)
             self.port = self._server.sockets[0].getsockname()[1]
         return self.port
 
@@ -244,13 +361,10 @@ class TcpStack:
         self._stopped = True
         for task in list(self._dial_tasks.values()):
             task.cancel()
-        for task in list(self._reader_tasks):
+        for task in list(self._accept_tasks):
             task.cancel()
         for sess in list(self._sessions.values()):
-            try:
-                sess.writer.close()
-            except Exception:
-                pass
+            sess.conn.close()
         self._sessions.clear()
         if self._server is not None:
             self._server.close()
@@ -277,8 +391,11 @@ class TcpStack:
         targets = dst if dst is not None else [
             p for p in self.registry.names() if p != self.name]
         self._count_msg(self.stats["tx_msgs"], op, len(data), len(targets))
+        now = time.perf_counter()
         for peer in targets:
             box = self._outboxes.setdefault(peer, [])
+            if not box:
+                self._outbox_since[peer] = now
             box.append(data)
             if len(box) > OUTBOX_CAP:          # quota: drop oldest
                 trimmed = len(box) - OUTBOX_CAP
@@ -291,18 +408,27 @@ class TcpStack:
         self._schedule_flush()
 
     def _schedule_flush(self) -> None:
-        if self._flush_scheduled or self._stopped:
+        """The path of a send made outside any prod cycle (handshake,
+        reconnect, a test): flush on the loop's next turn. A cycle's own
+        flush() gets there first and cancels it."""
+        if self._scheduled_flush is not None or self._stopped:
             return
-        self._flush_scheduled = True
         try:
-            asyncio.get_running_loop().call_soon(self._flush)
+            self._scheduled_flush = asyncio.get_running_loop().call_soon(
+                self.flush, "scheduled")
         except RuntimeError:
-            self._flush_scheduled = False      # no loop yet; flushed on start
+            pass                               # no loop yet; flushed on start
 
-    def _flush(self) -> None:
+    def flush(self, cause: str = "in_cycle") -> None:
         """Coalesce each peer's queued messages into ONE encrypted frame
-        (common/batched.py flushOutBoxes equivalent)."""
-        self._flush_scheduled = False
+        (common/batched.py flushOutBoxes equivalent) and write it: the
+        transport sends at once while its buffer is empty. Called at the
+        flush points of a prod cycle (the end of drain(), and the node
+        through the bus: docs/transport.md), so a vote leaves when the
+        phase that cast it ends, not when the whole cycle has."""
+        if self._scheduled_flush is not None:
+            self._scheduled_flush.cancel()
+            self._scheduled_flush = None
         for peer, box in self._outboxes.items():
             sess = self._sessions.get(peer)
             if sess is None or not box:
@@ -310,15 +436,19 @@ class TcpStack:
             frame_payload = pack(box)
             n_msgs = len(box)
             box.clear()
+            hold = self.stats["tx_hold"]
+            hold["count"] += 1
+            hold["sum_s"] += time.perf_counter() - self._outbox_since[peer]
             try:
                 # backpressure: a peer that stopped reading is dead to us —
                 # unbounded transport buffering would OOM the node (the
                 # reference's ZMQ high-water mark drops slow peers the same
                 # way; the dialer's retry loop gives it a fresh start)
-                if sess.writer.transport.get_write_buffer_size() > WRITE_HWM:
+                if sess.conn.transport.get_write_buffer_size() > WRITE_HWM:
                     raise ConnectionError("peer write buffer over HWM")
-                sess.writer.write(sess.encrypt_frame(frame_payload))
+                sess.conn.write(sess.encrypt_frame(frame_payload))
                 self.stats["sent_frames"] += 1
+                self.stats["flushes"][cause] += 1
             except Exception:
                 # the cleared box's messages die with the session — count
                 # them; silent loss here cost a debugging session once
@@ -332,16 +462,22 @@ class TcpStack:
     # --- incoming --------------------------------------------------------
 
     def drain(self) -> int:
-        """Deliver up to the per-cycle quota of inbound messages to the bus."""
+        """Deliver up to the per-cycle quota of inbound messages to the
+        bus, then write what their handlers cast (a PREPARE answering a
+        PRE-PREPARE, a COMMIT answering the last PREPARE)."""
         n = 0
+        hold = self.stats["rx_hold"]
         while self._inbound and n < self._quota:
-            msg, frm = self._inbound.popleft()
+            msg, frm, decoded = self._inbound.popleft()
             n += 1
+            hold["sum_s"] += time.perf_counter() - decoded
             try:
                 self.bus.process_incoming(msg, frm)
             except Exception:
                 logger.exception("handler failed for %s from %s",
                                  type(msg).__name__, frm)
+        hold["count"] += n
+        self.flush()
         return n
 
     @property
@@ -360,33 +496,30 @@ class TcpStack:
             if entry is None:
                 return
             host, port, expect_vk = entry
-            writer = None
+            conn = None
             try:
-                reader, writer = await asyncio.open_connection(host, port)
+                _, conn = await asyncio.get_running_loop().create_connection(
+                    _Conn, host, port)
                 # a wedged acceptor must not hang the dial loop forever:
                 # same 5s budget the acceptor gives us
                 sess = await asyncio.wait_for(
-                    self._handshake_dialer(peer, expect_vk, reader, writer),
+                    self._handshake_dialer(peer, expect_vk, conn),
                     timeout=5.0)
-                self._install_session(peer, sess, reader)
+                self._install_session(peer, sess)
                 backoff.reset()
             except (OSError, HandshakeError, asyncio.IncompleteReadError,
                     asyncio.TimeoutError):
-                if writer is not None:       # failed handshake: free the fd
-                    try:
-                        writer.close()
-                    except Exception:
-                        pass
+                if conn is not None:         # failed handshake: free the fd
+                    conn.close()
                 await asyncio.sleep(backoff.next())
 
     async def _handshake_dialer(self, peer: str, expect_vk: bytes,
-                                reader, writer) -> _Session:
+                                conn: _Conn) -> _Session:
         eph = X25519PrivateKey.generate()
         eph_pub = eph.public_key().public_bytes(
             serialization.Encoding.Raw, serialization.PublicFormat.Raw)
-        writer.write(MAGIC + eph_pub)
-        await writer.drain()
-        resp = await _read_exact(reader, 32 + 32 + 64)
+        conn.write(MAGIC + eph_pub)
+        resp = await _read_exact(conn, 32 + 32 + 64)
         eph_b, vk_b, sig_b = resp[:32], resp[32:64], resp[64:]
         if vk_b != expect_vk:
             raise HandshakeError(f"{peer}: unexpected verkey")
@@ -397,25 +530,29 @@ class TcpStack:
         except InvalidSignature:
             raise HandshakeError(f"{peer}: bad responder signature")
         sig_a = self._sk.sign(b"init" + transcript)
-        writer.write(self.verkey + sig_a)
-        await writer.drain()
+        conn.write(self.verkey + sig_a)
         k_d2a, k_a2d = _derive_keys(eph, eph_b, transcript)
-        return _Session(peer, writer, send_key=k_d2a, recv_key=k_a2d)
+        return _Session(peer, conn, send_key=k_d2a, recv_key=k_a2d)
 
     # --- handshake: acceptor side ---------------------------------------
 
-    async def _on_accept(self, reader, writer) -> None:
+    def _accepted(self, conn: _Conn) -> None:
+        task = asyncio.get_running_loop().create_task(self._on_accept(conn))
+        self._accept_tasks.add(task)
+        task.add_done_callback(self._accept_tasks.discard)
+
+    async def _on_accept(self, conn: _Conn) -> None:
         try:
             sess = await asyncio.wait_for(
-                self._handshake_acceptor(reader, writer), timeout=5.0)
+                self._handshake_acceptor(conn), timeout=5.0)
         except Exception:
             self.stats["rejected"] += 1
-            writer.close()
+            conn.close()
             return
-        self._install_session(sess.peer, sess, reader)
+        self._install_session(sess.peer, sess)
 
-    async def _handshake_acceptor(self, reader, writer) -> _Session:
-        hello = await _read_exact(reader, len(MAGIC) + 32)
+    async def _handshake_acceptor(self, conn: _Conn) -> _Session:
+        hello = await _read_exact(conn, len(MAGIC) + 32)
         if hello[:len(MAGIC)] != MAGIC:
             raise HandshakeError("bad magic")
         eph_a = hello[len(MAGIC):]
@@ -424,9 +561,8 @@ class TcpStack:
             serialization.Encoding.Raw, serialization.PublicFormat.Raw)
         transcript = eph_a + eph_pub
         sig_b = self._sk.sign(b"resp" + transcript)
-        writer.write(eph_pub + self.verkey + sig_b)
-        await writer.drain()
-        fin = await _read_exact(reader, 32 + 64)
+        conn.write(eph_pub + self.verkey + sig_b)
+        fin = await _read_exact(conn, 32 + 64)
         vk_a, sig_a = fin[:32], fin[32:]
         peer = self.registry.name_by_verkey(vk_a)
         if peer is None:                       # ZAP allowlist: unknown key
@@ -437,62 +573,56 @@ class TcpStack:
         except InvalidSignature:
             raise HandshakeError(f"{peer}: bad initiator signature")
         k_d2a, k_a2d = _derive_keys(eph, eph_a, transcript)
-        return _Session(peer, writer, send_key=k_a2d, recv_key=k_d2a)
+        return _Session(peer, conn, send_key=k_a2d, recv_key=k_d2a)
 
     # --- session plumbing -----------------------------------------------
 
-    def _install_session(self, peer: str, sess: _Session, reader) -> None:
+    def _install_session(self, peer: str, sess: _Session) -> None:
         old = self._sessions.get(peer)
         if old is not None:
             # restarted peer: the new connection supersedes the old one
-            try:
-                old.writer.close()
-            except Exception:
-                pass
+            old.conn.close()
         self._sessions[peer] = sess
-        task = asyncio.get_running_loop().create_task(
-            self._read_loop(peer, sess, reader))
-        self._reader_tasks.add(task)
-        task.add_done_callback(self._reader_tasks.discard)
         self.bus.update_connecteds(self.connected)
+        sess.conn.deliver_frames(
+            lambda ct: self._on_frame(peer, sess, ct),
+            lambda: self._session_closed(peer, sess))
         self._schedule_flush()                 # release queued outbox
 
     def _drop_session(self, peer: str) -> None:
         sess = self._sessions.pop(peer, None)
         if sess is not None:
-            try:
-                sess.writer.close()
-            except Exception:
-                pass
+            sess.conn.close()
             self.bus.update_connecteds(self.connected)
 
-    async def _read_loop(self, peer: str, sess: _Session, reader) -> None:
-        try:
-            while not self._stopped:
-                ct = await _read_frame(reader)
-                payload = sess.decrypt(ct)
-                self.stats["recv_frames"] += 1
-                # frame payload = packed list of per-message packed dicts
-                # (messages are serialized once at enqueue, even for
-                # broadcasts, then batched per peer at flush)
-                for raw in unpack(payload):
-                    try:
-                        d = unpack(raw)
-                        msg = message_from_dict(d)
-                    except Exception:
-                        logger.warning("undecodable message from %s", peer)
-                        continue
-                    self._count_msg(
-                        self.stats["rx_msgs"],
-                        d.get("op", "?") if isinstance(d, dict) else "?",
-                        len(raw))
-                    self._inbound.append((msg, peer))
-        except (asyncio.IncompleteReadError, ConnectionError, OSError,
-                asyncio.CancelledError, Exception):
-            pass
-        finally:
-            if self._sessions.get(peer) is sess:
-                self._drop_session(peer)
+    def _session_closed(self, peer: str, sess: _Session) -> None:
+        if self._sessions.get(peer) is sess:
+            self._drop_session(peer)
+
+    def _on_frame(self, peer: str, sess: _Session, ct: bytes) -> None:
+        """One frame, decoded into the inbound queue in the loop turn
+        that read it; raising (a frame that does not decrypt) makes the
+        connection close itself, which drops the session."""
+        payload = sess.decrypt(ct)
+        self.stats["recv_frames"] += 1
+        decoded = time.perf_counter()
+        # frame payload = packed list of per-message packed dicts
+        # (messages are serialized once at enqueue, even for
+        # broadcasts, then batched per peer at flush)
+        for raw in unpack(payload):
+            try:
+                d = unpack(raw)
+                msg = message_from_dict(d)
+            except Exception:
+                logger.warning("undecodable message from %s", peer)
+                continue
+            self._count_msg(
+                self.stats["rx_msgs"],
+                d.get("op", "?") if isinstance(d, dict) else "?",
+                len(raw))
+            self._inbound.append((msg, peer, decoded))
+        if self.arrival is not None:
+            self.arrival.set()
 
 
 class ClientStack:
@@ -527,8 +657,9 @@ class ClientStack:
         self.name = name
         self.host, self.port = host, port
         self._on_request = on_request
+        self.arrival: Optional[asyncio.Event] = None   # as TcpStack's
         self._server: Optional[asyncio.AbstractServer] = None
-        self._conns: dict[str, asyncio.StreamWriter] = {}
+        self._conns: dict[str, _Conn] = {}
         self._next_id = 0
         self._inbound: deque[tuple[dict, str]] = deque()
         self._quota = max_inbound_per_drain
@@ -539,17 +670,14 @@ class ClientStack:
 
     async def bind(self) -> int:
         if self._server is None:
-            self._server = await asyncio.start_server(
-                self._on_accept, self.host, self.port)
+            self._server = await asyncio.get_running_loop().create_server(
+                lambda: _Conn(self._on_accept), self.host, self.port)
             self.port = self._server.sockets[0].getsockname()[1]
         return self.port
 
     async def stop(self) -> None:
-        for w in self._conns.values():
-            try:
-                w.close()
-            except Exception:
-                pass
+        for conn in self._conns.values():
+            conn.close()
         self._conns.clear()
         if self._server is not None:
             self._server.close()
@@ -590,25 +718,22 @@ class ClientStack:
             self._send_packed(data, cid)
 
     def _send_packed(self, data: bytes, client_id: str) -> None:
-        writer = self._conns.get(client_id)
-        if writer is None:
+        conn = self._conns.get(client_id)
+        if conn is None:
             return
         try:
-            if writer.transport.get_write_buffer_size() > WRITE_HWM:
+            if conn.transport.get_write_buffer_size() > WRITE_HWM:
                 raise ConnectionError("client write buffer over HWM")
-            writer.write(len(data).to_bytes(4, "big") + data)
+            conn.write(len(data).to_bytes(4, "big") + data)
             self._last_activity[client_id] = time.monotonic()
         except Exception:
             self._drop_client(client_id)
 
     def _drop_client(self, client_id: str) -> None:
-        writer = self._conns.pop(client_id, None)
+        conn = self._conns.pop(client_id, None)
         self._last_activity.pop(client_id, None)
-        if writer is not None:
-            try:
-                writer.close()
-            except Exception:
-                pass
+        if conn is not None:
+            conn.close()
 
     def _sweep_idle(self) -> int:
         """Close connections with no traffic in either direction for
@@ -620,7 +745,7 @@ class ClientStack:
             self._drop_client(cid)
         return len(stale)
 
-    async def _on_accept(self, reader, writer) -> None:
+    def _on_accept(self, conn: _Conn) -> None:
         if len(self._conns) >= self.max_connections:
             self._sweep_idle()
         if len(self._conns) >= self.max_connections:
@@ -628,25 +753,21 @@ class ClientStack:
             # (bounded memory/FDs beat fairness here, as in the reference's
             # MAX_CONNECTED_CLIENTS_NUM)
             self.rejected_connections += 1
-            try:
-                writer.close()
-            except Exception:
-                pass
+            conn.close()
             return
         cid = f"client-{self._next_id}"
         self._next_id += 1
-        self._conns[cid] = writer
+        self._conns[cid] = conn
         self._last_activity[cid] = time.monotonic()
-        try:
-            while True:
-                frame = await _read_frame(reader)
-                msg = unpack(frame)
-                self._last_activity[cid] = time.monotonic()
-                if isinstance(msg, dict) and \
-                        len(self._inbound) < self.INBOUND_CAP:
-                    self._inbound.append((msg, cid))
-        except (asyncio.IncompleteReadError, ConnectionError, OSError,
-                Exception):
-            pass
-        finally:
-            self._drop_client(cid)
+        conn.deliver_frames(lambda frame: self._on_frame(cid, frame),
+                            lambda: self._drop_client(cid))
+
+    def _on_frame(self, cid: str, frame: bytes) -> None:
+        """A request reaches the inbox in the loop turn that read it; a
+        frame that is not msgpack closes the connection (_Conn)."""
+        msg = unpack(frame)
+        self._last_activity[cid] = time.monotonic()
+        if isinstance(msg, dict) and len(self._inbound) < self.INBOUND_CAP:
+            self._inbound.append((msg, cid))
+            if self.arrival is not None:
+                self.arrival.set()

@@ -4,9 +4,12 @@ Reference behavior: stp_core/loop/looper.py — a Looper owns Prodables and
 calls prod() on each in a run-forever loop, interleaved with the event loop
 so socket I/O and timers stay live. Here the transport IS asyncio, so the
 Looper is small: one task per node that services the shared QueueTimer,
-drains the node's transport stacks, and prods the node, sleeping
-prod_interval between cycles (long sleeps would add ordering latency; the
-interval is the reference's prodable loop granularity).
+drains the node's transport stacks, and prods the node. Between two busy
+cycles it lets the loop run what the selector found ready (a frame on a
+socket is decoded before the next cycle's drain); an idle node waits for a
+frame to land or for prod_interval, whichever is first (the interval is the
+longest an idle node goes without servicing its timers: the reference's
+prodable loop granularity).
 """
 from __future__ import annotations
 
@@ -26,6 +29,15 @@ class Prodable:
         self.node_stack = node_stack
         self.client_stack = client_stack
         self.timer = timer
+        # set by either stack when a frame lands in its inbound queue:
+        # what an idle Looper waits on
+        self.arrival = asyncio.Event()
+        for stack in (node_stack, client_stack):
+            if stack is not None:
+                stack.arrival = self.arrival
+        # why the Looper ran each cycle after the first: the one before
+        # it was busy, a frame arrived, or prod_interval ran out
+        self.wakes = {"busy": 0, "arrival": 0, "interval": 0}
 
     async def start(self) -> None:
         if self.node_stack is not None:
@@ -89,10 +101,27 @@ class Looper:
                        for p in self._prodables]
 
     async def _drive(self, prodable: Prodable) -> None:
+        arrival, wakes = prodable.arrival, prodable.wakes
         while self._running:
-            busy = prodable.prod()
-            # busy cycles yield to the loop but don't sleep the full interval
-            await asyncio.sleep(0 if busy else self.prod_interval)
+            if prodable.prod():
+                # a busy cycle yields to the loop and does not sleep. TWO
+                # turns: after one, this task's next step is already in the
+                # ready queue when the selector is polled, so the callbacks
+                # of sockets that are readable queue BEHIND it and the next
+                # cycle would run blind to a frame that is already here;
+                # the second turn puts the cycle behind them (and behind
+                # every other task's step: dial loops, accepts, status)
+                await asyncio.sleep(0)
+                await asyncio.sleep(0)
+                wakes["busy"] += 1
+                continue
+            arrival.clear()
+            try:
+                async with asyncio.timeout(self.prod_interval):
+                    await arrival.wait()
+                wakes["arrival"] += 1
+            except TimeoutError:
+                wakes["interval"] += 1
 
     async def run_until(self, predicate: Callable[[], bool],
                         timeout: float) -> bool:

@@ -234,6 +234,10 @@ class Node:
         self.pool_manager = components.pool_manager
         self.pool_manager._on_changed = self._on_pool_changed
         self.on_pool_changed_callbacks: list[Callable[[], None]] = []
+        # the runner that owns real sockets installs a reader of its
+        # stacks' and looper's counters (tools/start_node.py); a node on
+        # the sim fabric has none
+        self.transport_report: Optional[Callable[[], dict]] = None
         self.validators = self.pool_manager.node_names or [name]
         self.quorums = self.pool_manager.quorums
 
@@ -1923,13 +1927,18 @@ class Node:
         count += n
         if traced:
             _phase("prod.replicas", self.replicas.service_all)
-            count += _phase("prod.ordered", self._service_ordered)
         else:
             self.replicas.service_all()
-            count += self._service_ordered()
+        # a PRE-PREPARE cut just now leaves before the previous batch's
+        # commit and REPLY fan-out, not behind them: the peers apply it
+        # while this node commits
+        self.node_bus.flush()
+        count += (_phase("prod.ordered", self._service_ordered) if traced
+                  else self._service_ordered())
         # one PropagateBatch per tick instead of one wire message per vote:
         # the n^2 propagate message COUNT amortizes across the whole tick
         self.propagator.flush_outbox()
+        self.node_bus.flush()
         return count
 
     # --- client pipeline --------------------------------------------------
@@ -2488,4 +2497,10 @@ class Node:
             # a write's time on this node by stage (tracing.StageClock):
             # cumulative count and sum, quantiles since the last flush
             "stages": self.stages.report(),
+            # when a message left and when a frame was seen: frames by
+            # who flushed them, the holds in the outbox and in the
+            # inbound queue, why the looper ran each cycle (TcpStack.stats,
+            # Prodable.wakes); None without real sockets
+            "transport": (self.transport_report()
+                          if self.transport_report is not None else None),
         }

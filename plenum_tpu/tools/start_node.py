@@ -230,6 +230,20 @@ def build_node(name: str, base_dir: str, backend: str = "cpu",
     from plenum_tpu.common.metrics import MetricsName
     from plenum_tpu.common.timer import RepeatingTimer
 
+    prodable = Prodable(node, node_stack, client_stack, timer)
+
+    def transport_report() -> dict:
+        """When a message left and when a frame was seen (cumulative)."""
+        s = node_stack.stats
+        return {"sent_frames": s["sent_frames"],
+                "recv_frames": s["recv_frames"],
+                "flushes": dict(s["flushes"]),
+                "tx_hold": dict(s["tx_hold"]),
+                "rx_hold": dict(s["rx_hold"]),
+                "wakes": dict(prodable.wakes)}
+
+    node.transport_report = transport_report
+
     def sample_transport_stats():
         s = node_stack.stats
         metrics.add_event(MetricsName.TRANSPORT_DROPPED_FRAMES,
@@ -245,6 +259,11 @@ def build_node(name: str, base_dir: str, backend: str = "cpu",
             metrics.add_event(MetricsName.TRANSPORT_TX_BYTES if
                               direction == "tx" else
                               MetricsName.TRANSPORT_RX_BYTES, total)
+        for group in ("flushes", "tx_hold", "rx_hold"):
+            for key, value in s[group].items():
+                metrics.add_event(f"transport.{group}.{key}", value)
+        for cause, n in prodable.wakes.items():
+            metrics.add_event(f"looper.wakes.{cause}", n)
 
     node._transport_stats_timer = RepeatingTimer(
         timer, config.METRICS_FLUSH_INTERVAL, sample_transport_stats)
@@ -276,7 +295,7 @@ def build_node(name: str, base_dir: str, backend: str = "cpu",
         node_stack.maintain_connections()
 
     node.on_pool_changed_callbacks.append(sync_registry_from_pool)
-    return Prodable(node, node_stack, client_stack, timer), node, registry
+    return prodable, node, registry
 
 
 def main(argv=None):

@@ -21,7 +21,17 @@ mean residence, the same over the requests that had every stage (`whole`),
 and the client's p50 beside the residence's) and `probe_service_waits`
 (the crypto service's `waits`: a job's time on its queue and from there to
 its verdicts, window growth for the means); both null on a checkout older
-than PR 39. With `--trace 1` it keeps the run's xplane under
+than PR 39; `probe_transport` (the first validator's VALIDATOR_INFO
+`transport` over the window: frames written by who flushed them,
+`in_cycle` or `scheduled`, and the share the cycles' own flush points took;
+`tx_hold`, a frame's oldest message from `_enqueue_send` to the socket
+write, and `rx_hold`, a message from its frame's decode to the bus; the
+looper's `wakes` by cause, `busy`, `arrival`, `interval`; null where the
+nodes share a process and on a checkout older than PR 40) and
+`probe_round` (the window's batch cuts on the first validator, from
+VALIDATOR_INFO `batch_controller.cuts`: cuts a second and the ms one 3PC
+round takes while the pool orders one batch at a time). With `--trace 1`
+it keeps the run's xplane under
 `chiprun_out/probe_trace/` (where it is under 24 MiB) and prints
 `probe_gaps`: each device idle gap of the sample with the host spans
 (`prod.*`, `ring.*`, `svc.*`) that overlap it and the share of it each
@@ -88,20 +98,25 @@ def window_lanes(seen: list) -> dict | None:
             if total else None}      # a host plane dispatches no program
 
 
-def first_validator_stages(topo) -> dict | None:
-    """The first validator's VALIDATOR_INFO `stages` as it stands now."""
+def first_validator_info(topo) -> dict:
+    """The first validator's VALIDATOR_INFO as it stands now (of nodes in
+    this process: the parts of it the probe reads)."""
     if hasattr(topo, "last_infos"):             # four owners: just fetched
-        return topo.last_infos[0].get("stages")
+        return topo.last_infos[0]
     pool = getattr(topo, "pool", None)
     if pool is not None:                        # nodes in this process
-        clock = getattr(pool.nodes[topo.names[0]], "stages", None)
-        return clock.report() if clock is not None else None
+        node = pool.nodes[topo.names[0]]
+        clock = getattr(node, "stages", None)
+        ctl = node.batch_controller
+        return {"stages": clock.report() if clock is not None else None,
+                "batch_controller": ctl.trajectory() if ctl is not None
+                else None}
     from benchmarks.tcp_client import ask
     from plenum_tpu.execution.action_manager import VALIDATOR_INFO_ACTION
     msg = topo.loop.run_until_complete(ask(
         topo.addrs[topo.names[0]],
         topo._trustee_request({"type": VALIDATOR_INFO_ACTION})))
-    return ((msg.get("result") or {}).get("data") or {}).get("stages")
+    return (msg.get("result") or {}).get("data") or {}
 
 
 def service_waits(topo) -> dict | None:
@@ -145,6 +160,45 @@ def window_stages(seen: list, topo, numbers: dict) -> dict | None:
                 "stage.residence"]["count"]),
             "residence_p50_ms": stages["stage.residence"]["p50_ms"],
             "client_p50_ms": numbers.get("latency_p50_ms")}
+
+
+def window_transport(seen: list) -> dict | None:
+    """When a message left and when a frame was seen, over the window:
+    the growth between two readings of VALIDATOR_INFO `transport`."""
+    if len(seen) < 2 or not seen[0] or not seen[1]:
+        return None
+    start, end = seen
+
+    def grew(group):
+        return {k: end[group][k] - start[group].get(k, 0)
+                for k in end[group]}
+    flushes = grew("flushes")
+    frames = end["sent_frames"] - start["sent_frames"]
+    return dict(
+        grown([{k: r[k] for k in ("tx_hold", "rx_hold")} for r in seen]),
+        sent_frames=frames,
+        recv_frames=end["recv_frames"] - start["recv_frames"],
+        flushes=flushes,
+        in_cycle_share=round(flushes["in_cycle"] / frames, 4)
+        if frames else None,
+        wakes=grew("wakes"))
+
+
+def window_round(seen: list, numbers: dict) -> dict | None:
+    """Batch cuts over the window on the first validator, and what one
+    3PC round takes while the pool orders one batch at a time."""
+    cuts = [((info or {}).get("batch_controller") or {}).get("cuts")
+            for info in seen]
+    if len(cuts) < 2 or cuts[0] is None or cuts[1] is None:
+        return None
+    n = sum(cuts[1].values()) - sum(cuts[0].values())
+    # the window's seconds, as window_numbers divided by them
+    seconds = (numbers["acked_in_window"] / numbers["per_s"]
+               if numbers.get("per_s") else None)
+    return {"cuts": n,
+            "cuts_per_s": round(n / seconds, 2) if seconds else None,
+            "round_ms": round(seconds / n * 1e3, 2) if seconds and n
+            else None}
 
 
 HOST_SPANS = ("prod.", "ring.", "svc.")
@@ -206,7 +260,7 @@ def keep_xplane(xplane: str, tag: str) -> dict:
 
 def as_cell() -> int:
     from benchmarks import cell, manifest, readers, trace_reduce
-    runs, lanes_seen, windows, stages_seen, waits_seen = [], [], [], [], []
+    runs, lanes_seen, windows, infos_seen, waits_seen = [], [], [], [], []
     init, metrics, window = cell.Run.__init__, cell.metrics, cell.Run.window
     collect_trace = cell.Run.collect_trace
 
@@ -225,7 +279,7 @@ def as_cell() -> int:
         def snapshot_and_lanes():
             got = snapshot()
             lanes_seen.append(by_lanes(topo))
-            stages_seen.append(first_validator_stages(topo))
+            infos_seen.append(first_validator_info(topo))
             waits_seen.append(service_waits(topo))
             return got
         topo.snapshot = snapshot_and_lanes
@@ -235,7 +289,7 @@ def as_cell() -> int:
         # set-up may have taken one already (the four owners' does)
         at = len(lanes_seen)
         got = window(self, *args, **kwargs)
-        windows.append((lanes_seen[at:at + 2], stages_seen[at:at + 2],
+        windows.append((lanes_seen[at:at + 2], infos_seen[at:at + 2],
                         waits_seen[at:at + 2]))
         return got
 
@@ -254,11 +308,15 @@ def as_cell() -> int:
                     manifest.metric_spec("per_layer", m["name"]), obs)
             except Exception as e:      # a reader that wants the trace
                 layers[m["name"]] = f"not read: {e!r}"
-        lanes, stages, waits = windows[0] if windows else ([], [], [])
+        lanes, infos, waits = windows[0] if windows else ([], [], [])
         cell.say(probe_per_layer=layers, probe_cuts=cut_counts(runs[0].topo),
                  probe_lanes=window_lanes(lanes),
-                 probe_stages=window_stages(stages, runs[0].topo,
-                                            obs["numbers"]),
+                 probe_stages=window_stages(
+                     [info.get("stages") for info in infos], runs[0].topo,
+                     obs["numbers"]),
+                 probe_transport=window_transport(
+                     [info.get("transport") for info in infos]),
+                 probe_round=window_round(infos, obs["numbers"]),
                  probe_service_waits=dict(
                      grown(waits) or {}, since_pin=waits[1]) if len(
                      waits) > 1 and waits[1] else None)

@@ -405,3 +405,237 @@ def test_client_connection_flood_is_bounded():
         await stack.stop()
 
     asyncio.run(scenario())
+
+
+# --- when a message leaves, when a frame is read (PR 40) -------------------
+
+class _Relay:
+    """Stands where the Node does in a Prodable: nothing of its own to do;
+    what it casts, it casts in the handlers the node stack's drain runs."""
+
+    def prod(self) -> int:
+        return 0
+
+
+def _readable(stack: TcpStack, peer: str, timeout: float = 1.0) -> bool:
+    """Bytes wait on `stack`'s socket to `peer`, asked of the KERNEL:
+    no turn of the event loop is taken."""
+    import select
+    sock = stack._sessions[peer].conn.transport.get_extra_info("socket")
+    return bool(select.select([sock.fileno()], [], [], timeout)[0])
+
+
+def test_vote_cast_inside_prod_has_left_when_prod_returns():
+    """Beta answers each InstanceChange it drains with one of its own (a
+    PREPARE answering a PRE-PREPARE, in small). When Prodable.prod()
+    returns, before any await, the answer is on Alpha's socket."""
+    from plenum_tpu.node.looper import Prodable
+
+    async def main():
+        reg, stacks = await _make_pair()
+        a, b = stacks["Alpha"], stacks["Beta"]
+        assert await _wait(lambda: a.connected == {"Beta"}
+                           and b.connected == {"Alpha"})
+        b.bus.subscribe(
+            InstanceChange,
+            lambda m, f: b.bus.send(
+                InstanceChange(view_no=m.view_no + 100, reason=0), f))
+        prodable = Prodable(_Relay(), b)
+        a.bus.send(InstanceChange(view_no=1, reason=0), "Beta")
+        assert await _wait(lambda: len(b._inbound) == 1)
+        assert not _readable(a, "Beta", timeout=0)
+        base = dict(b.stats["flushes"]), b.stats["sent_frames"]
+        # ---- no await from here ...
+        assert prodable.prod() == 1
+        assert b.stats["sent_frames"] == base[1] + 1
+        assert b.stats["flushes"]["in_cycle"] == base[0]["in_cycle"] + 1
+        assert b.stats["flushes"]["scheduled"] == base[0]["scheduled"]
+        assert b._scheduled_flush is None      # and nothing left to run
+        assert _readable(a, "Beta")
+        # ---- ... to here
+        got = []
+        a.bus.subscribe(InstanceChange, lambda m, f: got.append(m.view_no))
+        assert await _wait(lambda: (a.drain(), got)[1] == [101])
+        hold = b.stats["tx_hold"]
+        assert hold["count"] == b.stats["sent_frames"]
+        assert 0 < hold["sum_s"] < 1.0
+        assert a.stats["rx_hold"]["count"] == 1 == b.stats["rx_hold"]["count"]
+        await a.stop()
+        await b.stop()
+
+    asyncio.run(main())
+
+
+def test_fifty_votes_in_one_phase_are_one_frame():
+    """Coalescing per flush point: what ONE drain casts to a peer is one
+    encrypted frame, written by the drain's own flush."""
+    async def main():
+        reg, stacks = await _make_pair()
+        a, b = stacks["Alpha"], stacks["Beta"]
+        assert await _wait(lambda: a.connected == {"Beta"}
+                           and b.connected == {"Alpha"})
+
+        def fifty(m, f):
+            for v in range(50):
+                b.bus.send(InstanceChange(view_no=v, reason=0), f)
+        b.bus.subscribe(InstanceChange, fifty)
+        got = []
+        a.bus.subscribe(InstanceChange, lambda m, f: got.append(m.view_no))
+        a.bus.send(InstanceChange(view_no=0, reason=0), "Beta")
+        assert await _wait(lambda: len(b._inbound) == 1)
+        base = b.stats["sent_frames"]
+        assert b.drain() == 1
+        assert b.stats["sent_frames"] == base + 1
+        assert b.stats["flushes"]["in_cycle"] == 1
+        assert await _wait(lambda: (a.drain(), len(got))[1] >= 50)
+        assert got == list(range(50))
+        assert a.stats["recv_frames"] == 1
+        await a.stop()
+        await b.stop()
+
+    asyncio.run(main())
+
+
+def test_send_outside_any_cycle_leaves_through_the_scheduled_flush():
+    """Nobody calls flush(): a handshake's tail, a reconnect, a test. The
+    send schedules one for the loop's next turn, as before."""
+    async def main():
+        reg, stacks = await _make_pair()
+        a, b = stacks["Alpha"], stacks["Beta"]
+        assert await _wait(lambda: a.connected == {"Beta"}
+                           and b.connected == {"Alpha"})
+        base = a.stats["sent_frames"]
+        a.bus.send(InstanceChange(view_no=5, reason=0), "Beta")
+        a.bus.send(InstanceChange(view_no=6, reason=0), "Beta")
+        assert a.stats["sent_frames"] == base  # queued, not written
+        assert a._scheduled_flush is not None
+        await asyncio.sleep(0)                 # the turn the flush rides
+        assert a.stats["sent_frames"] == base + 1
+        assert a.stats["flushes"] == {"in_cycle": 0, "scheduled": 1}
+        assert a._scheduled_flush is None
+        assert _readable(b, "Alpha")
+        await a.stop()
+        await b.stop()
+
+    asyncio.run(main())
+
+
+def test_write_hwm_still_drops_a_peer_that_stopped_reading(monkeypatch):
+    from plenum_tpu.network import tcp_stack
+    monkeypatch.setattr(tcp_stack, "WRITE_HWM", 256 * 1024)
+
+    async def main():
+        reg, stacks = await _make_pair()
+        a, b = stacks["Alpha"], stacks["Beta"]
+        assert await _wait(lambda: a.connected == {"Beta"}
+                           and b.connected == {"Alpha"})
+        b._sessions["Alpha"].conn.transport.pause_reading()
+        blob = {"op": "BLOB", "data": b"\x00" * (512 * 1024)}
+        for _ in range(64):                    # 32 MiB at a deaf peer
+            a.bus.send(blob, "Beta")
+            a.flush()
+            if a.stats["dropped_sessions"]:
+                break
+        assert a.stats["dropped_sessions"] == 1
+        assert a.stats["dropped_frames"] == 1
+        assert "Beta" not in a._sessions
+        assert a.bus.connecteds == set()
+        await a.stop()
+        await b.stop()
+
+    asyncio.run(main())
+
+
+def test_client_request_is_in_the_inbox_in_the_turn_that_read_it():
+    """ClientStack cuts frames in data_received too: the turn after the
+    selector saw the bytes the request is queued (a StreamReader task
+    took one turn more), and the looper's arrival event is set."""
+    async def main():
+        stack = ClientStack("srv", "127.0.0.1", 0, on_request=None)
+        stack.arrival = asyncio.Event()
+        port = await stack.bind()
+        reader, writer = await asyncio.open_connection("127.0.0.1", port)
+        assert await _wait(lambda: len(stack._conns) == 1)
+        payload = pack({"op": "NOOP"})
+        frame = len(payload).to_bytes(4, "big") + payload
+        # one frame and a half in one segment, the rest in the next
+        writer.write(frame + frame[:5])
+        await asyncio.sleep(0)     # the selector is polled behind this step
+        await asyncio.sleep(0)     # ... and its callback has run before this
+        assert len(stack._inbound) == 1
+        assert stack.arrival.is_set()
+        writer.write(frame[5:])
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+        assert [m for m, _ in stack._inbound] == [{"op": "NOOP"}] * 2
+        writer.close()
+        await stack.stop()
+
+    asyncio.run(main())
+
+
+class _FakeTransport:
+    def __init__(self):
+        self.closed = False
+
+    def is_closing(self):
+        return self.closed
+
+    def close(self):
+        self.closed = True
+
+
+@pytest.mark.parametrize("chunks, frames, closed", [
+    # two frames and the head of a third in one segment, then its tail
+    ([b"\x00\x00\x00\x01a\x00\x00\x00\x02bc\x00\x00", b"\x00\x03de", b"f"],
+     [b"a", b"bc", b"def"], False),
+    # a header split across segments; an empty frame
+    ([b"\x00", b"\x00\x00", b"\x02xy\x00\x00\x00\x00"], [b"xy", b""], False),
+    # a length over MAX_FRAME closes the connection, nothing delivered
+    ([b"\x7f\xff\xff\xff", b"zz"], [], True),
+    # a frame the session refuses (does not decrypt) closes it; what was
+    # behind it in the segment is not delivered
+    ([b"\x00\x00\x00\x01a\x00\x00\x00\x03BAD\x00\x00\x00\x01b"],
+     [b"a"], True),
+])
+def test_conn_cuts_frames_as_the_bytes_arrive(chunks, frames, closed):
+    from plenum_tpu.network.tcp_stack import _Conn
+    got = []
+
+    def on_frame(frame):
+        if frame == b"BAD":
+            raise ValueError("does not decrypt")
+        got.append(frame)
+
+    conn = _Conn()
+    conn.connection_made(_FakeTransport())
+    conn.deliver_frames(on_frame, lambda: None)
+    for chunk in chunks:
+        conn.data_received(chunk)
+    assert got == frames
+    assert conn.transport.closed is closed
+
+
+def test_frame_behind_the_handshake_in_one_segment_is_delivered():
+    """The dialer may flush its queued outbox the instant its handshake
+    ends, so the acceptor can read the handshake's last bytes and the
+    first frame in ONE segment: deliver_frames cuts what is buffered."""
+    from plenum_tpu.network.tcp_stack import _Conn
+    got = []
+
+    async def main():
+        conn = _Conn()
+        conn.connection_made(_FakeTransport())
+        conn.data_received(b"hand" + b"\x00\x00\x00\x02hi")
+        assert await conn.readexactly(4) == b"hand"
+        conn.deliver_frames(got.append, lambda: None)
+        assert got == [b"hi"]
+        # a connection lost mid-handshake ends readexactly, not hangs it
+        lost = _Conn()
+        lost.connection_made(_FakeTransport())
+        lost.data_received(b"ha")
+        asyncio.get_running_loop().call_soon(lost.connection_lost, None)
+        with pytest.raises(asyncio.IncompleteReadError):
+            await lost.readexactly(4)
+
+    asyncio.run(main())
