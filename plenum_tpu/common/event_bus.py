@@ -17,8 +17,13 @@ class Router:
     def __init__(self):
         self._handlers: dict[type, list[Callable]] = {}
 
-    def subscribe(self, message_type: type, handler: Callable) -> Callable[[], None]:
-        self._handlers.setdefault(message_type, []).append(handler)
+    def subscribe(self, message_type: type, handler: Callable,
+                  first: bool = False) -> Callable[[], None]:
+        """first: run before the handlers subscribed so far (an observer
+        that has to see a message before a handler's own sends nest
+        inside its dispatch)."""
+        handlers = self._handlers.setdefault(message_type, [])
+        handlers.insert(0 if first else len(handlers), handler)
         def unsubscribe():
             try:
                 self._handlers[message_type].remove(handler)

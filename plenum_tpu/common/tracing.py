@@ -460,6 +460,12 @@ class StageClock(NullStageClock):
         return out
 
 
+def unspanned(name: str, run: Callable):
+    """The `span` of a service nobody handed a host-span helper to (the
+    node hands node.py's `_phase` to its master's services): just run."""
+    return run()
+
+
 def make_stage_clock(metrics, tracer, now: Callable[[], float]):
     """On wherever the node has a metrics collector or a tracer: a
     NullMetricsCollector node without a tracer gets the shared no-op."""

@@ -60,14 +60,28 @@ class CheckpointService:
         seq_no = ordered.pp_seq_no
         if seq_no % self._chk_freq != 0:
             return
-        self._create_checkpoint(seq_no)
+        # The batch's OWN audit root where it has one (the master's
+        # batches; ref _do_checkpoint(ordered.auditTxnRootHash)): every
+        # node that orders the batch names the same digest. The
+        # provider's answer is the audit ledger's uncommitted root NOW,
+        # which already holds the next batch on a node whose PRE-PREPARE
+        # for it came before this batch's last COMMIT: such a node's
+        # checkpoint matched nobody's, never stabilized, and left the
+        # survivors of a dead primary with no checkpoint in common.
+        self._create_checkpoint(
+            seq_no, ordered.audit_txn_root or self._digest_for(seq_no))
 
-    def _create_checkpoint(self, seq_no: int) -> None:
+    def _create_checkpoint(self, seq_no: int, digest: str) -> None:
+        # the range is the checkpoint's own (the CHK_FREQ batches it
+        # closes), not "since whatever was stable here when it was cut":
+        # a view change compares whole checkpoints across its voters, and
+        # a node whose previous checkpoint stabilized a moment later than
+        # its peers' named another start for the same checkpoint
         msg = Checkpoint(inst_id=self._data.inst_id,
                          view_no=self._data.view_no,
-                         seq_no_start=self._data.stable_checkpoint + 1,
+                         seq_no_start=max(1, seq_no - self._chk_freq + 1),
                          seq_no_end=seq_no,
-                         digest=self._digest_for(seq_no))
+                         digest=digest)
         self._own[seq_no] = msg
         self._data.checkpoints.append(msg)
         self._network.send(msg)

@@ -154,6 +154,16 @@ class OrderingService:
         # backup instances joining a new view adopt the first pre-prepare
         # they see as their position (ref _setup_last_ordered_for_non_master)
         self._needs_last_ordered_setup = False
+        # the last view change as this instance saw it (Node.validator_info
+        # `view_change`): the batches it reverted at the start, the old
+        # view's batches the NEW_VIEW had re-ordered, and the finalised
+        # requests waiting when the new view's first fresh PRE-PREPARE
+        # was cut (primary) or accepted (the others). `span` is the
+        # node's host-span helper (node.py _phase) where one was handed
+        # in; both are touched by a view change and by nothing else.
+        self.vc_episode: Optional[dict] = None
+        self._first_cut_due = False
+        self.span = tracing.unspanned
 
     def stop(self) -> None:
         """Detach from the shared network bus (replica removal): a removed
@@ -296,13 +306,22 @@ class OrderingService:
                     break        # everything queued is awaiting its body
                 # queue wait attributed from the oldest request actually
                 # CUT (a stale bodyless head must not inflate the sample)
-                self._send_one_batch(lid, digests,
-                                     queue_wait=max(0.0, now - oldest_cut),
-                                     reason=reason)
+                queue_wait = max(0.0, now - oldest_cut)
+                if self._first_cut_due:
+                    self._note_first_cut(len(digests) + len(queue))
+                    self.span("vc.first_cut", lambda: self._send_one_batch(
+                        lid, digests, queue_wait=queue_wait, reason=reason))
+                else:
+                    self._send_one_batch(lid, digests, queue_wait=queue_wait,
+                                         reason=reason)
                 sent += 1
                 if force_empty:
                     break
         return sent
+
+    def _note_first_cut(self, waiting: int) -> None:
+        self._first_cut_due = False
+        self.vc_episode["waiting_at_first_cut"] = waiting
 
     def _cut_reason(self, queue: OrderedDict, now: float, max_size: int,
                     max_wait: float, force_empty: bool) -> Optional[str]:
@@ -568,6 +587,9 @@ class OrderingService:
                 self._suspect(Suspicions.PPR_DIGEST_WRONG, sender)
                 return DISCARD
         # Re-apply the batch and cross-check every root (ref :871-931).
+        if self._first_cut_due and _orig_view(msg) == msg.view_no:
+            self._note_first_cut(sum(
+                len(q) for q in self.request_queues.values()))
         if self._data.is_master and self._executor is not None and not rerun:
             reqs = [self._get_request(d) for d in msg.req_idr]
             # apply under the ORIGINAL view: the audit txn snapshots
@@ -1087,7 +1109,14 @@ class OrderingService:
         pre-prepares for possible re-ordering (ref :2380)."""
         self._phase_ts.clear()      # timings don't span views
         self._cut_ts.clear()        # controller spans don't span views
-        self.revert_unordered_batches()
+        reverted = self.span("vc.revert_batches",
+                             self.revert_unordered_batches)
+        if self._data.is_master:
+            self.vc_episode = {"view_no": msg.view_no,
+                               "reverted_batches": reverted,
+                               "reordered_batches": None,
+                               "waiting_at_first_cut": None}
+            self._first_cut_due = True
         # ALL pre-prepares (ordered ones too) become old-view material: a
         # NewView may cite an already-ordered batch, and both the re-sending
         # primary and the MessageReq server look it up by ORIGINAL view here
@@ -1127,6 +1156,10 @@ class OrderingService:
         """Re-order the prepared batches carried into the new view
         (ref process_new_view_checkpoints_applied :2380)."""
         self._last_new_view_msg = msg
+        if self._first_cut_due:
+            # what the new view re-certifies before anything fresh: the
+            # batches prepared since the checkpoint it starts from
+            self.vc_episode["reordered_batches"] = len(msg.batches)
         if not self._data.is_participating:
             # a view change can complete WHILE this replica catches up
             # (internal-bus traffic bypasses the wire stasher). Applying

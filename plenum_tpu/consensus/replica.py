@@ -13,6 +13,7 @@ from typing import Callable, Optional
 
 from plenum_tpu.common.event_bus import ExternalBus, InternalBus
 from plenum_tpu.common.internal_messages import (CheckpointStabilized,
+                                                 NeedMasterCatchup,
                                                  NewViewAccepted,
                                                  NewViewCheckpointsApplied,
                                                  ViewChangeStarted)
@@ -152,6 +153,15 @@ class Replica:
     # --- event glue -------------------------------------------------------
 
     def _on_new_view_accepted(self, msg: NewViewAccepted) -> None:
+        if self._data.is_master \
+                and msg.checkpoint[2] > self._data.last_ordered_3pc[1]:
+            # the new view starts from a checkpoint this node has not
+            # ordered up to (f+1 of the voters hold it, this one lagged
+            # them across its boundary): the batches below it are re-run
+            # by nobody, so they are fetched. Started before the
+            # re-ordering below, which then waits for the catchup's end
+            # (ordering.process_new_view_checkpoints_applied).
+            self.internal_bus.send(NeedMasterCatchup())
         self.checkpointer.process_new_view_accepted(msg.checkpoint)
         self.internal_bus.send(NewViewCheckpointsApplied(
             view_no=msg.view_no, checkpoint=msg.checkpoint, batches=msg.batches))

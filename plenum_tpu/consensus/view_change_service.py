@@ -35,6 +35,7 @@ from plenum_tpu.common.stashing import (DISCARD, PROCESS, STASH, StashReason,
                                         StashingRouter)
 from plenum_tpu.common.suspicion_codes import Suspicions
 from plenum_tpu.common.timer import TimerService
+from plenum_tpu.common.tracing import unspanned
 from plenum_tpu.config import Config
 
 from .batch_id import BatchID
@@ -62,9 +63,14 @@ class NewViewBuilder:
                 usable = sum(1 for v in vcs if end >= v.stable_checkpoint)
                 if not self._data.quorums.strong.is_reached(usable):
                     continue
-                # enough nodes actually hold it
+                # enough nodes actually hold it: f+1, so one of them is
+                # honest and ordered through it (ref calc_checkpoint:
+                # quorums.weak). n-f holders cannot be asked for: a view
+                # change runs on n-f votes, and a voter that lags the
+                # others across a checkpoint boundary, or stabilized a
+                # checkpoint later than they did, holds another set
                 holders = sum(1 for v in vcs if cp in {tuple(c) for c in v.checkpoints})
-                if not self._data.quorums.strong.is_reached(holders):
+                if not self._data.quorums.weak.is_reached(holders):
                     continue
                 if best is None or end > best[2]:
                     best = cp
@@ -178,6 +184,13 @@ class ViewChangeService:
         # where one view change takes 1.1x the flat timeout escalates
         # forever — each attempt aborted exactly before it can finish.
         self._escalations = 0
+        # the node's host-span helper (node.py _phase) where one was
+        # handed in: the new primary's selection and everyone's
+        # re-derivation of it are spans `vc.build_new_view` /
+        # `vc.check_new_view` of a traced node
+        self.span = unspanned
+        # why the new primary's last attempt at a NEW_VIEW gave none
+        self._no_selection: Optional[str] = None
 
         # per view: author node -> ViewChange
         self._view_changes: dict[int, dict[str, ViewChange]] = {}
@@ -381,12 +394,36 @@ class ViewChangeService:
                 self._pending_new_view = None
         self._try_finish(view_no)
 
+    def progress(self) -> dict:
+        """Where a view change in progress stands (VALIDATOR_INFO
+        `view_change.waiting_on`): whose votes this node holds for the
+        view it waits in, which of them the would-be primary could cite
+        (acknowledged by n-f-1 others), and whether a NEW_VIEW is held or
+        pending on a missing vote."""
+        view_no = self._data.view_no
+        return {"view_no": view_no, "primary": self._data.primary_name,
+                "votes_from": sorted(self._view_changes.get(view_no, {})),
+                "citable": sorted(self._citable(view_no)),
+                "new_view_held": self._new_view is not None,
+                "new_view_pending_on_votes":
+                    self._pending_new_view is not None,
+                "no_selection": self._no_selection,
+                "escalations": self._escalations}
+
+    def _citable(self, view_no: int) -> dict:
+        """The votes a NEW_VIEW of `view_no` may cite: this node's own and
+        those acknowledged by n-f-1 others."""
+        return {a: vc for a, vc in self._view_changes.get(view_no, {}).items()
+                if a == self._data.node_name or self._acked(view_no, a, vc)}
+
     def _try_build_new_view(self, view_no: int) -> None:
-        vcs_by_author = self._view_changes.get(view_no, {})
-        confirmed = {a: vc for a, vc in vcs_by_author.items()
-                     if a == self._data.node_name or self._acked(view_no, a, vc)}
+        confirmed = self._citable(view_no)
         if not self._data.quorums.view_change.is_reached(len(confirmed)):
             return
+        self.span("vc.build_new_view",
+                  lambda: self._build_new_view(view_no, confirmed))
+
+    def _build_new_view(self, view_no: int, confirmed: dict) -> None:
         # The primary may cite ANY view-change quorum (PBFT: n-f suffice).
         # Try the full confirmed set first; if the builder cannot produce a
         # consistent selection — one diverged member's conflicting batch
@@ -417,10 +454,14 @@ class ViewChangeService:
             vcs = [vc for _, vc in ordered]
             cp = self._builder.calc_checkpoint(vcs)
             if cp is None:
+                self._no_selection = f"no checkpoint over {subset}"
                 continue
             batches = self._builder.calc_batches(cp, vcs)
             if batches is None:
+                self._no_selection = (f"no batch selection past checkpoint "
+                                      f"{cp[2]} over {subset}")
                 continue
+            self._no_selection = None
             nv = NewView(view_no=view_no,
                          view_changes=tuple(
                              (a, view_change_digest(vc)) for a, vc in ordered),
@@ -469,18 +510,25 @@ class ViewChangeService:
                     f"NEW_VIEW cites a ViewChange by {author} that differs "
                     f"from the one we received")
             cited.append(vc)
-        cp = self._builder.calc_checkpoint(cited)
-        if cp is None or tuple(cp) != tuple(msg.checkpoint):
-            return self._reject_new_view("NEW_VIEW checkpoint does not follow "
-                                         "from the cited votes")
-        batches = self._builder.calc_batches(cp, cited)
-        if batches is None or [tuple(b.to_list()) for b in batches] != \
-                [tuple(b) for b in msg.batches]:
-            return self._reject_new_view("NEW_VIEW batches do not follow "
-                                         "from the cited votes")
+        why = self.span("vc.check_new_view",
+                        lambda: self._new_view_fault(msg, cited))
+        if why is not None:
+            return self._reject_new_view(why)
         self._pending_new_view = None
         self._finish(msg)
         return PROCESS
+
+    def _new_view_fault(self, msg: NewView, cited: list) -> Optional[str]:
+        """Re-derive the selection from the cited votes -> why the
+        NEW_VIEW does not follow from them, or None when it does."""
+        cp = self._builder.calc_checkpoint(cited)
+        if cp is None or tuple(cp) != tuple(msg.checkpoint):
+            return "NEW_VIEW checkpoint does not follow from the cited votes"
+        batches = self._builder.calc_batches(cp, cited)
+        if batches is None or [tuple(b.to_list()) for b in batches] != \
+                [tuple(b) for b in msg.batches]:
+            return "NEW_VIEW batches do not follow from the cited votes"
+        return None
 
     def process_requested_view_change(self, vc: ViewChange, author: str) -> None:
         """A peer-served ViewChange vote. Safe to record under the claimed

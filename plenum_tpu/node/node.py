@@ -513,6 +513,10 @@ class Node:
             self._check_stuck_behind)
         # VC stall decomposition: detection stamp on primary disconnect
         self._vc_phase_ts: dict[str, float] = {}
+        # VALIDATOR_INFO `view_change`: view changes started and
+        # completed, and the last completed episode's phase seconds
+        self._vc_counts = {"started": 0, "completed": 0}
+        self._vc_last: Optional[dict] = None
         self.node_bus.subscribe(
             ExternalBus.Disconnected,
             lambda m, frm="": self._vc_mark("detect")
@@ -1118,13 +1122,25 @@ class Node:
             replica.internal_bus.subscribe(NewViewAccepted,
                                            self._on_master_new_view)
             # VC stall decomposition: stamp the vote and the IC-quorum
-            # start as they pass through the master's bus
+            # start as they pass through the master's bus. The vote's
+            # stamp goes FIRST: the trigger service, subscribed at the
+            # replica's construction, sends NeedViewChange from inside
+            # its handling of the vote that completes the f+1 quorum,
+            # and a stamp taken after it found `start` already there and
+            # was dropped (detect_to_vote and vote_to_start were lost on
+            # exactly the nodes whose vote started the view change)
             replica.internal_bus.subscribe(
                 VoteForViewChange,
-                lambda _m: self._vc_mark("vote"))
+                lambda _m: self._vc_mark("vote"), first=True)
             replica.internal_bus.subscribe(
                 NeedViewChange,
                 lambda _m: self._vc_mark("start"))
+            # the work a view change does, as host spans of a traced node
+            # (vc.revert_batches, vc.build_new_view, vc.check_new_view,
+            # vc.first_cut)
+            replica.ordering.span = _phase
+            if replica.view_changer is not None:
+                replica.view_changer.span = _phase
         return replica
 
     # --- view-change stall decomposition (VERDICT r4 item 5) ------------
@@ -1148,6 +1164,7 @@ class Node:
         exists, earlier stamps freeze; phase metrics are emitted when the
         later endpoint of each pair is stamped."""
         if phase == "start":
+            self._vc_counts["started"] += 1
             self._vc_starts_streak += 1
             self._maybe_vc_storm_resync()
         elif phase in ("new_view", "order"):
@@ -1162,14 +1179,19 @@ class Node:
         if phase == "order":
             # metrics emit ONCE, at completion (refreshed stamps would
             # otherwise emit duplicate, drifting durations)
+            phases = {}
             for frm, to, metric in self._VC_PHASES:
                 if frm in ts and to in ts:
+                    phases[f"{frm}_to_{to}"] = ts[to] - ts[frm]
                     self.metrics.add_event(metric, ts[to] - ts[frm])
             # whole-episode duration (earliest stamp -> first post-VC
             # order), sampled so metrics_report prints churn p50/p95
             first = min(ts[p] for p in self._VC_ORDER if p in ts)
             self.metrics.add_event(MetricsName.VC_DURATION,
                                    ts["order"] - first)
+            self._vc_last = {
+                "view_no": self.master_replica.data.view_no,
+                "duration_s": ts["order"] - first, "phases_s": phases}
             if self.tracer.enabled:
                 self.tracer.anomaly("view_change_recovered",
                                     {"duration_s": ts["order"] - first})
@@ -1264,6 +1286,7 @@ class Node:
             replica.adopt_new_view(msg.view_no, primaries)
         self.monitor.reset()
         self.metrics.add_event(MetricsName.VIEW_CHANGES)
+        self._vc_counts["completed"] += 1
         self._vc_mark("new_view")
         self.notifier.send(TOPIC_VIEW_CHANGE, {
             "node": self.name, "view_no": msg.view_no,
@@ -2503,4 +2526,18 @@ class Node:
             # Prodable.wakes); None without real sockets
             "transport": (self.transport_report()
                           if self.transport_report is not None else None),
+            # view changes started (IC quorums) and completed (NEW_VIEWs
+            # accepted) since the start, the last completed episode's
+            # phase seconds (detect -> vote -> start -> new_view -> first
+            # master order, as the consensus.vc_* events), and what the
+            # master's ordering service saw of the last one: batches
+            # reverted at its start, finalised requests waiting at the
+            # new view's first fresh PRE-PREPARE; while one is in progress,
+            # what it waits on (ViewChangeService.progress)
+            "view_change": dict(
+                self._vc_counts, view_no=master.data.view_no,
+                in_progress=bool(master.data.waiting_for_new_view),
+                last=self._vc_last, ordering=master.ordering.vc_episode,
+                waiting_on=(master.view_changer.progress()
+                            if master.data.waiting_for_new_view else None)),
         }
