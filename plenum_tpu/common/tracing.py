@@ -210,7 +210,10 @@ class Tracer(NullTracer):
         if path is not None:
             tmp = path + ".tmp"
             with open(tmp, "w") as fh:
-                json.dump(snap, fh, default=repr)
+                # encoded in one piece (json.dump would walk the ring in
+                # Python and write it chunk by chunk): an anomaly's dump
+                # runs in line on the node's loop
+                fh.write(json.dumps(snap, default=repr))
             os.replace(tmp, path)
             self.dumps_written += 1
         return snap

@@ -108,10 +108,18 @@ class BlsBftReplica:
         # stage timer + the pairings-per-batch counter the batched-BLS
         # acceptance is judged by
         self.metrics = None
-        # multi-sigs we aggregated (and therefore verified) ourselves: in
-        # steady state the primary embeds exactly this into the next
-        # PRE-PREPARE, so validate_pre_prepare can skip the pairing
-        self._verified_ms_keys: dict[tuple, None] = {}
+        # multi-sigs we aggregated or verified ourselves -> the highest
+        # pp_seq_no we met each at: in steady state the primary embeds
+        # exactly this into the next PRE-PREPARE, so validate_pre_prepare
+        # can skip the pairing; and a view change re-sends every
+        # PRE-PREPARE since the last stable checkpoint with the
+        # multi-sig it carried, so the memory lasts as long as they do (gc)
+        self._verified_ms_keys: dict[tuple, int] = {}
+        # this node's COMMIT signatures since the last stable checkpoint,
+        # by signed value (which holds no view number, so a batch
+        # re-certified in a later view is the same value)
+        # -> (pp_seq_no, signature), dropped by gc with the 3PC log
+        self._own_sigs: dict[bytes, tuple[int, str]] = {}
         # ordered batches whose multi-sig fell short of quorum, retried as
         # late COMMITs arrive; and senders whose sig already failed for a key
         self._pending_order: dict[tuple[int, int], PrePrepare] = {}
@@ -154,12 +162,14 @@ class BlsBftReplica:
             ms = MultiSignature.from_list(list(pre_prepare.bls_multi_sig))
         except (ValueError, TypeError, IndexError, KeyError):
             return self.PPR_BLS_MULTISIG_WRONG
-        return None if self.multi_sig_holds(ms) \
+        return None if self.multi_sig_holds(ms, pre_prepare.pp_seq_no) \
             else self.PPR_BLS_MULTISIG_WRONG
 
-    def multi_sig_holds(self, ms: MultiSignature) -> bool:
+    def multi_sig_holds(self, ms: MultiSignature, seq: int = 0) -> bool:
         """Whether `ms` is a quorum multi-signature over its value, by the
-        keys and the quorum of the pool state it cites."""
+        keys and the quorum of the pool state it cites. `seq`: the
+        pp_seq_no of the PRE-PREPARE that carries it; the verdict is
+        remembered until a checkpoint at or past it is stable."""
         # Participants must be DISTINCT registered validators: aggregation is
         # plain point addition, so one colluding node's signature repeated
         # n-f times would otherwise verify as a quorum multi-sig (rogue
@@ -173,7 +183,9 @@ class BlsBftReplica:
         # whose participant count satisfies the OLD n - f, not the new one —
         # re-judging it with the new quorums would mark every honest primary
         # suspicious and storm view changes on every pool growth.
-        if self._ms_key(ms) in self._verified_ms_keys:
+        ms_key = self._ms_key(ms)
+        if ms_key in self._verified_ms_keys:
+            self._remember_verified(ms_key, seq)
             return True
         # keys AND quorum AS OF the sig's cited pool state — the same
         # epoch resolution process_order aggregates under, so an honest
@@ -194,7 +206,7 @@ class BlsBftReplica:
                                               ms.participants])
         self._drop_stale_points(vk_of)
         if ok:
-            self._remember_verified(ms)
+            self._remember_verified(ms_key, seq)
         return ok
 
     def adopt_multi_sig(self, ms: MultiSignature) -> bool:
@@ -212,11 +224,20 @@ class BlsBftReplica:
 
     # --- COMMIT -----------------------------------------------------------
 
-    def update_commit(self, params: dict, pre_prepare: PrePrepare) -> dict:
-        if self._signer is not None:
-            value = self._signed_value(pre_prepare)
-            params["bls_sig"] = self._signer.sign(value.as_single_value())
-        return params
+    def update_commit(self, params: dict,
+                      pre_prepare: PrePrepare) -> tuple[dict, bool]:
+        """-> (params with this node's signature, whether it is the one
+        kept from an earlier COMMIT over the same value). The signature is
+        sk * H(value): a fresh one would be the same bytes."""
+        if self._signer is None:
+            return params, False
+        value = self._signed_value(pre_prepare).as_single_value()
+        reused = value in self._own_sigs
+        if not reused:
+            self._own_sigs[value] = (pre_prepare.pp_seq_no,
+                                     self._signer.sign(value))
+        params["bls_sig"] = self._own_sigs[value][1]
+        return params, reused
 
     def validate_commit(self, commit: Commit, sender_node: str,
                         pre_prepare: PrePrepare) -> Optional[int]:
@@ -301,7 +322,7 @@ class BlsBftReplica:
         agg = self._verifier.create_multi_sig([good[n] for n in participants])
         ms = MultiSignature(signature=agg, participants=participants,
                             value=self._signed_value(pre_prepare))
-        self._remember_verified(ms)
+        self._remember_verified(self._ms_key(ms), key[1])
         self._recent_multi_sigs[pre_prepare.state_root] = ms
         if len(self._recent_multi_sigs) > 10:
             oldest = next(iter(self._recent_multi_sigs))
@@ -370,12 +391,18 @@ class BlsBftReplica:
         return (ms.signature, tuple(ms.participants),
                 ms.value.as_single_value())
 
-    def _remember_verified(self, ms: MultiSignature) -> None:
-        self._verified_ms_keys[self._ms_key(ms)] = None
-        while len(self._verified_ms_keys) > 50:
-            del self._verified_ms_keys[next(iter(self._verified_ms_keys))]
+    def _remember_verified(self, ms_key: tuple, seq: int) -> None:
+        self._verified_ms_keys[ms_key] = max(
+            seq, self._verified_ms_keys.get(ms_key, 0))
 
     def gc(self, stable_3pc: tuple[int, int]) -> None:
+        seq = stable_3pc[1]
+        # the multi-sig of the checkpoint's own last batch stays: the
+        # PRE-PREPARE after it carries it
+        self._verified_ms_keys = {k: v for k, v in
+                                  self._verified_ms_keys.items() if v >= seq}
+        self._own_sigs = {k: v for k, v in self._own_sigs.items()
+                          if v[0] > seq}
         self._sigs = {k: v for k, v in self._sigs.items() if k > stable_3pc}
         self._pending_order = {k: v for k, v in self._pending_order.items()
                                if k > stable_3pc}

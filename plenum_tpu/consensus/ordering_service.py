@@ -101,6 +101,9 @@ class OrderingService:
         # 3PC logs (all keyed by (view_no, pp_seq_no))
         self.sent_preprepares: dict[tuple[int, int], PrePrepare] = {}
         self.prePrepares: dict[tuple[int, int], PrePrepare] = {}
+        # the highest key ever put there (_hold_preprepare): what
+        # _last_preprepared_seq reads for every PRE-PREPARE that arrives
+        self._top_preprepared: tuple[int, int] = (0, 0)
         self.prepares: dict[tuple[int, int], dict[str, Prepare]] = {}
         self.commits: dict[tuple[int, int], dict[str, Commit]] = {}
         self.ordered: set[tuple[int, int]] = set()
@@ -388,7 +391,7 @@ class OrderingService:
         self._data.last_batch_timestamp = pp_time
         key = (view_no, pp_seq_no)
         self.sent_preprepares[key] = pre_prepare
-        self.prePrepares[key] = pre_prepare
+        self._hold_preprepare(key, pre_prepare)
         self.cuts[reason] += 1
         if self._controller is not None:
             self._controller.note_batch_cut(queue_wait, len(digests))
@@ -477,6 +480,18 @@ class OrderingService:
             h.update(b"\x02" + (root or "").encode())
         return h.hexdigest()
 
+    @classmethod
+    def _content_digest(cls, pp: PrePrepare) -> str:
+        """_batch_digest of a received PRE-PREPARE, computed once a message
+        object (frozen, so kept on it as MessageBase keeps its hash): a
+        stashed one comes back through process_preprepare every time the
+        stash is replayed."""
+        digest = pp.__dict__.get("_content_digest")
+        if digest is None:
+            digest = cls._batch_digest(pp)
+            object.__setattr__(pp, "_content_digest", digest)
+        return digest
+
     # ------------------------------------------------------------------ #
     # admission control                                                  #
     # ------------------------------------------------------------------ #
@@ -525,7 +540,7 @@ class OrderingService:
         # The digest must actually bind the batch content — everything
         # downstream (prepares, commits, message-req recovery) anchors on it.
         # Re-ordered batches keep the digest minted in their original view.
-        if msg.digest != self._batch_digest(msg):
+        if msg.digest != self._content_digest(msg):
             self._suspect(Suspicions.PPR_DIGEST_WRONG, sender)
             return DISCARD
         if key in self.sent_preprepares:
@@ -566,10 +581,18 @@ class OrderingService:
                 return DISCARD
         return self._process_valid_preprepare(msg, sender)
 
+    def _hold_preprepare(self, key: tuple[int, int], pp: PrePrepare) -> None:
+        self.prePrepares[key] = pp
+        if key > self._top_preprepared:
+            self._top_preprepared = key
+
     def _last_preprepared_seq(self) -> int:
-        seqs = [k[1] for k in self.prePrepares if k[0] == self._data.view_no]
+        """The highest pp_seq_no pre-prepared in this view. Keys leave
+        prePrepares from the low end only (gc, a catch-up: at or below
+        the floor), or all at once with the view."""
         floor = max(self._data.low_watermark, self._data.last_ordered_3pc[1])
-        return max(seqs + [floor])
+        view_no, seq = self._top_preprepared
+        return max(seq, floor) if view_no == self._data.view_no else floor
 
     def _process_valid_preprepare(self, msg: PrePrepare, sender: str):
         key = (msg.view_no, msg.pp_seq_no)
@@ -620,7 +643,7 @@ class OrderingService:
         else:
             batch_id = BatchID(msg.view_no, _orig_view(msg),
                                msg.pp_seq_no, msg.digest)
-        self.prePrepares[key] = msg
+        self._hold_preprepare(key, msg)
         if self._metrics is not None:
             self._phase_ts[key] = [self._timer.get_current_time(), None]
         self._stages.pp_recv(msg, sender)
@@ -702,7 +725,11 @@ class OrderingService:
     def _send_commit(self, pp: PrePrepare, key: tuple[int, int]) -> None:
         params = dict(inst_id=pp.inst_id, view_no=key[0], pp_seq_no=key[1])
         if self._bls is not None:
-            params = self._bls.update_commit(params, pp)
+            params, reused = self._bls.update_commit(params, pp)
+            if self.vc_episode is not None and _orig_view(pp) != pp.view_no:
+                # a batch the NEW_VIEW carried over from an earlier view
+                self.vc_episode["bls_sigs_reused" if reused
+                                else "bls_sigs_fresh"] += 1
         commit = Commit(**params)
         self._commits_sent.add(key)
         if self._tracer.enabled:
@@ -1087,6 +1114,8 @@ class OrderingService:
                               and k[0] == self._data.view_no
                               and k not in self.ordered]:
                         del self.prePrepares[k]
+                    self._top_preprepared = max(self.prePrepares,
+                                                default=(0, 0))
                     break
         self._data.is_participating = True
         if self._last_new_view_msg is not None:
@@ -1115,7 +1144,11 @@ class OrderingService:
             self.vc_episode = {"view_no": msg.view_no,
                                "reverted_batches": reverted,
                                "reordered_batches": None,
-                               "waiting_at_first_cut": None}
+                               "waiting_at_first_cut": None,
+                               # of the re-ordered batches: COMMITs sent
+                               # with the signature kept from the earlier
+                               # view / with a new one
+                               "bls_sigs_reused": 0, "bls_sigs_fresh": 0}
             self._first_cut_due = True
         # ALL pre-prepares (ordered ones too) become old-view material: a
         # NewView may cite an already-ordered batch, and both the re-sending
@@ -1125,6 +1158,7 @@ class OrderingService:
             self.old_view_preprepares[(orig, key[1])] = pp
         self.prePrepares = {k: v for k, v in self.prePrepares.items()
                             if k in self.ordered}
+        self._top_preprepared = (0, 0)      # what is left is ordered
         self.sent_preprepares.clear()
         self.prepares.clear()
         self.commits.clear()
@@ -1263,7 +1297,7 @@ class OrderingService:
                             RequestPropagates(bad_requests=missing))
                         break
                 self.sent_preprepares[key] = new_pp
-                self.prePrepares[key] = new_pp
+                self._hold_preprepare(key, new_pp)
                 self._data.pp_seq_no = max(self._data.pp_seq_no, pp_seq_no)
                 if self._data.is_master and self._executor is not None \
                         and not rerun:

@@ -44,7 +44,35 @@ from .primary_selector import RoundRobinPrimariesSelector
 
 
 def view_change_digest(vc: ViewChange) -> str:
-    return hashlib.sha256(json_dumps(vc.to_dict()).encode()).hexdigest()
+    """SHA-256 of the vote's canonical form, computed once a vote: a
+    ViewChange is frozen, so the digest is kept on the message object (as
+    MessageBase keeps its hash) and lives exactly as long as the vote. A
+    vote carries every batch id prepared since the last stable checkpoint,
+    and its digest is asked for on every vote and ack that arrives."""
+    digest = vc.__dict__.get("_digest")
+    if digest is None:
+        digest = hashlib.sha256(json_dumps(vc.to_dict()).encode()).hexdigest()
+        object.__setattr__(vc, "_digest", digest)
+    return digest
+
+
+def _by_seq(raws) -> dict[int, list[BatchID]]:
+    out: dict[int, list[BatchID]] = {}
+    for raw in raws:
+        bid = BatchID.from_seq(raw)
+        out.setdefault(bid.pp_seq_no, []).append(bid)
+    return out
+
+
+class _VoteIndex:
+    """One vote's certificates by pp_seq_no, each parsed once, in the
+    order the vote lists them (the selection's tie-breaks follow it)."""
+    __slots__ = ("stable_checkpoint", "prepared", "preprepared")
+
+    def __init__(self, vc: ViewChange):
+        self.stable_checkpoint = vc.stable_checkpoint
+        self.prepared = _by_seq(vc.prepared)
+        self.preprepared = _by_seq(vc.preprepared)
 
 
 class NewViewBuilder:
@@ -54,6 +82,7 @@ class NewViewBuilder:
         self._data = data
 
     def calc_checkpoint(self, vcs: list[ViewChange]) -> Optional[tuple]:
+        held = [{tuple(c) for c in v.checkpoints} for v in vcs]
         best: Optional[tuple] = None
         for vc in vcs:
             for cp in vc.checkpoints:
@@ -69,7 +98,7 @@ class NewViewBuilder:
                 # change runs on n-f votes, and a voter that lags the
                 # others across a checkpoint boundary, or stabilized a
                 # checkpoint later than they did, holds another set
-                holders = sum(1 for v in vcs if cp in {tuple(c) for c in v.checkpoints})
+                holders = sum(1 for h in held if cp in h)
                 if not self._data.quorums.weak.is_reached(holders):
                     continue
                 if best is None or end > best[2]:
@@ -77,47 +106,46 @@ class NewViewBuilder:
         return best
 
     def calc_batches(self, cp: tuple, vcs: list[ViewChange]) -> Optional[list[BatchID]]:
+        # every vote's lists are parsed here, once; the walk below reads
+        # the indexes, in the votes' order (author-sorted by the caller)
+        votes = [_VoteIndex(vc) for vc in vcs]
         batches: list[BatchID] = []
         pp_seq_no = cp[2] + 1
         while pp_seq_no <= cp[2] + self._data.log_size:
-            bid = self._find_batch(vcs, pp_seq_no)
+            bid = self._find_batch(votes, pp_seq_no)
             if bid is not None:
                 batches.append(bid)
                 pp_seq_no += 1
                 continue
-            if self._null_batch_certified(vcs, pp_seq_no):
+            if self._null_batch_certified(votes, pp_seq_no):
                 break                    # sequential ordering: stop at first gap
             return None                  # quorum not yet available
         return batches
 
-    def _find_batch(self, vcs, pp_seq_no) -> Optional[BatchID]:
+    def _find_batch(self, votes: list[_VoteIndex],
+                    pp_seq_no: int) -> Optional[BatchID]:
         # Among all certified candidates at this seq, pick the highest-view
         # certificate (PBFT selection rule: a batch prepared in a later view
         # supersedes earlier ones), tie-broken fully deterministically so the
         # primary and every validator compute the identical NewView.
         best: Optional[BatchID] = None
-        for vc in vcs:
-            for raw in vc.prepared:
-                bid = BatchID.from_seq(raw)
-                if bid.pp_seq_no != pp_seq_no:
-                    continue
+        for vote in votes:
+            for bid in vote.prepared.get(pp_seq_no, ()):
                 if best is not None and (bid.view_no, bid.pp_view_no,
                                          bid.pp_digest) <= \
                         (best.view_no, best.pp_view_no, best.pp_digest):
                     continue
-                if (self._prepared_certified(bid, vcs)
-                        and self._preprepared_certified(bid, vcs)):
+                if (self._prepared_certified(bid, votes)
+                        and self._preprepared_certified(bid, votes)):
                     best = bid
         return best
 
-    def _prepared_certified(self, bid: BatchID, vcs) -> bool:
-        def not_contradicting(vc: ViewChange) -> bool:
-            if bid.pp_seq_no <= vc.stable_checkpoint:
+    def _prepared_certified(self, bid: BatchID,
+                            votes: list[_VoteIndex]) -> bool:
+        def not_contradicting(vote: _VoteIndex) -> bool:
+            if bid.pp_seq_no <= vote.stable_checkpoint:
                 return False
-            for raw in vc.prepared:
-                other = BatchID.from_seq(raw)
-                if other.pp_seq_no != bid.pp_seq_no:
-                    continue
+            for other in vote.prepared.get(bid.pp_seq_no, ()):
                 # A vote contradicts unless it is from an older view, or the
                 # same view with identical identity.
                 if other.view_no > bid.view_no:
@@ -128,29 +156,24 @@ class NewViewBuilder:
                     return False
             return True
         return self._data.quorums.strong.is_reached(
-            sum(1 for vc in vcs if not_contradicting(vc)))
+            sum(1 for vote in votes if not_contradicting(vote)))
 
-    def _preprepared_certified(self, bid: BatchID, vcs) -> bool:
-        def witnessed(vc: ViewChange) -> bool:
-            for raw in vc.preprepared:
-                other = BatchID.from_seq(raw)
-                if (other.pp_seq_no == bid.pp_seq_no
-                        and other.pp_view_no == bid.pp_view_no
-                        and other.pp_digest == bid.pp_digest
-                        and other.view_no >= bid.view_no):
-                    return True
-            return False
+    def _preprepared_certified(self, bid: BatchID,
+                               votes: list[_VoteIndex]) -> bool:
+        def witnessed(vote: _VoteIndex) -> bool:
+            return any(other.pp_view_no == bid.pp_view_no
+                       and other.pp_digest == bid.pp_digest
+                       and other.view_no >= bid.view_no
+                       for other in vote.preprepared.get(bid.pp_seq_no, ()))
         return self._data.quorums.weak.is_reached(
-            sum(1 for vc in vcs if witnessed(vc)))
+            sum(1 for vote in votes if witnessed(vote)))
 
-    def _null_batch_certified(self, vcs, pp_seq_no) -> bool:
-        def has_no_prepare(vc: ViewChange) -> bool:
-            if pp_seq_no <= vc.stable_checkpoint:
-                return False
-            return all(BatchID.from_seq(raw).pp_seq_no != pp_seq_no
-                       for raw in vc.prepared)
+    def _null_batch_certified(self, votes: list[_VoteIndex],
+                              pp_seq_no: int) -> bool:
         return self._data.quorums.strong.is_reached(
-            sum(1 for vc in vcs if has_no_prepare(vc)))
+            sum(1 for vote in votes
+                if pp_seq_no > vote.stable_checkpoint
+                and pp_seq_no not in vote.prepared))
 
 
 class ViewChangeService:

@@ -31,6 +31,25 @@ BATCH = 20
 DEPTH = 3           # uncommitted batches the primary keeps in flight
 
 
+@pytest.fixture(scope="module", autouse=True)
+def highest_pre_prepared_is_what_the_log_holds():
+    """Every scenario of this file, at every PRE-PREPARE that arrives: the
+    sequence number OrderingService keeps is the one a scan of its log
+    gives (the scan is what it used to do)."""
+    from plenum_tpu.consensus.ordering_service import OrderingService
+    kept = OrderingService._last_preprepared_seq
+
+    def checked(self):
+        seqs = [k[1] for k in self.prePrepares if k[0] == self._data.view_no]
+        floor = max(self._data.low_watermark, self._data.last_ordered_3pc[1])
+        got = kept(self)
+        assert got == max(seqs + [floor]), (self._data.name, got, seqs, floor)
+        return got
+    OrderingService._last_preprepared_seq = checked
+    yield
+    OrderingService._last_preprepared_seq = kept
+
+
 def _user(i: int) -> Ed25519Signer:
     return Ed25519Signer(seed=(b"failover-%d" % i).ljust(32, b"\0"))
 
@@ -348,3 +367,292 @@ def test_every_node_names_the_same_checkpoint():
     assert roots[("Delta", 10)] == roots[("Alpha", 10)] != ""
     assert {pool.nodes[n].master_replica.data.stable_checkpoint
             for n in pool.names} == {10}
+
+
+# --- what re-certifying a batch costs a node that had ordered it ------------
+
+
+def _spy(obj, name: str, seen: list, key=lambda *a: a):
+    """Record every call of obj.<name> (an instance attribute over the
+    method) and pass it through."""
+    real = getattr(obj, name)
+
+    def through(*args):
+        seen.append(key(*args))
+        return real(*args)
+    setattr(obj, name, through)
+
+
+@pytest.fixture(scope="module")
+def recertified(tmp_path_factory):
+    """Six one-request batches in view 0, the last two ordered by three of
+    the four (Delta prepared them and sent its COMMITs, and the COMMITs to
+    it were lost); the primary stops; the view change re-certifies all
+    six; then the new view runs past a checkpoint. -> what was seen."""
+    from plenum_tpu.common.node_messages import PrePrepare
+    pool = Pool(tracing=False, config=Config(
+        Max3PCBatchWait=0.05, Max3PCBatchSize=1, CHK_FREQ=10, LOG_SIZE=30,
+        kv_backend="native", PRIMARY_DISCONNECT_TIMEOUT=1.5,
+        ORDERING_PROGRESS_TIMEOUT=300.0,
+        STATE_FRESHNESS_UPDATE_INTERVAL=300.0),
+        data_dir=str(tmp_path_factory.mktemp("recertified")))
+    survivors = ["Beta", "Gamma", "Delta"]
+    wire: list = []
+    pool.net.add_rule(Discard(), lambda m, frm, dst: isinstance(
+        m, (Commit, PrePrepare)) and wire.append((m, frm)) and False)
+
+    def write(rid, to=None):
+        pool.submit(signed_nym(pool.trustee, _user(7000 + rid), rid), to=to)
+    for rid in range(1, 5):
+        write(rid)
+        pool.run(0.5)
+    pool.run(2.0)
+    lost = pool.net.add_rule(Discard(), lambda m, frm, dst:
+                             isinstance(m, Commit) and dst == "Delta")
+    for rid in (5, 6):
+        write(rid)
+        pool.run(0.5)
+    pool.run(2.0)
+    at_stop = {n: pool.nodes[n].master_replica.last_ordered_3pc
+               for n in pool.names}
+    view0_sigs = {(frm, m.pp_seq_no): m.bls_sig for m, frm in wire
+                  if isinstance(m, Commit) and m.inst_id == 0}
+
+    signed = {n: [] for n in survivors}
+    stored = {n: [] for n in survivors}
+    aggregated = {n: [] for n in survivors}
+    for n in survivors:
+        node = pool.nodes[n]
+        _spy(node.c.bls_signer, "sign", signed[n])
+        _spy(node.c.bls_store, "put", stored[n],
+             key=lambda ms: ms.value.state_root_hash)
+        _spy(node.master_replica.bls, "process_order", aggregated[n],
+             key=lambda key, pp: key)
+    roots = {pp.pp_seq_no: pp.state_root for pp in pool.nodes[
+        "Beta"].master_replica.ordering.prePrepares.values()}
+
+    pool.crash_node("Alpha")
+    pool.net.remove_rule(lost)
+    del wire[:]
+    pool.run(8.0)
+    seen = {
+        "at_stop": at_stop, "view0_sigs": view0_sigs, "roots": roots,
+        "view1_commits": [(m, frm) for m, frm in wire
+                          if isinstance(m, Commit) and m.inst_id == 0
+                          and m.view_no == 1],
+        "signed": {n: list(v) for n, v in signed.items()},
+        "stored": {n: list(v) for n, v in stored.items()},
+        "aggregated": {n: list(v) for n, v in aggregated.items()},
+        "episode": {n: dict(pool.nodes[n].validator_info()
+                            ["view_change"]["ordering"]) for n in survivors},
+        "sizes": {n: _domain(pool.nodes[n]).size for n in survivors}}
+
+    # the new view's first fresh batch, then on past checkpoint 10
+    del wire[:]
+    write(7, to=survivors)
+    pool.run(3.0)
+    seen["fresh"] = [m for m, frm in wire if isinstance(m, PrePrepare)
+                     and m.inst_id == 0 and m.view_no == 1
+                     and m.pp_seq_no == 7]
+    seen["kept_before_gc"] = {
+        n: len(pool.nodes[n].master_replica.bls._own_sigs)
+        for n in survivors}
+    # on past checkpoints 10, 20 and 30, two batches beyond each
+    seen["kept_after_gc"] = {n: [] for n in survivors}
+    for first, last in ((8, 12), (13, 22), (23, 32)):
+        for rid in range(first, last + 1):
+            write(rid, to=survivors)
+            pool.run(0.5)
+        pool.run(3.0)
+        for n in survivors:
+            bls = pool.nodes[n].master_replica.bls
+            seen["kept_after_gc"][n].append((
+                pool.nodes[n].master_replica.data.stable_checkpoint,
+                sorted(seq for seq, _sig in bls._own_sigs.values()),
+                sorted(bls._verified_ms_keys.values())))
+    return pool, survivors, seen
+
+
+def test_a_node_that_ordered_a_batch_re_certifies_it_without_bls_work(
+        recertified):
+    pool, survivors, seen = recertified
+    assert seen["at_stop"] == {"Alpha": (0, 6), "Beta": (0, 6),
+                               "Gamma": (0, 6), "Delta": (0, 4)}
+    # all six ride into view 1, on every survivor
+    assert {e["reordered_batches"] for e in seen["episode"].values()} == {6}
+    # no signature was made: each COMMIT of view 1 carries the bytes its
+    # sender's COMMIT carried in view 0 (Delta had sent its own for the
+    # two batches it never got to order)
+    assert seen["signed"] == {n: [] for n in survivors}
+    commits = seen["view1_commits"]
+    assert {(frm, m.pp_seq_no) for m, frm in commits} \
+        == {(n, s) for n in survivors for s in range(1, 7)}
+    for m, frm in commits:
+        assert m.bls_sig == seen["view0_sigs"][(frm, m.pp_seq_no)] \
+            is not None
+    # and the two that had ordered all six put nothing into their BLS store
+    assert seen["stored"]["Beta"] == seen["stored"]["Gamma"] == []
+
+
+def test_a_node_that_had_not_ordered_a_cited_batch_takes_the_whole_path(
+        recertified):
+    """Delta applies batches 5 and 6, checks the COMMITs' signatures at the
+    quorum and stores its aggregate over each (`process_order`)."""
+    pool, survivors, seen = recertified
+    assert seen["sizes"] == {n: 7 for n in survivors}
+    assert seen["stored"]["Delta"] == [seen["roots"][5], seen["roots"][6]]
+    store = pool.nodes["Delta"].c.bls_store
+    for seq in (5, 6):
+        ms = store.get(seen["roots"][seq])
+        assert ms is not None and len(ms.participants) >= 3
+        assert pool.nodes["Beta"].master_replica.bls.multi_sig_holds(ms)
+
+
+def test_the_episode_counts_the_bls_work_of_the_re_certified_batches(
+        recertified):
+    pool, survivors, seen = recertified
+    for n in survivors:
+        e = seen["episode"][n]
+        assert e["bls_sigs_reused"] + e["bls_sigs_fresh"] \
+            == e["reordered_batches"] == 6, (n, e)
+        assert (e["bls_sigs_reused"], e["bls_sigs_fresh"]) == (6, 0)
+
+
+def test_the_first_fresh_pre_prepare_carries_the_multi_sig_before_it(
+        recertified):
+    """The batch before it was ordered in view 0 and only re-certified in
+    view 1: the view-0 aggregate over its state root is the signature."""
+    from plenum_tpu.crypto.multi_signature import MultiSignature
+    pool, survivors, seen = recertified
+    assert len({id(pp) for pp in seen["fresh"]}) == 1
+    pp = seen["fresh"][0]
+    assert pp.original_view_no in (None, 1)
+    ms = MultiSignature.from_list(list(pp.bls_multi_sig))
+    assert ms.value.state_root_hash == seen["roots"][6]
+    for n in ("Gamma", "Delta"):
+        assert pool.nodes[n].master_replica.bls.validate_pre_prepare(
+            pp, "Beta") is None
+    assert {_domain(pool.nodes[n]).size for n in survivors} == {1 + 32}
+
+
+def test_what_was_kept_goes_with_the_stable_checkpoint(recertified):
+    """Read two batches past checkpoints 10, 20 and 30: what a node keeps
+    of its BLS work is what lies past its stable checkpoint, the same
+    size each time, so it does not grow with the ledger."""
+    pool, survivors, seen = recertified
+    for n in survivors:
+        assert seen["kept_before_gc"][n] == 7, n
+        readings = seen["kept_after_gc"][n]
+        assert [r[0] for r in readings] == [10, 20, 30], n
+        for stable, own, verified in readings:
+            assert own == [stable + 1, stable + 2], (n, stable, own)
+            # the multi-sig of the checkpoint's last batch stays: the
+            # PRE-PREPARE after it carried it
+            assert verified and verified[0] >= stable, (n, verified)
+            # two a batch past the checkpoint (the one aggregated, the
+            # one the next PRE-PREPARE carried) and the checkpoint's own
+            assert len(verified) <= 2 * 2 + 1, (n, verified)
+        bls = pool.nodes[n].master_replica.bls
+        assert all(key > (1, 30) for key in bls._sigs), (n, list(bls._sigs))
+
+
+def test_a_node_that_holds_no_signature_signs_afresh():
+    """What a node keeps of its BLS work lives in memory and goes with a
+    restart. Gamma's is emptied as a restart leaves it (a node really
+    started again also lacks the bodies of the requests it committed in
+    its first life, stashes the re-sent PRE-PREPAREs as MISSING_REQUESTS
+    and casts no vote on them at all): it re-certifies with signatures it
+    makes anew, the same bytes (sk * H(value)) it had sent in view 0."""
+    pool = Pool(tracing=False, config=Config(
+        Max3PCBatchWait=0.05, Max3PCBatchSize=1,
+        PRIMARY_DISCONNECT_TIMEOUT=1.5, ORDERING_PROGRESS_TIMEOUT=300.0,
+        STATE_FRESHNESS_UPDATE_INTERVAL=300.0))
+    survivors = ["Beta", "Gamma", "Delta"]
+    wire: list = []
+    pool.net.add_rule(Discard(), lambda m, frm, dst: isinstance(m, Commit)
+                      and m.inst_id == 0 and frm == "Gamma"
+                      and wire.append(m) and False)
+    for rid in range(1, 4):
+        pool.submit(signed_nym(pool.trustee, _user(8000 + rid), rid))
+        pool.run(0.5)
+    pool.run(2.0)
+    view0 = {m.pp_seq_no: m.bls_sig for m in wire}
+    assert sorted(view0) == [1, 2, 3]
+
+    gamma = pool.nodes["Gamma"]
+    assert len(gamma.master_replica.bls._own_sigs) == 3
+    gamma.master_replica.bls._own_sigs.clear()
+    signed: list = []
+    _spy(gamma.c.bls_signer, "sign", signed)
+    pool.crash_node("Alpha")
+    del wire[:]
+    pool.run(8.0)
+    episodes = {n: pool.nodes[n].validator_info()["view_change"]["ordering"]
+                for n in survivors}
+    assert {e["reordered_batches"] for e in episodes.values()} == {3}
+    assert [(episodes[n]["bls_sigs_reused"], episodes[n]["bls_sigs_fresh"])
+            for n in survivors] == [(3, 0), (0, 3), (3, 0)]
+    assert len(signed) == 3
+    assert {m.pp_seq_no: m.bls_sig for m in wire if m.view_no == 1} == view0
+    pool.submit(signed_nym(pool.trustee, _user(8004), 4), to=survivors)
+    pool.run(4.0)
+    assert {_domain(pool.nodes[n]).size for n in survivors} == {5}
+
+
+def test_a_re_certified_batch_reaches_process_order_only_where_it_is_new(
+        recertified):
+    """A batch at or below a survivor's `last_ordered` has its new-view
+    quorum parked by the in-order rule and never comes to `_order` again:
+    no aggregate, no store `put`, nothing to skip. Only Delta, which had
+    not ordered batches 5 and 6, runs `process_order`, for those two."""
+    pool, survivors, seen = recertified
+    assert seen["aggregated"] == {"Beta": [], "Gamma": [],
+                                  "Delta": [(1, 5), (1, 6)]}
+
+
+def test_a_pre_prepares_content_is_digested_once_an_object(monkeypatch):
+    import dataclasses
+    from plenum_tpu.consensus.ordering_service import OrderingService
+    pool = Pool(tracing=False)
+    pool.submit(signed_nym(pool.trustee, _user(9100), 1))
+    pool.run(2.0)
+    pp = pool.nodes["Beta"].master_replica.ordering.prePrepares[(0, 1)]
+    fresh = dataclasses.replace(pp)                 # as off the wire
+    digested: list = []
+    real = OrderingService._batch_digest
+    monkeypatch.setattr(OrderingService, "_batch_digest", staticmethod(
+        lambda m: digested.append(1) or real(m)))
+    assert [OrderingService._content_digest(fresh) for _ in range(4)] \
+        == [pp.digest] * 4
+    assert len(digested) == 1
+    # another object, another check: a changed batch does not ride on the
+    # digest of the one it was made from
+    forged = dataclasses.replace(fresh, req_idr=fresh.req_idr + ("ff" * 32,))
+    assert OrderingService._content_digest(forged) != forged.digest
+    assert len(digested) == 2
+
+
+def test_a_view_changes_start_is_on_disk_when_the_view_change_starts(
+        tmp_path):
+    """The flight recorder's dump of `view_change_start` is written in
+    line, before anything that follows can lose it, and ends with the
+    anomaly that took it."""
+    import json
+    pool = Pool(config=Config(Max3PCBatchWait=0.05,
+                              PRIMARY_DISCONNECT_TIMEOUT=1.5,
+                              ORDERING_PROGRESS_TIMEOUT=300.0,
+                              STATE_FRESHNESS_UPDATE_INTERVAL=300.0))
+    node = pool.nodes["Gamma"]
+    node.tracer.dump_dir = str(tmp_path)
+    pool.submit(signed_nym(pool.trustee, _user(9200), 1))
+    pool.run(3.0)
+    pool.crash_node("Alpha")
+    for _ in range(40):
+        pool.run(0.1)
+        if node.validator_info()["view_change"]["started"]:
+            break
+    assert node.validator_info()["view_change"]["started"] == 1
+    dumps = list(tmp_path.glob("Gamma-flight-*.json"))
+    assert len(dumps) == 1
+    events = json.loads(dumps[0].read_text())["events"]
+    assert [e[1] for e in events].count("anomaly.view_change_start") == 1

@@ -663,3 +663,23 @@ def test_probe_names_idle_gaps_by_the_host_spans_over_them(monkeypatch):
     assert [round(g[1] * 1e3, 3) for g in reduced["idle_gaps"]] == [
         50.0, 30.0, 10.0]
     assert reduced["busy_s"] == 0.005       # the host spans moved nothing
+
+
+def test_a_dump_is_the_snapshot_as_json_dump_would_write_it(tmp_path):
+    """The dump is encoded in one piece and written once; the artifact is
+    byte for byte what `json.dump(snapshot, fh, default=repr)` gave, data
+    that JSON does not know included, and no `.tmp` is left beside it."""
+    import io
+    clock = {"t": 1.5}
+    tr = Tracer("N1", lambda: clock["t"], ring_size=8,
+                dump_dir=str(tmp_path), min_dump_interval=5.0)
+    tr.emit("stage", "k0", {"n": 3, "dur": 0.25, "who": ("a", "b")})
+    tr.emit("stage", "k1", {"odd": {1, 2} - {2}, "raw": b"\x00\xff"})
+    tr.anomaly("view_change_start", None)       # written before it returns
+    dumps = sorted(tmp_path.iterdir())
+    assert [d.name for d in dumps] == ["N1-flight-0.json"]
+    plain = io.StringIO()
+    json.dump(tr.snapshot(), plain, default=repr)
+    assert dumps[0].read_text() == plain.getvalue()
+    assert json.loads(dumps[0].read_text())["events"][-1][1] \
+        == "anomaly.view_change_start"
