@@ -58,6 +58,8 @@ class ConsProofService:
             base=retry_timeout, cap=self._retry_max,
             jitter=0.3, salt=f"cons_proof/{salt}/{ledger_id}")
         self._sent_at: Optional[float] = None
+        # the retry as armed: its delay, and the RTT base it was drawn on
+        self._armed_delay = self._armed_base = retry_timeout
         self.rounds = 0          # status broadcasts this catchup round
         self._retry_armed = False
         self._same_status: set[str] = set()
@@ -114,23 +116,45 @@ class ConsProofService:
     def _note_reply(self) -> None:
         """First answer to the outstanding broadcast: fold its round trip
         into the shared RTT estimate (later answers to the same broadcast
-        measure peer spread, not the link — skip them)."""
-        if self._sent_at is not None and self._timer is not None:
-            self._rtt.note(self._timer.get_current_time() - self._sent_at)
-            self._sent_at = None
+        measure peer spread, not the link — skip them). A retry armed on
+        a longer estimate than the link now shows is brought forward: the
+        status left before any round trip had been measured (a process
+        just started: the 5 s fallback), and peers that answer in a
+        millisecond but name no common target — a pool that orders moves
+        between two answers — are asked again at the link's pace, from
+        when the status left."""
+        if self._sent_at is None or self._timer is None:
+            return
+        now, sent_at = self._timer.get_current_time(), self._sent_at
+        self._rtt.note(now - sent_at)
+        self._sent_at = None
+        if not (self._adaptive and self._retry_armed and self._running):
+            return
+        base = self._rtt_base()
+        if base < self._armed_base:
+            delay = self._armed_delay * base / self._armed_base
+            self._cancel_retry()
+            self._timer.schedule(max(0.0, sent_at + delay - now),
+                                 self._on_retry)
+            self._retry_armed = True
+            self._armed_base, self._armed_delay = base, delay
+
+    def _rtt_base(self) -> float:
+        return self._rtt.timeout(floor=self._retry_min, cap=self._retry_max,
+                                 fallback=self._retry_timeout)
 
     def _retry_delay(self) -> float:
         if not self._adaptive:
             return self._retry_timeout
-        return self._backoff.next(base=self._rtt.timeout(
-            floor=self._retry_min, cap=self._retry_max,
-            fallback=self._retry_timeout))
+        self._armed_base = self._rtt_base()
+        return self._backoff.next(base=self._armed_base)
 
     def _arm_retry(self) -> None:
         if self._timer is None:
             return
         self._cancel_retry()
-        self._timer.schedule(self._retry_delay(), self._on_retry)
+        self._armed_delay = self._retry_delay()
+        self._timer.schedule(self._armed_delay, self._on_retry)
         self._retry_armed = True
 
     def _cancel_retry(self) -> None:

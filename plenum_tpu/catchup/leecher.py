@@ -15,10 +15,13 @@ from plenum_tpu.common.node_messages import (AUDIT_LEDGER_ID, CatchupRep,
                                              ConsistencyProof, CONFIG_LEDGER_ID,
                                              DOMAIN_LEDGER_ID, LedgerStatus,
                                              POOL_LEDGER_ID)
+from plenum_tpu.common import tracing
 from plenum_tpu.common.backoff import RttEstimator
 from plenum_tpu.common.quorums import Quorums
 from plenum_tpu.common.timer import TimerService
+from plenum_tpu.execution import txn as txn_lib
 from plenum_tpu.execution.database_manager import DatabaseManager
+from plenum_tpu.execution.handlers import audit as audit_lib
 
 from .cons_proof import ConsProofService
 from .rep import CatchupRepService
@@ -51,11 +54,24 @@ class LedgerLeecherService:
             ledger_id, db, send, timer, peers_provider, on_txn_added,
             self._on_rep_complete, config=config, rtt=rtt, salt=salt)
         self.is_active = False
+        # the size this ledger is being (was last) synced to; None when
+        # the last round found it current
+        self.target_size: Optional[int] = None
 
     def start(self, rejoin: bool = False) -> None:
         self.is_active = True
         self._last_3pc = None
+        self.target_size = None
         self.cons_proof.start(rejoin)
+
+    def start_till(self, size: int, root_hex: str) -> None:
+        """Sync to a target the audit ledger names (NodeLeecherService.
+        _audit_cut): no round of its own. The replies are verified against
+        that root as against an agreed one."""
+        self.is_active = True
+        self._last_3pc = None
+        self.target_size = size
+        self.rep.start(size, root_hex)
 
     def stop(self) -> None:
         self.is_active = False
@@ -69,6 +85,7 @@ class LedgerLeecherService:
             return
         size, root_hex, last_3pc = target
         self._last_3pc = last_3pc
+        self.target_size = size
         self.rep.start(size, root_hex)
 
     def _on_rep_complete(self, ledger_id: int) -> None:
@@ -105,6 +122,7 @@ class NodeLeecherService:
                                       if lid == AUDIT_LEDGER_ID else None)
             for lid in CATCHUP_ORDER if db.get_ledger(lid) is not None}
         self.is_running = False
+        self.span = tracing.unspanned       # the node hands `_phase` in
         self._rejoin = False
         self._order: list[int] = [lid for lid in CATCHUP_ORDER
                                   if lid in self.leechers]
@@ -180,7 +198,34 @@ class NodeLeecherService:
             self.is_running = False
             self._on_catchup_complete(self._last_3pc)
             return
-        self.leechers[self._order[self._idx]].start(self._rejoin)
+        lid = self._order[self._idx]
+        cut = self._audit_cut(lid)
+        if cut is None:
+            self.leechers[lid].start(self._rejoin)
+        else:
+            self.leechers[lid].start_till(*cut)
+
+    def _audit_cut(self, ledger_id: int) -> Optional[tuple[int, str]]:
+        """Where the audit ledger, synced first, says `ledger_id` stood at
+        its last batch -> (size, root hex), as upstream's
+        _calc_catchup_till. The pool keeps ordering while a node catches
+        up: a target agreed for each ledger in its own round, one after
+        the other, leaves the later ledgers past the audit ledger's last
+        batch, and the node then derives other roots than the pool for the
+        very next PRE-PREPARE. None for the audit ledger itself and while
+        it records no batch: that ledger is agreed by its own round."""
+        if ledger_id == AUDIT_LEDGER_ID:
+            return None
+        audit = self._db.get_ledger(AUDIT_LEDGER_ID)
+        if audit is None or audit.size == 0:
+            return None
+        last = audit_lib.last_audit_txn(audit)
+        size = txn_lib.txn_data(last).get("ledgerSize", {}).get(
+            str(ledger_id))
+        root = audit_lib.resolve_ledger_root(audit, last, ledger_id)
+        if size is None or root is None:
+            return None
+        return size, root
 
     def _ledger_done(self, ledger_id: int,
                      last_3pc: Optional[tuple[int, int]]) -> None:
@@ -207,4 +252,7 @@ class NodeLeecherService:
     def process_catchup_rep(self, msg: CatchupRep, frm: str) -> None:
         leecher = self.leechers.get(msg.ledger_id)
         if leecher is not None:
-            leecher.rep.process_catchup_rep(msg, frm)
+            # verifying and applying a reply is the catch-up's work on
+            # this node's loop: a host span while a trace is held
+            self.span("rejoin.catchup",
+                      lambda: leecher.rep.process_catchup_rep(msg, frm))

@@ -236,6 +236,14 @@ class MetricsName:
     CATCHUP_PROVIDER_SWITCHES = "catchup.provider_switches"
     CATCHUP_WATCHDOG_KICKS = "catchup.watchdog_kicks"
     CATCHUP_DEGRADED = "catchup.degraded"
+    # what this node served to peers that catch up (catchup/seeder.py):
+    # one event a CatchupReq, the txns and the log's bytes each answer
+    # carried (fold sum = total), and the seconds of every answer, to a
+    # CatchupReq or a LedgerStatus, on the loop that orders (sampled)
+    SEEDER_REQS = "seeder.reqs"
+    SEEDER_TXNS_SERVED = "seeder.txns_served"
+    SEEDER_BYTES_SERVED = "seeder.bytes_served"
+    SEEDER_SERVE_TIME = "seeder.serve_time"
     # membership churn: pool-registry changes observed at commit, the
     # validator-count gauge, and BLS key rotations detected (old key
     # evicted from the crypto planes' key tables)
@@ -443,7 +451,7 @@ SAMPLED_NAMES = frozenset({
     MetricsName.INGRESS_QUEUE_WAIT, MetricsName.INGRESS_QUEUE_DEPTH,
     MetricsName.INGRESS_AUTH_BATCH,
     MetricsName.VC_DURATION, MetricsName.CATCHUP_DURATION,
-    MetricsName.CATCHUP_ROUNDS,
+    MetricsName.CATCHUP_ROUNDS, MetricsName.SEEDER_SERVE_TIME,
 })
 SAMPLE_CAP = 256
 

@@ -113,6 +113,8 @@ class OrderingService:
         self._ordered_originals: dict[tuple[int, int], str] = {}
         self._commits_sent: set[tuple[int, int]] = set()
         self._stashed_ooo_commits: dict[tuple[int, int], PrePrepare] = {}
+        # the stash's counts at the last caught_up_till_3pc
+        self.catchup_stash: Optional[dict] = None
         # Old-view pre-prepares kept for re-ordering after a view change,
         # keyed by (original view, pp_seq_no).
         self.old_view_preprepares: dict[tuple[int, int], PrePrepare] = {}
@@ -534,8 +536,14 @@ class OrderingService:
             self._suspect(Suspicions.PPR_FRM_NON_PRIMARY, sender)
             return DISCARD
         key = (msg.view_no, msg.pp_seq_no)
-        if key in self.prePrepares and self.prePrepares[key].digest != msg.digest:
-            self._suspect(Suspicions.DUPLICATE_PPR_SENT, sender)
+        held = self.prePrepares.get(key)
+        if held is not None:
+            if held.digest != msg.digest:
+                self._suspect(Suspicions.DUPLICATE_PPR_SENT, sender)
+            # the same batch a second time (the primary's broadcast and a
+            # copy asked for, both stashed through a catch-up): applying
+            # it again would stack it on its own effects and blame the
+            # primary for the roots that gives
             return DISCARD
         # The digest must actually bind the batch content — everything
         # downstream (prepares, commits, message-req recovery) anchors on it.
@@ -873,6 +881,27 @@ class OrderingService:
                 best = k[1] if best is None else max(best, k[1])
         return best
 
+    def gap_behind(self) -> Optional[int]:
+        """behind_evidence() where this replica holds no PRE-PREPARE for
+        the batch right after its last ordered one, neither processed nor
+        stashed: the pool committed past a batch whose 3PC messages left
+        before this replica listened (it was down, and the batch in flight
+        when the catch-up target was agreed). No message it holds or will
+        be sent orders that batch; only a further catch-up round brings
+        it. None otherwise."""
+        evidence = self.behind_evidence()
+        if evidence is None:
+            return None
+        nxt = self._data.last_ordered_3pc[1] + 1
+        if any(k[1] == nxt for k in self.prePrepares):
+            return None
+        for queue in self._stasher._queues.values():
+            for message, _args, _handler in queue:
+                if isinstance(message, PrePrepare) \
+                        and message.pp_seq_no == nxt:
+                    return None
+        return evidence
+
     def _stage_batch(self, pp: PrePrepare) -> bool:
         """Re-stage an in-flight batch's uncommitted apply (the catchup
         re-apply twin of _process_valid_preprepare's admission apply):
@@ -1124,7 +1153,21 @@ class OrderingService:
             # the caught-up state before releasing the stashed traffic
             self.process_new_view_checkpoints_applied(
                 self._last_new_view_msg)
-        self._stasher.process_all_stashed(StashReason.CATCHING_UP)
+        # what the catch-up left in the stash, for whoever accounts for a
+        # rejoin: messages held, those at or below the position reached
+        # (history: dropped on replay), and what the replay stashed again
+        # for request bodies this node never saw propagated
+        held = self._stasher._queues.get(StashReason.CATCHING_UP, ())
+        waiting = self._stasher.stash_size(StashReason.MISSING_REQUESTS)
+        self.catchup_stash = {
+            "held": len(held),
+            "below_last_ordered": sum(
+                1 for message, _a, _h in held
+                if getattr(message, "pp_seq_no", pos + 1) <= pos)}
+        self.catchup_stash["replayed"] = \
+            self._stasher.process_all_stashed(StashReason.CATCHING_UP)
+        self.catchup_stash["restashed_missing_requests"] = \
+            self._stasher.stash_size(StashReason.MISSING_REQUESTS) - waiting
         self._stasher.process_all_stashed(StashReason.OUTSIDE_WATERMARKS)
         # a catchup can JUMP views (audit adoption): messages stashed as
         # future-view are now current-view material — without this drain a

@@ -239,9 +239,13 @@ class Ledger:
         return self.tree.root_hash
 
     def get_by_seq_no(self, seq_no: int) -> dict:
+        return unpack(self.get_packed(seq_no))
+
+    def get_packed(self, seq_no: int) -> bytes:
+        """The transaction as the log holds it."""
         if not (1 <= seq_no <= self.seq_no):
             raise KeyError(seq_no)
-        return unpack(self._log.get(seq_no))
+        return self._log.get(seq_no)
 
     def get_all_txns(self, start: int = 1, end: Optional[int] = None):
         end = self.seq_no if end is None else min(end, self.seq_no)

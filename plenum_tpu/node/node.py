@@ -372,7 +372,9 @@ class Node:
         # (ref ledger_manager.py:21 + server/catchup/*)
         self.seeder = SeederService(
             components.db, send=self.node_bus.send,
-            last_3pc=lambda: self.master_replica.last_ordered_3pc)
+            last_3pc=lambda: self.master_replica.last_ordered_3pc,
+            metrics=self.metrics)
+        self.seeder.span = _phase
         self.leecher = NodeLeecherService(
             components.db, send=self.node_bus.send, timer=timer,
             quorums_provider=lambda: self.quorums,
@@ -382,12 +384,20 @@ class Node:
             on_catchup_complete=self._on_catchup_complete,
             config=self.config, salt=name, rtt=self.catchup_rtt,
             on_unbacked=self._on_unbacked_tail)
+        self.leecher.span = _phase
         # a start from durable stores (bootstrap's record of it; None on
         # memory stores): `rejoining` from rejoin_after_restart() until
         # the first catch-up has brought this node to the pool
         self.recovery = components.recovery
         self.rejoining = False
         self._caught_up_txns: dict[int, int] = {}
+        self._caught_up_at_last_round = 0
+        # the rejoin's account of itself (VALIDATOR_INFO `rejoin`): its
+        # phases as seconds since the process started, on the timer's
+        # clock, and every catch-up round up to the first batch this node
+        # orders by its own COMMIT quorum. None unless
+        # rejoin_after_restart() was called
+        self.rejoin: Optional[dict] = None
         # durable stores keep a record of their own traffic (storage/
         # kv_native.py, kv_file.py); none on memory stores
         self._durable_kvs = [kv for kv in components.db.iter_kv_stores()
@@ -1439,6 +1449,7 @@ class Node:
                 and self.network_watcher.has_weak_connectivity()):
             self._needs_resync = False
             self.spylog.append(("resync_after_partition", None))
+            self._rejoin_mark("peers_reachable")
             self.start_catchup()
 
     def _maybe_vc_storm_resync(self) -> None:
@@ -1501,7 +1512,7 @@ class Node:
             self._catchup_kicks = 0
             self.leecher.stop()
             # fresh targets, fresh providers
-            self.leecher.start(rejoin=self.rejoining)
+            self.leecher.start(rejoin=self._tail_unproven())
         else:
             self.leecher.kick()
 
@@ -1568,17 +1579,45 @@ class Node:
             self.tracer.anomaly("catchup", None)
         for replica in self.replicas:
             replica.ordering.catchup_started()
-        self.leecher.start(rejoin=self.rejoining)
+        self._rejoin_mark("catchup_started")
+        self.leecher.start(rejoin=self._tail_unproven())
 
-    def rejoin_after_restart(self) -> None:
+    def _tail_unproven(self) -> bool:
+        """Whether a catch-up round starts as a rejoin's first (ConsProof
+        Service.start): nothing on this node's disk has been held against
+        the pool yet. A rejoin's later rounds start from ledgers that a
+        round has verified."""
+        return self.rejoining and not self.rejoin["rounds"]
+
+    def rejoin_after_restart(self,
+                             process_started_at: Optional[float] = None
+                             ) -> None:
         """The process entry's call after a start that found ledgers on
         disk: catch up before ordering, as soon as f+1 peers are reachable
         (upstream's Node.start does the same). The validators of a pool
         that crashed stop at different batches, and a node that takes its
-        own disk for the pool's would order from the wrong place."""
+        own disk for the pool's would order from the wrong place.
+        process_started_at: on the timer's clock; the phases are seconds
+        since then (since this call where the entry knows no better)."""
+        now = self.timer.get_current_time()
+        start = now if process_started_at is None else process_started_at
+        self.rejoin = {"t0": start, "phases_s": {"process_start": 0.0},
+                       "rounds": [], "stash": None}
         self.rejoining = True
+        self._rejoin_mark("stores_replayed")
         self._needs_resync = True
         self._maybe_resync_after_partition()
+
+    def _rejoin_mark(self, phase: str, last: bool = False) -> None:
+        """A rejoin's phase, once (`last`: at its latest occurrence), as
+        seconds since the process started; nothing once the rejoin is
+        over (its first own 3PC order)."""
+        rejoin = self.rejoin
+        if rejoin is None or "first_3pc_order" in rejoin["phases_s"] \
+                or (phase in rejoin["phases_s"] and not last):
+            return
+        rejoin["phases_s"][phase] = round(
+            self.timer.get_current_time() - rejoin["t0"], 6)
 
     def _on_unbacked_tail(self, ledger_id: int, backed_size: int) -> None:
         """Rejoin (catchup/cons_proof.py): this node's audit ledger runs
@@ -1707,6 +1746,11 @@ class Node:
         self.pool_manager.pool_changed()
         self._last_executed_pp_seq = max(self._last_executed_pp_seq,
                                          pp_seq_no)
+        # a round of a rejoin still open (the replay below may order this
+        # node's first batch, which closes it)
+        of_rejoin = self.rejoin is not None \
+            and "first_3pc_order" not in self.rejoin["phases_s"]
+        self._rejoin_mark("catchup_complete", last=True)
         for replica in self.replicas:
             if view_no > replica.data.view_no:
                 replica.data.view_no = view_no
@@ -1720,12 +1764,50 @@ class Node:
         # root reached by catch-up came without its multi-signature
         self.read_plane.restore_anchors()
         self._fetch_missing_multi_sigs()
-        if self.rejoining:
+        master = self.master_replica.ordering
+        caught_up = sum(self._caught_up_txns.values())
+        gap = None
+        if caught_up > self._caught_up_at_last_round:
+            # a pool that keeps ordering has a batch in flight whenever a
+            # target is agreed; a node that was down when that batch's
+            # 3PC messages left can never order it, nor anything after it.
+            # The round that just ended moved the ledgers, so the next one
+            # starts now rather than at the stuck-behind check, 5-10 s on:
+            # this node has listened since, so that round's target covers
+            # every batch it holds no messages for
+            gap = master.gap_behind()
+            if gap is not None:
+                self.spylog.append(("catchup_gap_behind", (pp_seq_no, gap)))
+                self.timer.schedule(0.0, self.start_catchup)
+        if of_rejoin:
+            # how far the pool moved while this round ran: the batch the
+            # round was agreed at, beside the highest one f+1 COMMITs
+            # held here vouch for when it ended
+            self.rejoin["rounds"].append({
+                "seconds": duration, "target_3pc": [view_no, pp_seq_no],
+                "target_sizes": {
+                    lid: leecher.target_size
+                    for lid, leecher in self.leecher.leechers.items()
+                    if leecher.target_size is not None},
+                "txns": caught_up - self._caught_up_at_last_round,
+                "pool_seen_at": master.behind_evidence() or pp_seq_no,
+                # set where another round follows at once: COMMITs from
+                # f+1 reach this far past a batch no message here orders
+                "gap_behind": gap,
+                "request_rounds_so_far": rounds["rounds"]})
+            self.rejoin["stash"] = master.catchup_stash
+        self._caught_up_at_last_round = caught_up
+        if self.rejoining and gap is None:
             self.rejoining = False
             if self.recovery is not None:
+                phases = self.rejoin["phases_s"]
                 self.recovery["rejoined"] = {
                     "txns_caught_up": dict(self._caught_up_txns),
-                    "seconds": duration, "last_3pc": [view_no, pp_seq_no]}
+                    "seconds": round(phases["catchup_complete"]
+                                     - phases["catchup_started"], 6),
+                    "rounds": len(self.rejoin["rounds"]),
+                    "last_3pc": [view_no, pp_seq_no]}
+                self.recovery["seconds"]["phases"] = phases
 
     def _forward_to_replicas(self, digest: str) -> None:
         self.monitor.request_finalized(digest)
@@ -1733,6 +1815,11 @@ class Node:
             replica.internal_bus.send(ReqKey(digest))
 
     def _on_ordered(self, msg: Ordered) -> None:
+        if msg.inst_id == 0 and self.rejoin is not None \
+                and not self.leecher.is_running:
+            # the rejoin is over: a batch ordered by this node's own
+            # COMMIT quorum, not taken from a peer's ledger
+            self._rejoin_mark("first_3pc_order")
         if msg.inst_id == 0 and "new_view" in self._vc_phase_ts:
             # first post-VC MASTER order closes the episode (backups'
             # ordering is not client-visible recovery)
@@ -2517,6 +2604,14 @@ class Node:
             # on memory stores
             "storage": self._storage_io() if self._durable_kvs else None,
             "recovery": self.recovery,
+            # a start from this node's disk against a live pool: the
+            # phases on one clock (seconds since the process started) and
+            # each catch-up round, up to the first batch it ordered by
+            # its own quorum (docs/rejoin.md); None on any other start
+            "rejoin": self.rejoin,
+            # what this node served to peers that caught up, since its
+            # start (catchup/seeder.py)
+            "catchup": {"seeder": self.seeder.report()},
             # a write's time on this node by stage (tracing.StageClock):
             # cumulative count and sum, quantiles since the last flush
             "stages": self.stages.report(),

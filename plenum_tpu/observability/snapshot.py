@@ -168,6 +168,8 @@ SNAPSHOT_SCHEMA: dict[str, frozenset] = {
         MetricsName.VC_DURATION, MetricsName.CATCHUP_DURATION,
         MetricsName.CATCHUP_ROUNDS, MetricsName.CATCHUP_PROVIDER_SWITCHES,
         MetricsName.CATCHUP_WATCHDOG_KICKS, MetricsName.CATCHUP_DEGRADED,
+        MetricsName.SEEDER_REQS, MetricsName.SEEDER_TXNS_SERVED,
+        MetricsName.SEEDER_BYTES_SERVED, MetricsName.SEEDER_SERVE_TIME,
         MetricsName.MEMBERSHIP_POOL_CHANGES, MetricsName.MEMBERSHIP_VALIDATORS,
         MetricsName.MEMBERSHIP_KEY_ROTATIONS,
     }),

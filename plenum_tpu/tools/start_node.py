@@ -31,6 +31,25 @@ from collections import deque
 REJOIN_WAIT_S = 60.0
 
 
+def process_started_at() -> float:
+    """When the kernel made this process, on time.perf_counter's clock
+    (CLOCK_MONOTONIC, as /proc's start time but for suspends): what a
+    restarted validator's phases count from, so that the interpreter's
+    start and the imports are in them. The moment of this call where
+    /proc does not say."""
+    try:
+        with open("/proc/self/stat") as fh:
+            ticks = int(fh.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as fh:
+            up = float(fh.read().split()[0])
+        age = up - ticks / os.sysconf("SC_CLK_TCK")
+        if age >= 0.0:
+            return time.perf_counter() - age
+    except (OSError, ValueError, IndexError):
+        pass
+    return time.perf_counter()
+
+
 class _DurableSpylog(deque):
     """The node's bounded in-memory event trace, made durable: every
     append also writes a JSONL row {"t", "event", "data"} that
@@ -315,8 +334,13 @@ def main(argv=None):
                          " (feeds tools.perf_budget — the Amdahl breakdown)")
     args = ap.parse_args(argv)
 
-    prodable, node, _ = build_node(args.name, args.base_dir, args.backend,
-                                   args.kv, record=args.record)
+    born = process_started_at()
+    import jax
+    # a restart's replay of its stores, as a span of a traced process
+    with jax.profiler.TraceAnnotation("rejoin.replay"):
+        prodable, node, _ = build_node(args.name, args.base_dir,
+                                       args.backend, args.kv,
+                                       record=args.record)
     ring = node.c.pipeline
     if ring is not None:
         # before the start line: whoever waits for it may send at once
@@ -386,7 +410,7 @@ def main(argv=None):
         if recovery is not None and recovery["restarted"]:
             # ledgers on disk: first to where the pool is, then serve
             t0 = time.monotonic()
-            node.rejoin_after_restart()
+            node.rejoin_after_restart(process_started_at=born)
             while node.rejoining and time.monotonic() - t0 < REJOIN_WAIT_S:
                 await asyncio.sleep(0.02)
             recovery["seconds"]["rejoin"] = round(time.monotonic() - t0, 3)
