@@ -60,6 +60,16 @@ class MetricsName:
     # signature validation, uncommitted apply, the durable group flush,
     # and client REPLY fan-out — regressions must localize to a stage
     COMMIT_BLS_VERIFY_TIME = "commit_path.bls_verify_time"
+    # the COMMIT-set check runs on the native library's worker thread
+    # (consensus/bls_bft_replica.py submit_order / landings): seconds this
+    # node's thread blocked in a landing for the worker (one event a
+    # landing; 1 - sum(join_wait) / sum(bls_verify_time) is the share of
+    # the checks' time that ran beside the loop), and this node's checks
+    # so far that went to the worker / were settled at the submit
+    # (cumulative gauges sampled at flush)
+    COMMIT_BLS_JOIN_WAIT = "bls.join_wait"
+    BLS_CHECKS_OFFLOADED = "bls.checks_offloaded"
+    BLS_CHECKS_INLINE = "bls.checks_inline"
     COMMIT_APPLY_TIME = "commit_path.apply_time"
     COMMIT_DURABLE_TIME = "commit_path.durable_time"
     COMMIT_REPLY_TIME = "commit_path.reply_time"
@@ -431,7 +441,7 @@ def sample_process_gauges(collector: "MetricsCollector") -> None:
 # batches, and SAMPLE_CAP per flush keeps rows small.
 SAMPLED_NAMES = frozenset({
     MetricsName.COMMIT_BLS_VERIFY_TIME, MetricsName.COMMIT_APPLY_TIME,
-    MetricsName.COMMIT_WAVE_TIME,
+    MetricsName.COMMIT_BLS_JOIN_WAIT, MetricsName.COMMIT_WAVE_TIME,
     MetricsName.COMMIT_DURABLE_TIME, MetricsName.COMMIT_REPLY_TIME,
     MetricsName.STORAGE_FLUSH_TIME,
     MetricsName.STAGE_INBOX_WAIT, MetricsName.STAGE_AUTH_WAIT,

@@ -10,13 +10,16 @@ bls_store.py (root-hash → multi-sig KV used by state-proof reads).
 from __future__ import annotations
 
 import time
-from typing import Callable, Optional
+from collections import deque
+from typing import Callable, NamedTuple, Optional
 
 from plenum_tpu.common.metrics import MetricsName
 from plenum_tpu.common.node_messages import Commit, PrePrepare
 from plenum_tpu.common.quorums import Quorums
 from plenum_tpu.common.serialization import json_dumps, json_loads
-from plenum_tpu.crypto.bls import BlsCryptoSigner, BlsCryptoVerifier
+from plenum_tpu.crypto.bls import (BatchCheck, BlsCryptoSigner,
+                                   BlsCryptoVerifier)
+from plenum_tpu.crypto.bn254 import PAIRING_STATS
 from plenum_tpu.crypto.multi_signature import (MultiSignature,
                                                MultiSignatureValue)
 from plenum_tpu.storage.kv_store import KeyValueStorage
@@ -62,6 +65,19 @@ class BlsStore:
         if data is None:
             return None
         return MultiSignature.from_list(json_loads(data))
+
+
+class _Submitted(NamedTuple):
+    """An order-time check begun and not landed yet: what the landing
+    needs of the moment it was submitted in."""
+    key: tuple                    # (view_no, pp_seq_no)
+    pre_prepare: PrePrepare
+    quorums: Quorums
+    names: list                   # signers checked, sorted: the items' order
+    sigs: dict                    # name -> signature
+    check: BatchCheck
+    pairings: int                 # Miller loops the submit itself counted
+    late: bool                    # asked for by a late COMMIT, not by _order
 
 
 class BlsBftReplica:
@@ -131,6 +147,22 @@ class BlsBftReplica:
         # node emits (and a just-re-keyed node never visibly rejoins)
         self._aggregated: dict[tuple[int, int],
                                tuple[PrePrepare, tuple]] = {}
+        # order-time checks submitted and not landed, in submit order
+        # (`submit_order` / `land`): the pairing work of each runs on the
+        # native library's worker thread, beside this node's loop
+        self._submitted: deque[_Submitted] = deque()
+        # what this replica's checks did, cumulative (VALIDATOR_INFO
+        # `bls`): `offloaded` went to the worker, `inline` were settled
+        # at the submit (verdict cache, malformed input, no native
+        # library); `join_wait` the seconds this thread blocked in the
+        # landings, `verify` the checks' own seconds, `verify_late`
+        # those of them that a late COMMIT asked for (one fresh signature
+        # where the quorum's check has three: the two modes of
+        # `commit_path.bls_verify_time`)
+        self.stats = {"offloaded": 0, "inline": 0,
+                      "join_wait": {"count": 0, "sum_s": 0.0},
+                      "verify": {"count": 0, "sum_s": 0.0},
+                      "verify_late": {"count": 0, "sum_s": 0.0}}
 
     def set_quorums(self, quorums: Quorums) -> None:
         self._quorums = quorums
@@ -150,6 +182,7 @@ class BlsBftReplica:
 
     def update_pre_prepare(self, params: dict, state_root: str) -> dict:
         """Attach the previous batch's aggregated multi-sig (by state root)."""
+        self.land_all()
         ms = self._recent_multi_sigs.get(state_root)
         if ms is not None:
             params["bls_multi_sig"] = tuple(ms.to_list())
@@ -176,6 +209,12 @@ class BlsBftReplica:
         # self-aggregation).
         if len(set(ms.participants)) != len(ms.participants):
             return False
+        # a check of this node's own over the same root, still with the
+        # worker, may be about to aggregate this very multi-signature
+        for sub in self._submitted:
+            if sub.pre_prepare.state_root == ms.value.state_root_hash:
+                self.land(sub.key)
+                break
         # A multi-sig we aggregated (or fully verified) OURSELVES passed the
         # quorum rules in force when it was created. This shortcut must come
         # BEFORE the current-quorum check: the first PRE-PREPARE after a pool
@@ -258,13 +297,21 @@ class BlsBftReplica:
             return
         key = (commit.view_no, commit.pp_seq_no)
         self._sigs.setdefault(key, {})[sender_node] = commit.bls_sig
+        # the batch's check is still with the worker: whatever it finds,
+        # a signer it did not see asks again, behind it (the signatures it
+        # is checking are not checked twice: crypto/bls.py _IN_FLIGHT)
+        for sub in reversed(self._submitted):
+            if sub.key == key:
+                if sender_node not in sub.names:
+                    self._submit(key, sub.pre_prepare, late=True)
+                return
         # A batch can order before every honest COMMIT arrives; if its
         # multi-sig aggregation fell short of quorum (e.g. one bad signature
         # evicted by the bisection), late honest sigs must retry it — or a
         # single Byzantine racer could suppress multi-sigs forever.
         pending = self._pending_order.get(key)
         if pending is not None:
-            self.process_order(key, pending)
+            self._submit(key, pending, late=True)
             return
         # late sig for an already-aggregated batch: re-aggregate so the
         # sender joins the multi-sig (verdicts of the existing members
@@ -272,12 +319,28 @@ class BlsBftReplica:
         # check of the new sig, not n pairings)
         agg = self._aggregated.get(key)
         if agg is not None and sender_node not in agg[1]:
-            self.process_order(key, agg[0])
+            self._submit(key, agg[0], late=True)
 
     # --- order ------------------------------------------------------------
 
     def process_order(self, key: tuple[int, int],
                       pre_prepare: PrePrepare) -> Optional[MultiSignature]:
+        """Submit and land at once: for a caller that needs the
+        multi-signature now."""
+        self.submit_order(key, pre_prepare)
+        return self.land(key)
+
+    def submit_order(self, key: tuple[int, int],
+                     pre_prepare: PrePrepare) -> None:
+        """The batch is ordered: begin the check of its COMMIT
+        signatures. Whatever follows from the verdicts happens in the
+        landing, at the first point that reads them (`update_pre_prepare`,
+        the close of the group commit, `gc`, ...); in between the pairing
+        work runs beside this thread."""
+        self._submit(key, pre_prepare, late=False)
+
+    def _submit(self, key: tuple[int, int], pre_prepare: PrePrepare,
+                late: bool) -> None:
         # Aggregate under the keys and quorum of the EPOCH the sig value
         # cites (the pre-prepare's pool state root), not the node's current
         # register: around a rotation or demotion the two differ, and every
@@ -296,29 +359,99 @@ class BlsBftReplica:
                 and n not in self._known_bad.get(key, set())}
         if not quorums.bls_signatures.is_reached(len(sigs)):
             self._pending_order[key] = pre_prepare      # retry on late sigs
-            return None
+            return
+        # Validate the whole COMMIT set with ONE random-linear-combination
+        # pairing check (crypto.bls.BlsCryptoVerifier.batch_verify_begin):
+        # every signer signs the same ordered-batch value, so the combined
+        # check costs 2 pairings regardless of pool size — amortized O(1)
+        # vs the Θ(n) independent 2-pairing checks of per-Commit
+        # verification. On failure the verifier falls back to
+        # per-signature checks, which name the culprit(s) exactly (no
+        # subset bisection: plain-aggregation subsets can be satisfied by
+        # error-cancelling signature pairs, the RLC cannot).
         value = self._signed_value(pre_prepare).as_single_value()
-        t0 = time.perf_counter()
-        from plenum_tpu.crypto.bn254 import PAIRING_STATS
+        names = sorted(sigs)
         pairings_before = PAIRING_STATS["pairings"]
-        good, bad = self._batch_verify_commits(sigs, value, vk_of)
+        check = self._verifier.batch_verify_begin(
+            [(sigs[n], value, vk_of[n]) for n in names])
+        self._drop_stale_points(vk_of)
+        self.stats["offloaded" if check.offloaded else "inline"] += 1
+        self._submitted.append(_Submitted(
+            key, pre_prepare, quorums, names, sigs, check,
+            PAIRING_STATS["pairings"] - pairings_before, late))
+
+    def land_all(self) -> None:
+        """Land every check submitted, in submit order; blocks while the
+        worker still computes one of them."""
+        while self._submitted:
+            self._land_first()
+
+    def land_ordered(self, key: tuple[int, int]) -> None:
+        """Land through the check that `submit_order` began for `key`, and
+        leave what late COMMITs asked for behind it: the multi-signature
+        of that batch and of every batch ordered before it is in the
+        store when this returns (the group commit closes on it, a REPLY
+        follows), and no REPLY waits for an upgrade by a fourth signer."""
+        n = next((i + 1 for i, sub in enumerate(self._submitted)
+                  if sub.key == key and not sub.late), 0)
+        for _ in range(n):
+            self._land_first()
+
+    def land_done(self) -> None:
+        """Land what the worker has finished, in submit order, and stop
+        at the first check it has not: never blocks."""
+        while self._submitted and self._verifier.batch_verify_ready(
+                self._submitted[0].check):
+            self._land_first()
+
+    def land(self, key: tuple[int, int]) -> Optional[MultiSignature]:
+        """Land through the newest check submitted for `key` (what follows
+        from verdicts happens in submit order, so every earlier check
+        lands first). -> the multi-signature that check aggregated, if it
+        did."""
+        ms = None
+        while any(sub.key == key for sub in self._submitted):
+            landed, ms = self._land_first()
+            if landed != key:
+                ms = None
+        return ms
+
+    def _land_first(self) -> tuple[tuple[int, int], Optional[MultiSignature]]:
+        sub = self._submitted.popleft()
+        key = sub.key
+        pre_prepare, quorums = sub.pre_prepare, sub.quorums
+        pairings_before = PAIRING_STATS["pairings"]
+        t0 = time.perf_counter()
+        oks = self._verifier.batch_verify_end(sub.check)
+        waited = time.perf_counter() - t0
+        good = {n: sub.sigs[n] for n, ok in zip(sub.names, oks) if ok}
+        bad = [n for n, ok in zip(sub.names, oks) if not ok]
+        self._note(self.stats["verify"], sub.check.seconds)
+        if sub.late:
+            self._note(self.stats["verify_late"], sub.check.seconds)
+        self._note(self.stats["join_wait"], waited)
         if self.metrics is not None:
             self.metrics.add_event(MetricsName.COMMIT_BLS_VERIFY_TIME,
-                                   time.perf_counter() - t0)
-            self.metrics.add_event(MetricsName.BLS_PAIRINGS_PER_BATCH,
-                                   PAIRING_STATS["pairings"] - pairings_before)
+                                   sub.check.seconds)
+            self.metrics.add_event(MetricsName.COMMIT_BLS_JOIN_WAIT, waited)
+            self.metrics.add_event(
+                MetricsName.BLS_PAIRINGS_PER_BATCH, sub.pairings
+                + PAIRING_STATS["pairings"] - pairings_before)
         for sender in bad:
-            self._known_bad.setdefault(key, set()).add(sender)
+            known = self._known_bad.setdefault(key, set())
+            if sender in known:
+                continue        # a check begun before the one that named it
+            known.add(sender)
             if self.report_bad_signature is not None:
                 self.report_bad_signature(sender)
         if not quorums.bls_signatures.is_reached(len(good)):
             self._pending_order[key] = pre_prepare      # retry on late sigs
-            return None
+            return key, None
         self._pending_order.pop(key, None)
         participants = tuple(sorted(good))
         prev = self._aggregated.get(key)
         if prev is not None and set(participants) <= set(prev[1]):
-            return None         # no new honest signer: keep the aggregate
+            return key, None    # no new honest signer: keep the aggregate
         agg = self._verifier.create_multi_sig([good[n] for n in participants])
         ms = MultiSignature(signature=agg, participants=participants,
                             value=self._signed_value(pre_prepare))
@@ -335,7 +468,12 @@ class BlsBftReplica:
             self._store.put(ms)
         if self.on_multi_sig is not None:
             self.on_multi_sig(ms)
-        return ms
+        return key, ms
+
+    @staticmethod
+    def _note(record: dict, seconds: float) -> None:
+        record["count"] += 1
+        record["sum_s"] += seconds
 
     def _epoch_of(self, pool_root: str):
         """-> (key_of, reg, quorums) AS OF `pool_root` — the epoch a
@@ -355,26 +493,6 @@ class BlsBftReplica:
                 if self._key_at is not None else None
             return vk or self._register.get_key_by_name(n)
         return key_of, reg, quorums
-
-    def _batch_verify_commits(self, sigs: dict[str, str], value: bytes,
-                              vk_of: dict[str, Optional[str]]) \
-            -> tuple[dict[str, str], list[str]]:
-        """Validate the whole COMMIT set with ONE random-linear-combination
-        pairing check (crypto.bls.BlsCryptoVerifier.batch_verify): every
-        signer signs the same ordered-batch value, so the combined check
-        costs 2 pairings regardless of pool size — amortized O(1) vs the
-        Θ(n) independent 2-pairing checks of per-Commit verification. On
-        failure the verifier falls back to per-signature checks, which name
-        the culprit(s) exactly (no subset bisection: plain-aggregation
-        subsets can be satisfied by error-cancelling signature pairs, the
-        RLC cannot)."""
-        names = sorted(sigs)
-        items = [(sigs[n], value, vk_of[n]) for n in names]
-        oks = self._verifier.batch_verify(items)
-        self._drop_stale_points(vk_of)
-        good = {n: sigs[n] for n, ok in zip(names, oks) if ok}
-        bad = [n for n, ok in zip(names, oks) if not ok]
-        return good, bad
 
     def _drop_stale_points(self, vk_of: dict[str, Optional[str]]) -> None:
         """A historic-epoch verify (a batch citing a pre-rotation pool
@@ -396,6 +514,7 @@ class BlsBftReplica:
             seq, self._verified_ms_keys.get(ms_key, 0))
 
     def gc(self, stable_3pc: tuple[int, int]) -> None:
+        self.land_all()
         seq = stable_3pc[1]
         # the multi-sig of the checkpoint's own last batch stays: the
         # PRE-PREPARE after it carries it

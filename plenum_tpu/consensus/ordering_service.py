@@ -174,6 +174,8 @@ class OrderingService:
         """Detach from the shared network bus (replica removal): a removed
         instance must not keep consuming 3PC messages as a zombie."""
         self._stasher.unsubscribe_from_buses()
+        if self._bls is not None:
+            self._bls.land_all()
 
     # ------------------------------------------------------------------ #
     # request intake                                                     #
@@ -214,6 +216,10 @@ class OrderingService:
 
     def service(self) -> None:
         """Called each prod cycle: primaries turn queued requests into batches."""
+        if self._bls is not None:
+            # what an earlier cycle left with the BLS worker and is done
+            # by now (a late COMMIT's re-run: nothing waits for it)
+            self._bls.land_done()
         if not self.is_primary or self._data.waiting_for_new_view:
             self._freshness_deadline.clear()
             return
@@ -1002,7 +1008,11 @@ class OrderingService:
         self._applied_unordered = [(lid, b) for (lid, b) in self._applied_unordered
                                    if b != batch_id]
         if self._bls is not None:
-            self._bls.process_order(key, pp)
+            # the COMMIT signatures' pairing check runs beside this loop
+            # from here; it lands (multi-signature aggregated, stored,
+            # announced) before the next PRE-PREPARE is built and before
+            # this batch's group commit closes (Node._service_ordered)
+            self._bls.submit_order(key, pp)
         if rerun:
             # already executed under its original view: this pass only
             # re-certified the batch into the new view's 3PC chain
@@ -1050,6 +1060,8 @@ class OrderingService:
         return count
 
     def catchup_started(self) -> None:
+        if self._bls is not None:
+            self._bls.land_all()
         self.revert_unordered_batches()
         self._data.is_participating = False
 
@@ -1181,6 +1193,8 @@ class OrderingService:
         pre-prepares for possible re-ordering (ref :2380)."""
         self._phase_ts.clear()      # timings don't span views
         self._cut_ts.clear()        # controller spans don't span views
+        if self._bls is not None:
+            self._bls.land_all()
         reverted = self.span("vc.revert_batches",
                              self.revert_unordered_batches)
         if self._data.is_master:

@@ -11,6 +11,7 @@ attacks exactly as the reference's PoP does.
 from __future__ import annotations
 
 import os
+import time
 from typing import Optional, Sequence
 
 from plenum_tpu.utils.base58 import b58decode, b58encode
@@ -142,13 +143,17 @@ def batch_coefficients(n: int) -> list[int]:
     return [int.from_bytes(os.urandom(16), "big") | 1 for _ in range(n)]
 
 
-def _combined_pairs(entries: Sequence[tuple]) -> list:
+def _combined_pairs(entries: Sequence[tuple],
+                    coeffs: Optional[Sequence[int]] = None) -> list:
     """THE random-linear-combination construction, shared by every batch
     check (soundness-critical — one copy only): decoded (sig_pt, msg_bytes,
     pk_pt) triples -> the pairing_check pair list
     [(G2, -Σrᵢσᵢ)] + [(Σ_{mᵢ=m} rᵢpkᵢ, H(m)) per distinct m], under fresh
-    coefficients."""
-    coeffs = batch_coefficients(len(entries))
+    coefficients (`coeffs` is for the differential test of the native
+    twin, native/bn254.cpp "the COMMIT-set check", which is handed its
+    coefficients by `_begin_fresh` below and draws none itself)."""
+    if coeffs is None:
+        coeffs = batch_coefficients(len(entries))
     agg_sig: c.G1Point = None
     by_msg: dict[bytes, c.G2Point] = {}
     for (sig, msg, pk), r in zip(entries, coeffs):
@@ -246,11 +251,57 @@ _BLS_VERDICTS_MAX = 16384
 # rising fallback rate is the operator's first sign of a bad signer (or
 # a bug) long before throughput moves.
 BATCH_STATS = {"batches": 0, "combined_ok": 0, "fallbacks": 0,
-               "per_sig_checks": 0}
+               "per_sig_checks": 0, "offloaded": 0}
 
 
 def _bls_cache_put(key: bytes, verdict: bool) -> bool:
     return _cache_put(_BLS_VERDICTS, _BLS_VERDICTS_MAX, key, verdict)
+
+
+def _combined_ok(keys: Sequence[bytes]) -> dict[bytes, bool]:
+    """A combined check passed: every signature of it is valid."""
+    BATCH_STATS["combined_ok"] += 1
+    return {k: _bls_cache_put(k, True) for k in keys}
+
+
+class _Flight:
+    """One combined check on the native worker, and afterwards its
+    verdicts, for every asker of one of its signatures."""
+    __slots__ = ("ticket", "keys", "items", "entries", "verdicts", "seconds")
+
+    def __init__(self, ticket: int, keys: tuple, items: list, entries: list,
+                 prepared_s: float):
+        self.ticket = ticket
+        self.keys = keys            # the verdict-cache keys of the set
+        self.items = items
+        self.entries = entries      # decoded, for the Python twin
+        self.verdicts: Optional[dict[bytes, bool]] = None
+        # decoding and hashing on the caller's thread, then the worker's
+        # begin to done
+        self.seconds = prepared_s
+
+
+# signatures whose check is with the worker now, by verdict-cache key
+_IN_FLIGHT: dict[bytes, _Flight] = {}
+
+
+class BatchCheck:
+    """What `batch_verify_begin` hands back: the verdicts known so far
+    (None where a check in `flights` will give one) and, once ended (or
+    at once where nothing is with the worker), `seconds`: the check's own
+    duration, begin to done, whoever waited for it or did not."""
+    __slots__ = ("items", "cache_keys", "verdicts", "flights", "seconds")
+
+    def __init__(self, items: list):
+        self.items = items
+        self.cache_keys: list[bytes] = []
+        self.verdicts: list[Optional[bool]] = []
+        self.flights: list[_Flight] = []
+        self.seconds = 0.0
+
+    @property
+    def offloaded(self) -> bool:
+        return bool(self.flights)
 
 
 class BlsCryptoVerifier:
@@ -330,48 +381,125 @@ class BlsCryptoVerifier:
         checks, which name the culprit(s) exactly; those verdicts ride the
         process-wide cache, so re-checking a batch after evicting a bad
         signer costs one fresh combined check, not n pairings."""
-        items = list(items)
-        if not items:
-            return []
+        return self.batch_verify_end(self.batch_verify_begin(items))
+
+    def batch_verify_begin(self, items: Sequence[tuple[str, bytes, str]]
+                           ) -> "BatchCheck":
+        """`batch_verify`, begun: what the verdict cache, a malformed
+        input or the pure-Python engine settles is settled here, at once.
+        A fresh same-message set (every COMMIT set is one) with the
+        native library there is decoded and hashed here and its combined
+        check handed to the library's worker thread; `batch_verify_end`
+        comes back for it, and the caller's thread is free in between.
+        A signature whose check is with the worker already (co-hosted
+        nodes ask for the same COMMIT set at order time; a late COMMIT's
+        re-run asks for the quorum's three again) is not checked twice:
+        the askers share that check in flight, as they share its verdicts
+        afterwards."""
+        t0 = time.perf_counter()
+        check = BatchCheck(list(items))
         # A passing combined check certifies each signature INDIVIDUALLY
         # (unlike plain aggregation), so per-signature verdicts are shared
         # with verify_sig through the process-wide cache: co-hosted nodes
         # batch-checking the identical COMMIT set (sim pools, multi-replica
         # hosts) pay the pairings once per host, dict hits after.
-        verdicts: list[Optional[bool]] = []
-        cache_keys: list[bytes] = []
-        for sig_b58, msg, vk_b58 in items:
+        todo = []
+        for i, (sig_b58, msg, vk_b58) in enumerate(check.items):
             k = _bls_verdict_key(b"sig", sig_b58.encode(), msg,
                                  vk_b58.encode())
-            cache_keys.append(k)
-            verdicts.append(_BLS_VERDICTS.get(k))
-        todo = [i for i, vd in enumerate(verdicts) if vd is None]
-        if not todo:
-            return [bool(v) for v in verdicts]
+            check.cache_keys.append(k)
+            check.verdicts.append(_BLS_VERDICTS.get(k))
+            if check.verdicts[i] is None:
+                flight = _IN_FLIGHT.get(k)
+                if flight is None:
+                    todo.append(i)
+                elif flight not in check.flights:
+                    check.flights.append(flight)
+        if todo:
+            self._begin_fresh(check, todo, t0)
+        if not check.flights:
+            check.seconds = time.perf_counter() - t0
+        return check
+
+    def _begin_fresh(self, check: "BatchCheck", todo: list[int],
+                     t0: float) -> None:
         BATCH_STATS["batches"] += 1
-        decoded: dict[int, tuple] = {}
-        malformed = False
-        for i in todo:
-            sig_b58, msg, vk_b58 = items[i]
-            try:
-                decoded[i] = (_decode_sig(sig_b58), msg, self._pk(vk_b58))
-            except (ValueError, KeyError):
-                malformed = True
-        if not malformed:
-            if c.pairing_check(_combined_pairs([decoded[i] for i in todo])):
-                BATCH_STATS["combined_ok"] += 1
-                for i in todo:
-                    _bls_cache_put(cache_keys[i], True)
-                    verdicts[i] = True
-                return [bool(v) for v in verdicts]
-        # combined check failed or input malformed: per-signature culprit
-        # naming — counted, never silent (a rising rate flags a bad signer)
+        keys = tuple(check.cache_keys[i] for i in todo)
+        todo_items = [check.items[i] for i in todo]
+        try:
+            entries = [(_decode_sig(s), m, self._pk(v))
+                       for s, m, v in todo_items]
+        except (ValueError, KeyError):
+            entries = None                  # malformed: name it below
+        if entries is not None:
+            msg = entries[0][1]
+            ticket = None
+            if all(m == msg for _, m, _ in entries):
+                ticket = c.commit_check_begin(
+                    [s for s, _, _ in entries], [k for _, _, k in entries],
+                    batch_coefficients(len(entries)),
+                    c.hash_to_g1(msg, _MSG_DOMAIN))
+            if ticket is not None:
+                BATCH_STATS["offloaded"] += 1
+                flight = _Flight(ticket, keys, todo_items, entries,
+                                 time.perf_counter() - t0)
+                _IN_FLIGHT.update(dict.fromkeys(keys, flight))
+                check.flights.append(flight)
+                return
+            if c.pairing_check(_combined_pairs(entries)):
+                self._settle(check, _combined_ok(keys))
+                return
+        self._settle(check, self._name_culprits(keys, todo_items))
+
+    def _name_culprits(self, keys: tuple, items: list) -> dict[bytes, bool]:
+        """combined check failed or input malformed: per-signature culprit
+        naming — counted, never silent (a rising rate flags a bad signer)"""
         BATCH_STATS["fallbacks"] += 1
-        BATCH_STATS["per_sig_checks"] += len(todo)
-        for i in todo:
-            s, m, v = items[i]
-            verdicts[i] = (i in decoded) and self.verify_sig(s, m, v)
-        return [bool(v) for v in verdicts]
+        BATCH_STATS["per_sig_checks"] += len(items)
+        return {k: self.verify_sig(*it) for k, it in zip(keys, items)}
+
+    @staticmethod
+    def _settle(check: "BatchCheck", fresh: dict[bytes, bool]) -> None:
+        check.verdicts = [fresh.get(k) if vd is None else vd
+                          for k, vd in zip(check.cache_keys, check.verdicts)]
+
+    def batch_verify_ready(self, check: "BatchCheck") -> bool:
+        """Whether `batch_verify_end` would return without waiting."""
+        return all(flight.verdicts is not None
+                   or self._land_flight(flight, wait=False)
+                   for flight in check.flights)
+
+    def batch_verify_end(self, check: "BatchCheck") -> list[bool]:
+        """The verdicts of a check begun; blocks (GIL released) while the
+        worker still computes. If a combined check FAILED, the
+        per-signature culprit naming runs here, inline (rare, counted)."""
+        for flight in check.flights:
+            if flight.verdicts is None:
+                self._land_flight(flight, wait=True)
+            # the worker takes its checks one after the other: the last
+            # one's end is this check's
+            check.seconds = max(check.seconds, flight.seconds)
+            self._settle(check, flight.verdicts)
+        check.flights = []
+        return [bool(v) for v in check.verdicts]
+
+    def _land_flight(self, flight: "_Flight", wait: bool) -> bool:
+        done = c.commit_check_end(flight.ticket, wait)
+        if done is None:
+            return False
+        ok, worked = done
+        flight.seconds += worked
+        for k in flight.keys:
+            if _IN_FLIGHT.get(k) is flight:
+                del _IN_FLIGHT[k]
+        if ok is None:
+            # the worker has no verdict to give (its ticket was another
+            # process's): the Python twin decides
+            ok = c.pairing_check(_combined_pairs(flight.entries))
+        flight.verdicts = (
+            _combined_ok(flight.keys) if ok else
+            self._name_culprits(flight.keys, flight.items))
+        return True
 
     def create_multi_sig(self, signatures: Sequence[str]) -> str:
         return aggregate_sigs(signatures)

@@ -481,6 +481,41 @@ def pairing_check(pairs) -> bool:
     return multi_pairing(pairs) == F12_ONE
 
 
+def commit_check_begin(sigs, keys, coeffs, h) -> Optional[int]:
+    """Hand the random-linear-combination check of n signatures (G1) by n
+    keys (G2) over ONE message, e(-Σrᵢσᵢ, G2)·e(H(m), Σrᵢpkᵢ) == 1 under
+    the 128-bit coefficients given, to the native library's own worker
+    thread (native/bn254.cpp, "the COMMIT-set check"). -> a ticket for
+    `commit_check_end`, or None where the library did not build. The
+    thread never needs the GIL: the caller's Python runs beside it."""
+    if _NATIVE is None:
+        return None
+    PAIRING_STATS["checks"] += 1
+    PAIRING_STATS["pairings"] += 2
+    PAIRING_STATS["native"] += 2
+    return _NATIVE.pc_commit_check_begin(
+        b"".join(_enc_g1(s) for s in sigs),
+        b"".join(_enc_g2(k) for k in keys),
+        b"".join(r.to_bytes(16, "big") for r in coeffs),
+        _enc_g1(h), len(sigs))
+
+
+def commit_check_end(ticket: int, wait: bool = True
+                     ) -> Optional[Tuple[Optional[bool], float]]:
+    """-> (verdict, the check's own seconds from begin to done); None
+    while it still runs and `wait` is false. With `wait` it blocks, GIL
+    released. A verdict of None means the library has none to give (a
+    point it could not decode, or a ticket of another process: a forked
+    child does not inherit its parent's worker): the caller decides in
+    Python. A ticket is ended once."""
+    seconds = ctypes.c_double(0.0)
+    res = _NATIVE.pc_commit_check_end(ticket, int(wait),
+                                      ctypes.byref(seconds))
+    if res == -3:
+        return None
+    return (bool(res) if res >= 0 else None), seconds.value
+
+
 # --- hashing to G1 -----------------------------------------------------------
 
 def g1_from_x(x: int) -> G1Point:

@@ -40,7 +40,7 @@ def _build(src_name: str, tag: str) -> Optional[ctypes.CDLL]:
             tmp = so_path + f".build-{os.getpid()}"
             subprocess.run(
                 ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-                 "-std=c++17", "-o", tmp, src],
+                 "-std=c++17", "-pthread", "-o", tmp, src],
                 check=True, capture_output=True, timeout=300)
             os.replace(tmp, so_path)      # atomic: concurrent builds race safely
         return ctypes.CDLL(so_path)
@@ -61,6 +61,16 @@ if _bn254 is not None:
         fn.restype = ctypes.c_int
     _bn254.pc_g2_in_subgroup.argtypes = [ctypes.c_char_p]
     _bn254.pc_g2_in_subgroup.restype = ctypes.c_int
+    # the whole COMMIT-set check on the library's own thread: begin
+    # copies its inputs and returns a ticket, end blocks (or polls) for
+    # the verdict; both release the GIL like every ctypes call
+    _bn254.pc_commit_check_begin.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
+        ctypes.c_int]
+    _bn254.pc_commit_check_begin.restype = ctypes.c_uint64
+    _bn254.pc_commit_check_end.argtypes = [
+        ctypes.c_uint64, ctypes.c_int, ctypes.POINTER(ctypes.c_double)]
+    _bn254.pc_commit_check_end.restype = ctypes.c_int
     # differential-test surface
     _bn254.pc_miller.argtypes = [ctypes.c_char_p, ctypes.c_char_p,
                                  ctypes.c_char_p]

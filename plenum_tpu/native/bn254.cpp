@@ -17,9 +17,18 @@
 // infinity), G2 = x0||x1||y0||y1 (128B, all-zero = infinity) — identical to
 // the Python g1_to_bytes / g2_to_bytes layout.
 
+#include <condition_variable>
 #include <cstdint>
 #include <cstring>
+#include <deque>
 #include <mutex>
+#include <new>
+#include <thread>
+#include <unordered_map>
+#include <vector>
+
+#include <pthread.h>
+#include <time.h>
 
 typedef uint64_t u64;
 typedef __uint128_t u128;
@@ -505,7 +514,6 @@ static const int ATE_TOP_BIT = 64;                   // bit 64 is set (value 0x1
 
 static Fp2 G1C_M[6], G3C_M[6], FROBX_M, FROBY_M;
 static Fp G2C_M[6];
-static Fp2 G2_GEN_X, G2_GEN_Y;
 static bool INITED = false;
 
 static void load_fp2(Fp2 &out, const u64 raw[2][4]) {
@@ -1185,7 +1193,10 @@ static void miller_loop_prepared(Fp12 &f, const PreparedG2 &pre,
     f12_mul_line(f, A, B, pre.c[s]);
 }
 
-#define PREP_CACHE_SLOTS 8
+// the generator, the validators' own keys (a late COMMIT's single-
+// signature check pairs with one) and the aggregate keys of the signer
+// sets a pool of four forms: ten live entries, with room
+#define PREP_CACHE_SLOTS 16
 static PreparedG2 g_prep_cache[PREP_CACHE_SLOTS];
 static uint64_t g_prep_last_hit[PREP_CACHE_SLOTS];
 static uint64_t g_prep_tick = 0;
@@ -1337,6 +1348,165 @@ static void scalar_from_be(u64 *out, const uint8_t *in) {
     }
 }
 
+// Miller loop through the prepared table: reuse (or build and keep) the
+// coefficient sequence for this G2, keyed by its 128-byte encoding, so
+// the loop runs inversion-free on every hit. A degenerate structure
+// (infinity/vertical mid-ladder; impossible for valid subgroup points)
+// falls back to the generic loop.
+static void miller_loop_cached(Fp12 &f, const uint8_t *key, const G2 &q,
+                               const G1 &p) {
+    PreparedG2 pre;
+    if (prep_cache_get(key, pre)) {
+        miller_loop_prepared(f, pre, p);
+    } else if (prepare_g2(pre, q)) {
+        prep_cache_put(key, pre);
+        miller_loop_prepared(f, pre, p);
+    } else {
+        miller_loop(f, q, p);
+    }
+}
+
+// ------------------------------------------------- the COMMIT-set check
+//
+// The random-linear-combination check of n signatures over ONE message
+// (every COMMIT of a batch signs the same value), whole, as one call:
+//
+//     e(-sum r_i*sig_i, G2) * e(H(m), sum r_i*pk_i) == 1
+//
+// the native twin of crypto/bls.py `_combined_pairs` + `pairing_check`
+// (a differential test holds the two together). The coefficients come
+// from the caller: their construction has one copy, in Python.
+//
+// It runs on ONE thread of this library's own, so that a caller with
+// other work hands the check over (begin) and comes back for the verdict
+// (end) while the thread, which never needs the interpreter's lock,
+// computes beside it.
+
+static const uint8_t G2_GEN_BE[128] = {
+    0x18, 0x00, 0xde, 0xef, 0x12, 0x1f, 0x1e, 0x76, 0x42, 0x6a, 0x00, 0x66, 0x5e, 0x5c, 0x44, 0x79,
+    0x67, 0x43, 0x22, 0xd4, 0xf7, 0x5e, 0xda, 0xdd, 0x46, 0xde, 0xbd, 0x5c, 0xd9, 0x92, 0xf6, 0xed,
+    0x19, 0x8e, 0x93, 0x93, 0x92, 0x0d, 0x48, 0x3a, 0x72, 0x60, 0xbf, 0xb7, 0x31, 0xfb, 0x5d, 0x25,
+    0xf1, 0xaa, 0x49, 0x33, 0x35, 0xa9, 0xe7, 0x12, 0x97, 0xe4, 0x85, 0xb7, 0xae, 0xf3, 0x12, 0xc2,
+    0x12, 0xc8, 0x5e, 0xa5, 0xdb, 0x8c, 0x6d, 0xeb, 0x4a, 0xab, 0x71, 0x80, 0x8d, 0xcb, 0x40, 0x8f,
+    0xe3, 0xd1, 0xe7, 0x69, 0x0c, 0x43, 0xd3, 0x7b, 0x4c, 0xe6, 0xcc, 0x01, 0x66, 0xfa, 0x7d, 0xaa,
+    0x09, 0x06, 0x89, 0xd0, 0x58, 0x5f, 0xf0, 0x75, 0xec, 0x9e, 0x99, 0xad, 0x69, 0x0c, 0x33, 0x95,
+    0xbc, 0x4b, 0x31, 0x33, 0x70, 0xb3, 0x8e, 0xf3, 0x55, 0xac, 0xda, 0xdc, 0xd1, 0x22, 0x97, 0x5b,
+};
+
+struct CommitCheck {
+    int n = 0;
+    std::vector<uint8_t> sigs, keys, coeffs;    // n x 64, n x 128, n x 16
+    uint8_t h[64];
+    int result = -1;
+    bool done = false;
+    double began = 0, ended = 0;                // CLOCK_MONOTONIC seconds
+};
+
+static double monotonic_s() {
+    struct timespec ts;
+    clock_gettime(CLOCK_MONOTONIC, &ts);
+    return ts.tv_sec + ts.tv_nsec * 1e-9;
+}
+
+// 1 | 0, -1 on a point that does not decode.
+static int commit_check_run(const CommitCheck &job) {
+    G1 agg_sig, h;
+    G2 agg_key, gen;
+    agg_sig.inf = true;
+    agg_key.inf = true;
+    // ONE signature (a late COMMIT's re-run: the others are cached) holds
+    // under any coefficient that is not 0 mod r iff it holds under 1: the
+    // groups' order is prime. Its key is a validator's own and repeats,
+    // so its Miller loop runs from the prepared table.
+    const bool one = job.n == 1;
+    if (one) {
+        if (!decode_g1(agg_sig, job.sigs.data())) return -1;
+        if (!decode_g2(agg_key, job.keys.data())) return -1;
+    }
+    for (int i = 0; i < job.n && !one; i++) {
+        G1 sig, rsig;
+        G2 key, rkey;
+        if (!decode_g1(sig, job.sigs.data() + 64 * i)) return -1;
+        if (!decode_g2(key, job.keys.data() + 128 * i)) return -1;
+        uint8_t wide[32] = {0};                  // 128-bit BE -> 256-bit BE
+        memcpy(wide + 16, job.coeffs.data() + 16 * i, 16);
+        u64 r[4];
+        scalar_from_be(r, wide);
+        g1_mul_pt(rsig, sig, r);
+        g2_mul_pt(rkey, key, r);
+        g1_add_pt(agg_sig, agg_sig, rsig);
+        g2_add_pt(agg_key, agg_key, rkey);
+    }
+    if (!decode_g1(h, job.h) || !decode_g2(gen, G2_GEN_BE)) return -1;
+    if (!agg_sig.inf) fp_neg(agg_sig.y, agg_sig.y);
+    Fp12 acc, f;
+    // the generator's coefficients stay in the prepared table; a
+    // combined key is fresh every time and would only push others out
+    miller_loop_cached(acc, G2_GEN_BE, gen, agg_sig);
+    if (one)
+        miller_loop_cached(f, job.keys.data(), agg_key, h);
+    else
+        miller_loop(f, agg_key, h);
+    f12_mul(acc, acc, f);
+    Fp12 res;
+    final_exp(res, acc);
+    return f12_is_one(res) ? 1 : 0;
+}
+
+struct CommitWorker {
+    std::mutex mu;
+    std::condition_variable work, finished;
+    std::deque<uint64_t> queue;
+    std::unordered_map<uint64_t, CommitCheck *> jobs;
+    uint64_t next_ticket = 1;
+
+    void run() {
+        std::unique_lock<std::mutex> lock(mu);
+        for (;;) {
+            work.wait(lock, [this] { return !queue.empty(); });
+            CommitCheck *job = jobs[queue.front()];
+            queue.pop_front();
+            lock.unlock();
+            int result = commit_check_run(*job);
+            double ended = monotonic_s();
+            lock.lock();
+            job->result = result;
+            job->ended = ended;
+            job->done = true;
+            finished.notify_all();
+        }
+    }
+};
+
+// One worker a process, made at the first check. A forked child has
+// none of its parent's threads: it forgets the parent's worker (and the
+// checks only that one could finish) and makes its own when it needs it.
+static CommitWorker *g_commit_worker = nullptr;
+static std::mutex g_commit_worker_mu;
+
+static void forget_threads_after_fork() {
+    g_commit_worker = nullptr;                  // leaked: its thread is gone
+    new (&g_commit_worker_mu) std::mutex();
+    // whoever held the table's lock is gone too, maybe half-way through
+    new (&g_prep_mu) std::mutex();
+    for (int i = 0; i < PREP_CACHE_SLOTS; i++) g_prep_cache[i].used = false;
+}
+
+static CommitWorker *commit_worker(bool make) {
+    std::lock_guard<std::mutex> lock(g_commit_worker_mu);
+    if (g_commit_worker == nullptr && make) {
+        static bool registered = false;
+        if (!registered) {
+            pthread_atfork(nullptr, nullptr, forget_threads_after_fork);
+            registered = true;
+        }
+        CommitWorker *w = new CommitWorker();
+        std::thread(&CommitWorker::run, w).detach();
+        g_commit_worker = w;
+    }
+    return g_commit_worker;
+}
+
 // ------------------------------------------------------------------- C ABI
 
 extern "C" {
@@ -1345,32 +1515,69 @@ extern "C" {
 int pc_pairing_check(const uint8_t *g2s, const uint8_t *g1s, int n) {
     init_constants();
     Fp12 acc;
-    memset(&acc, 0, sizeof acc);
-    acc.c0.c0.c0 = FP_ONE_M;
+    f12_one(acc);
     for (int i = 0; i < n; i++) {
         G2 q;
         G1 p;
         if (!decode_g2(q, g2s + 128 * i)) return -1;
         if (!decode_g1(p, g1s + 64 * i)) return -1;
         Fp12 f;
-        // prepared path: reuse (or build) the coefficient sequence for
-        // this G2 — inversion-free Miller loop on every cache hit. A
-        // degenerate structure (infinity/vertical mid-ladder; impossible
-        // for valid subgroup points) falls back to the generic loop.
-        PreparedG2 pre;
-        if (prep_cache_get(g2s + 128 * i, pre)) {
-            miller_loop_prepared(f, pre, p);
-        } else if (prepare_g2(pre, q)) {
-            prep_cache_put(g2s + 128 * i, pre);
-            miller_loop_prepared(f, pre, p);
-        } else {
-            miller_loop(f, q, p);
-        }
+        miller_loop_cached(f, g2s + 128 * i, q, p);
         f12_mul(acc, acc, f);
     }
     Fp12 res;
     final_exp(res, acc);
     return f12_is_one(res) ? 1 : 0;
+}
+
+// Hand the COMMIT-set check of n signatures (64 B each), n keys (128 B
+// each), n 128-bit big-endian coefficients and H(m) (64 B) to the worker.
+// The inputs are copied. -> a ticket (never 0).
+uint64_t pc_commit_check_begin(const uint8_t *sigs, const uint8_t *keys,
+                               const uint8_t *coeffs, const uint8_t *h,
+                               int n) {
+    init_constants();
+    CommitCheck *job = new CommitCheck();
+    job->n = n;
+    job->sigs.assign(sigs, sigs + 64 * n);
+    job->keys.assign(keys, keys + 128 * n);
+    job->coeffs.assign(coeffs, coeffs + 16 * n);
+    memcpy(job->h, h, 64);
+    job->began = monotonic_s();
+    CommitWorker *w = commit_worker(true);
+    std::lock_guard<std::mutex> lock(w->mu);
+    uint64_t ticket = w->next_ticket++;
+    w->jobs[ticket] = job;
+    w->queue.push_back(ticket);
+    w->work.notify_one();
+    return ticket;
+}
+
+// The verdict of a ticket: 1 | 0, -1 on a malformed point, -2 for a
+// ticket this process does not hold (taken already, or a parent's), -3
+// while the check still runs and `wait` is 0. With `wait` it blocks until
+// the worker is done. A verdict is handed out once; with it `seconds`
+// (if not null) gets the check's own duration, begin to done.
+int pc_commit_check_end(uint64_t ticket, int wait, double *seconds) {
+    CommitWorker *w = commit_worker(false);
+    if (w == nullptr) return -2;
+    std::unique_lock<std::mutex> lock(w->mu);
+    CommitCheck *job;
+    for (;;) {
+        // looked up again after every wake: another caller waiting for
+        // the same ticket may have taken it
+        auto it = w->jobs.find(ticket);
+        if (it == w->jobs.end()) return -2;
+        job = it->second;
+        if (job->done) { w->jobs.erase(it); break; }
+        if (!wait) return -3;
+        w->finished.wait(lock);
+    }
+    lock.unlock();
+    int result = job->result;
+    if (seconds != nullptr) *seconds = job->ended - job->began;
+    delete job;
+    return result;
 }
 
 int pc_g1_mul(const uint8_t *in, const uint8_t *scalar, uint8_t *out) {

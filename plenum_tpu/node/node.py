@@ -698,6 +698,11 @@ class Node:
         self.metrics.add_event(MetricsName.BLS_BATCH_FALLBACKS,
                                BATCH_STATS["fallbacks"])
         bls = getattr(self.replicas.master, "bls", None)
+        if bls is not None:
+            self.metrics.add_event(MetricsName.BLS_CHECKS_OFFLOADED,
+                                   bls.stats["offloaded"])
+            self.metrics.add_event(MetricsName.BLS_CHECKS_INLINE,
+                                   bls.stats["inline"])
         bls_stats = getattr(getattr(bls, "_verifier", None), "stats", None)
         if isinstance(bls_stats, dict) and "local_fallbacks" in bls_stats:
             self.metrics.add_event(MetricsName.BLS_LOCAL_FALLBACKS,
@@ -1062,9 +1067,13 @@ class Node:
         # cannot cite a pool-state epoch for rotation-aware validation.
         bls = None
         if inst_id == 0:
-            # with the service plane, the per-batch aggregate pairing is
-            # deduped host-wide (every co-hosted node runs the identical
-            # check); otherwise verify locally — the factory encodes both
+            # with the service plane, the aggregate pairing of a
+            # multi-signature a PRE-PREPARE carries is deduped host-wide
+            # (every co-hosted node runs the identical check); otherwise
+            # verify locally — the factory encodes both. The order-time
+            # check of COMMIT signatures is local behind either
+            # (BlsCryptoVerifier.batch_verify_begin: the BLS library's own
+            # thread, beside this node's loop)
             from plenum_tpu.parallel.crypto_service import \
                 make_bls_verifier
             if (self.c.pipeline is not None
@@ -2415,6 +2424,13 @@ class Node:
                                 MetricsName.EXECUTE_BATCH_TIME):
                             committed_per_msg.append(self._commit_ordered(msg))
                         self._last_executed_pp_seq = msg.pp_seq_no
+                    bls = self.replicas.master.bls
+                    if bls is not None:
+                        # the batches' COMMIT signatures were checked
+                        # beside the commits above: their multi-signatures
+                        # go into the BLS store inside this scope, flushed
+                        # with it before any REPLY
+                        bls.land_ordered((msg.view_no, msg.pp_seq_no))
                 self.metrics.add_event(MetricsName.COMMIT_DURABLE_TIME,
                                        time.perf_counter() - t0)
                 self.metrics.add_event(MetricsName.GROUP_COMMIT_BATCHES,
@@ -2615,6 +2631,13 @@ class Node:
             # a write's time on this node by stage (tracing.StageClock):
             # cumulative count and sum, quantiles since the last flush
             "stages": self.stages.report(),
+            # the order-time checks of COMMIT signatures, cumulative: how
+            # many went to the native library's worker (`offloaded`) or
+            # were settled at the submit (`inline`), the seconds this
+            # node's thread blocked for the worker in the landings
+            # (`join_wait`) and the checks' own (`verify`; `verify_late`
+            # those a late COMMIT asked for)
+            "bls": master.bls.stats if master.bls is not None else None,
             # when a message left and when a frame was seen: frames by
             # who flushed them, the holds in the outbox and in the
             # inbound queue, why the looper ran each cycle (TcpStack.stats,

@@ -84,6 +84,10 @@ SNAPSHOT_SCHEMA: dict[str, frozenset] = {
         MetricsName.STAGE_PROPAGATE_WAIT, MetricsName.STAGE_QUEUE_WAIT,
         MetricsName.STAGE_ORDERING_WAIT, MetricsName.STAGE_COMMIT_WAIT,
         MetricsName.STAGE_REPLY_WAIT, MetricsName.STAGE_RESIDENCE,
+        # the order-time BLS check beside the loop: what the loop still
+        # waited for it, and where the checks went
+        MetricsName.COMMIT_BLS_JOIN_WAIT,
+        MetricsName.BLS_CHECKS_OFFLOADED, MetricsName.BLS_CHECKS_INLINE,
     }),
     "crypto": frozenset({
         MetricsName.BLS_PAIRING_CHECKS,

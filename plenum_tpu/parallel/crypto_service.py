@@ -974,7 +974,13 @@ class ServiceBlsVerifier:
     cache first (repeat checks inside ONE node cost a dict hit, repeat
     checks ACROSS nodes cost one IPC round-trip instead of a 4 ms
     pairing). Everything else (PoP, well-formedness, aggregation)
-    delegates to the local implementation."""
+    delegates to the local implementation, and so does the order-time
+    check of a batch's COMMIT signatures (`batch_verify_begin` /
+    `batch_verify_end`, consensus/bls_bft_replica.py): a round trip to
+    the plane is a wait on a socket, which a node's loop would have to
+    sit out, where the local check runs on the BLS library's own thread
+    beside the loop. What still crosses is what gates a message at once:
+    the multi-signature a PRE-PREPARE carries (`verify_multi_sig`)."""
 
     def __init__(self, socket_path: Optional[str] = None, breaker=None):
         from plenum_tpu.crypto import bls as _bls

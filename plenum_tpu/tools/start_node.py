@@ -383,6 +383,8 @@ def main(argv=None):
         try:
             # capture the tail of the run: gauges + accumulators since the
             # last periodic flush would otherwise die with the process
+            if node.master_replica.bls is not None:
+                node.master_replica.bls.land_all()
             node._sample_transport_stats()
             node._flush_metrics()
         except Exception:

@@ -30,7 +30,15 @@ looper's `wakes` by cause, `busy`, `arrival`, `interval`; null where the
 nodes share a process and on a checkout older than PR 40) and
 `probe_round` (the window's batch cuts on the first validator, from
 VALIDATOR_INFO `batch_controller.cuts`: cuts a second and the ms one 3PC
-round takes while the pool orders one batch at a time). With `--trace 1`
+round takes while the pool orders one batch at a time) and `probe_bls`
+(the first validator's VALIDATOR_INFO `bls` over the window: order-time
+checks of COMMIT signatures that went to the BLS library's worker thread,
+`offloaded`, or were settled at the submit, `inline`; `join_wait`, what
+the node's loop blocked for the worker in a landing, `verify`, the
+checks' own duration, and `verify_late`, those of them a late COMMIT asked
+for, each with count and mean; `hidden_share` = 1 - the
+summed join wait over the summed check time; null on a checkout older
+than PR 48). With `--trace 1`
 it keeps the run's xplane under
 `chiprun_out/probe_trace/` (where it is under 24 MiB) and prints
 `probe_gaps`: each device idle gap of the sample with the host spans
@@ -41,6 +49,7 @@ covers. Nothing of the benchmark is changed: the last line is run.py's own.
 """
 from __future__ import annotations
 
+import copy
 import os
 import sys
 
@@ -108,13 +117,19 @@ def first_validator_info(topo) -> dict:
         node = pool.nodes[topo.names[0]]
         clock = getattr(node, "stages", None)
         ctl = node.batch_controller
+        bls = getattr(node.master_replica, "bls", None)
         return {"stages": clock.report() if clock is not None else None,
                 "batch_controller": ctl.trajectory() if ctl is not None
-                else None}
+                else None,
+                # a snapshot: the record keeps counting
+                "bls": copy.deepcopy(getattr(bls, "stats", None))}
     from benchmarks.tcp_client import ask
     from plenum_tpu.execution.action_manager import VALIDATOR_INFO_ACTION
+    # the failover cell kills the first validator: it reads from the
+    # first survivor, and so does the probe
+    name = getattr(topo, "reads_from", None) or topo.names[0]
     msg = topo.loop.run_until_complete(ask(
-        topo.addrs[topo.names[0]],
+        topo.addrs[name],
         topo._trustee_request({"type": VALIDATOR_INFO_ACTION})))
     return (msg.get("result") or {}).get("data") or {}
 
@@ -199,6 +214,23 @@ def window_round(seen: list, numbers: dict) -> dict | None:
             "cuts_per_s": round(n / seconds, 2) if seconds else None,
             "round_ms": round(seconds / n * 1e3, 2) if seconds and n
             else None}
+
+
+def window_bls(seen: list) -> dict | None:
+    """The order-time BLS checks over the window, and the share of their
+    time that ran beside the node's loop."""
+    if len(seen) < 2 or not seen[0] or not seen[1]:
+        return None
+    start, end = seen
+    times = grown([{k: r[k] for k in ("join_wait", "verify", "verify_late")}
+                   for r in seen])
+    waited = end["join_wait"]["sum_s"] - start["join_wait"]["sum_s"]
+    checked = end["verify"]["sum_s"] - start["verify"]["sum_s"]
+    return dict(times,
+                offloaded=end["offloaded"] - start["offloaded"],
+                inline=end["inline"] - start["inline"],
+                hidden_share=round(1 - waited / checked, 4)
+                if checked else None)
 
 
 HOST_SPANS = ("prod.", "ring.", "svc.")
@@ -317,6 +349,7 @@ def as_cell() -> int:
                  probe_transport=window_transport(
                      [info.get("transport") for info in infos]),
                  probe_round=window_round(infos, obs["numbers"]),
+                 probe_bls=window_bls([info.get("bls") for info in infos]),
                  probe_service_waits=dict(
                      grown(waits) or {}, since_pin=waits[1]) if len(
                      waits) > 1 and waits[1] else None)

@@ -298,3 +298,158 @@ def test_order_time_all_honest_single_check():
         "expected ONE combined pairing check for the whole COMMIT set"
     assert c.PAIRING_STATS["pairings"] - before["pairings"] == 2, \
         "same-message batch must cost 2 pairings regardless of n"
+
+
+# --- the order-time check runs beside the caller (PR 48) -------------------
+#
+# `submit_order` begins the check of a batch's COMMIT signatures on the
+# native library's worker; a landing takes the verdicts and does what
+# `process_order` used to do in line. Whatever the arrival order, and
+# wherever the landings fall, the replica ends where the in-line call
+# would have left it.
+
+def _order_time_replica(store_rows, reported):
+    from plenum_tpu.common.quorums import Quorums
+    from plenum_tpu.consensus.bls_bft_replica import (BlsBftReplica,
+                                                      BlsKeyRegister)
+
+    class Rows:
+        def put(self, ms):
+            store_rows.append((ms.value.state_root_hash, ms.participants,
+                               ms.signature))
+
+    signers = {n: BlsCryptoSigner(seed=n.encode().ljust(32, b"\0"))
+               for n in "ABCD"}
+    replica = BlsBftReplica(
+        node_name="A", bls_signer=signers["A"],
+        bls_verifier=BlsCryptoVerifier(),
+        key_register=BlsKeyRegister({n: s.pk for n, s in signers.items()}),
+        bls_store=Rows(), quorums=Quorums(4))
+    replica.report_bad_signature = reported.append
+    return replica, signers
+
+
+def _arrival_scenario(seed):
+    """-> (events, forged): the four COMMITs of one batch in a seeded
+    order, `("order",)` where the 3PC quorum falls (after two of them:
+    the check's own quorum is missed and reached later; after three; after
+    all four), at times a second order of the same key, and at most one
+    signer that signed another value."""
+    import random
+    rng = random.Random(seed)
+    names = list("ABCD")
+    rng.shuffle(names)
+    forged = rng.choice(names + [None, None])
+    events = [("commit", n) for n in names]
+    events.insert(rng.choice([2, 3, 3, 4]), ("order",))
+    if rng.random() < 0.4:
+        events.insert(rng.randrange(events.index(("order",)) + 1,
+                                    len(events) + 1), ("order",))
+    return events, forged
+
+
+def _drive(seed, deferred: bool):
+    import random
+    from plenum_tpu.common.node_messages import Commit, PrePrepare
+    bls_mod._BLS_VERDICTS.clear()      # both drives check the same bytes
+    rows, reported = [], []
+    replica, signers = _order_time_replica(rows, reported)
+    events, forged = _arrival_scenario(seed)
+    pp = PrePrepare(inst_id=0, view_no=0, pp_seq_no=1, pp_time=1.0,
+                    req_idr=(), discarded=(), digest="d", ledger_id=1,
+                    state_root=f"aa{seed}", txn_root="cc",
+                    pool_state_root="bb")
+    value = replica._signed_value(pp).as_single_value()
+    polls = random.Random(seed * 7 + 1)
+    for event in events:
+        if event == ("order",):
+            replica.submit_order((0, 1), pp)
+        else:
+            signed = b"something else" if event[1] == forged else value
+            replica.process_commit(Commit(
+                inst_id=0, view_no=0, pp_seq_no=1,
+                bls_sig=signers[event[1]].sign(signed)), event[1])
+        if not deferred:
+            replica.land_all()          # the in-line call of before
+        elif polls.random() < 0.3:
+            replica.land_done()         # a later cycle's poll, maybe
+    replica.land_all()
+    return {"multi_sig": replica._recent_multi_sigs.get(pp.state_root),
+            "known_bad": replica._known_bad, "reported": reported,
+            "store_rows": rows, "pending": set(replica._pending_order),
+            "aggregated": {k: v[1] for k, v in replica._aggregated.items()}}
+
+
+@pairing_heavy
+@pytest.mark.parametrize("seed", range(24))
+def test_submit_then_land_leaves_what_the_inline_call_left(seed):
+    inline = _drive(seed, deferred=False)
+    assert _drive(seed, deferred=True) == inline
+    events, forged = _arrival_scenario(seed)
+    assert inline["reported"] == ([forged] if forged else [])
+    assert inline["multi_sig"] is not None
+    assert set(inline["multi_sig"].participants) == set("ABCD") - {forged}
+
+
+@pairing_heavy
+def test_two_cohosted_replicas_asking_for_one_check_make_one_native_call():
+    """Nodes of one process reach the same quorum: the second asker finds
+    the first's check with the worker and waits for that one."""
+    from plenum_tpu.common.node_messages import Commit, PrePrepare
+    pp = PrePrepare(inst_id=0, view_no=0, pp_seq_no=1, pp_time=1.0,
+                    req_idr=(), discarded=(), digest="d", ledger_id=1,
+                    state_root="a-cohosted", txn_root="c-cohosted",
+                    pool_state_root="b-cohosted")
+    first, signers = _order_time_replica([], [])
+    second, _ = _order_time_replica([], [])
+    value = first._signed_value(pp).as_single_value()
+    for replica in (first, second):
+        for n in "ABC":
+            replica.process_commit(Commit(
+                inst_id=0, view_no=0, pp_seq_no=1,
+                bls_sig=signers[n].sign(value)), n)
+    before = dict(bls_mod.BATCH_STATS), dict(c.PAIRING_STATS)
+    first.submit_order((0, 1), pp)
+    second.submit_order((0, 1), pp)
+    assert bls_mod.BATCH_STATS["offloaded"] - before[0]["offloaded"] == \
+        (1 if c._NATIVE is not None else 0)
+    assert c.PAIRING_STATS["checks"] - before[1]["checks"] == 1
+    # in the other order too: a landing holds nothing another node's needs
+    assert second.land((0, 1)) == first.land((0, 1)) is not None
+    assert first.stats["offloaded"] == second.stats["offloaded"] == \
+        (1 if c._NATIVE is not None else 0)
+
+
+@pairing_heavy
+def test_late_commit_behind_a_check_in_flight_checks_only_its_own_signature():
+    """A fourth COMMIT that arrives while the quorum's check is still with
+    the worker asks again behind it, and the three signatures being
+    checked are not checked a second time."""
+    from plenum_tpu.common.node_messages import Commit, PrePrepare
+    pp = PrePrepare(inst_id=0, view_no=0, pp_seq_no=1, pp_time=1.0,
+                    req_idr=(), discarded=(), digest="d", ledger_id=1,
+                    state_root="a-late", txn_root="c-late",
+                    pool_state_root="b-late")
+    rows: list = []
+    replica, signers = _order_time_replica(rows, [])
+    value = replica._signed_value(pp).as_single_value()
+
+    def commit(n):
+        replica.process_commit(Commit(inst_id=0, view_no=0, pp_seq_no=1,
+                                      bls_sig=signers[n].sign(value)), n)
+    for n in "ABC":
+        commit(n)
+    replica.submit_order((0, 1), pp)
+    commit("D")
+    assert [(s.names, s.late) for s in replica._submitted] == \
+        [(["A", "B", "C"], False), (["A", "B", "C", "D"], True)]
+    if c._NATIVE is not None:
+        quorum, late = (s.check for s in replica._submitted)
+        assert [len(f.keys) for f in late.flights] == [3, 1]
+        assert late.flights[0] is quorum.flights[0]
+    # the group commit's landing takes the quorum's check and leaves the
+    # upgrade, which nothing waits for
+    replica.land_ordered((0, 1))
+    assert [r[1] for r in rows] == [("A", "B", "C")]
+    replica.land_all()
+    assert [r[1] for r in rows] == [("A", "B", "C"), ("A", "B", "C", "D")]

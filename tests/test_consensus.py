@@ -65,13 +65,18 @@ class PoolSim:
         for name in (to or self.names):
             self.replicas[name].internal_bus.send(ReqKey(req.digest))
 
-    def run(self, seconds=5.0, step=0.25):
+    def run(self, seconds=5.0, step=0.25, land=True):
         elapsed = 0.0
         while elapsed < seconds:
             for r in self.replicas.values():
                 r.service()
             self.timer.advance(step)
             elapsed += step
+        # a node lands its BLS checks when it closes a batch's group
+        # commit (Node._service_ordered); this pool has replicas only
+        for r in self.replicas.values():
+            if land and r.bls is not None:
+                r.bls.land_all()
 
     def primary_name(self):
         return self.replicas[self.names[0]].data.primaries[0]
@@ -282,3 +287,102 @@ def test_bls_multi_sig_collected_on_order():
     pool.run(3.0)
     pp = pool.replicas["Beta"].ordering.prePrepares[(0, 2)]
     assert pp.bls_multi_sig is not None
+
+
+# --- the order-time BLS check lands where its result is read (PR 48) -------
+
+def test_next_pre_prepare_carries_the_batchs_multi_sig_on_the_primary():
+    """The check of batch k is begun at its order and nothing of this
+    pool lands it (no node, no group commit): building PRE-PREPARE k+1
+    does, and carries k's multi-signature as it always did."""
+    from plenum_tpu.crypto.multi_signature import MultiSignature
+    pool = PoolSim(with_bls=True)
+    primary = pool.replicas[pool.primary_name().rsplit(":", 1)[0]]
+    for i in range(3):
+        pool.finalize_request(make_request(i))
+        pool.run(3.0, land=False)
+    sent = primary.ordering.sent_preprepares
+    assert sorted(sent) == [(0, 1), (0, 2), (0, 3)]
+    assert sent[(0, 1)].bls_multi_sig is None
+    for k in (1, 2):
+        ms = MultiSignature.from_list(list(sent[(0, k + 1)].bls_multi_sig))
+        assert ms.value.state_root_hash == sent[(0, k)].state_root
+        assert len(ms.participants) >= 3
+        assert ms == primary.bls._recent_multi_sigs[sent[(0, k)].state_root] \
+            or len(ms.participants) == 3    # upgraded by the fourth since
+
+
+def _node_pool_run(seed):
+    """Five writes through a four-node pool on the sim network -> what
+    each node ordered, sent and stored."""
+    from test_pool import Pool, signed_nym
+    from plenum_tpu.crypto.ed25519 import Ed25519Signer
+    pool = Pool(seed=seed, tracing=False)
+    for i in range(5):
+        user = Ed25519Signer(seed=f"landing-{i}".encode().ljust(32, b"\0"))
+        pool.submit(signed_nym(pool.trustee, user, 100 + i))
+        pool.run(1.0)
+    pool.run(3.0)
+    out = {}
+    for name, node in pool.nodes.items():
+        node.master_replica.bls.land_all()
+        ordering = node.master_replica.ordering
+        out[name] = (
+            [(key, pp.digest, pp.bls_multi_sig)
+             for key, pp in sorted(ordering.prePrepares.items())],
+            sorted(ordering.ordered),
+            sorted((k, v) for k, v in node.c.bls_store.kv.iterator()))
+    return out
+
+
+def test_sim_pool_run_twice_on_one_seed_orders_identically():
+    """Landing is positional: what a PRE-PREPARE carries and what the BLS
+    store ends up holding do not depend on how fast the worker was."""
+    first = _node_pool_run(seed=4848)
+    assert first == _node_pool_run(seed=4848)
+    pps, ordered, rows = first["Alpha"]
+    assert len(ordered) == 5 and len(rows) == 5
+    assert sum(1 for _, _, ms in pps if ms is not None) == 4
+
+
+def test_multi_sig_is_put_inside_the_group_commit_scope_before_the_reply():
+    """`Node._service_ordered` lands the batch's check after its commit
+    and before the scope closes: the BLS store's row is flushed with the
+    batch, and the REPLY follows the flush. (A check that is done when
+    the cycle starts lands there, outside any scope and flushed at once;
+    the poll is held off here so that the scope's landing is what runs.)"""
+    from contextlib import contextmanager
+    from test_pool import Pool, signed_nym
+    from plenum_tpu.common.node_messages import Reply
+    from plenum_tpu.crypto.ed25519 import Ed25519Signer
+    pool = Pool(tracing=False)
+    seen = {name: [] for name in pool.names}
+    for name, node in pool.nodes.items():
+        kv, events = node.c.bls_store.kv, seen[name]
+        scope, put = kv.write_batch, kv.put
+        node.master_replica.bls.land_done = lambda: None
+
+        @contextmanager
+        def recorded_scope(scope=scope, events=events):
+            events.append("open")
+            with scope():
+                yield
+            events.append("close")
+        kv.write_batch = recorded_scope
+        # a scope inside a scope joins it: the depth says whether one is open
+        kv.put = lambda k, v, put=put, events=events: (events.append((
+            "put", events.count("open") - events.count("close"))),
+            put(k, v))[1]
+        node._client_send = lambda msg, client, send=node._client_send, \
+            events=events: (isinstance(msg, Reply) and events.append("reply"),
+                            send(msg, client))[1]
+    user = Ed25519Signer(seed=b"in-scope".ljust(32, b"\0"))
+    pool.submit(signed_nym(pool.trustee, user, 7))
+    pool.run(3.0)
+    for name in pool.names:
+        events = seen[name]
+        puts = [e for e in events if e[0] == "put"]
+        assert puts and puts[0] == ("put", 1), (name, events)
+        closed = max(i for i, e in enumerate(events[:events.index("reply")])
+                     if e == "close")
+        assert events.index(puts[0]) < closed, (name, events)

@@ -365,3 +365,47 @@ def test_storage_counters_and_flush_time_on_durable_stores_only(tmp_path):
     assert info["storage"] is None and info["recovery"] is None
     assert "storage.flush_time" not in memory.nodes[
         "Alpha"].metrics.accumulators
+
+
+def test_pool_killed_at_the_second_matching_reply_restores_the_multi_sig(
+        tmp_path):
+    """The BLS check of a batch runs beside the loop (PR 48) and its
+    multi-signature is stored where the check lands: inside the batch's
+    group commit at the latest, so before any REPLY. All four killed the
+    instant a client holds two matching REPLYs for the last write, no
+    cycle run to its end: every validator that answered serves proofs at
+    that batch's root from its own BLS store, before anything is ordered
+    or caught up again."""
+    from plenum_tpu.common.node_messages import Reply
+    pool = _pool(tmp_path)
+    _order(pool, "kill-1", 1)
+    last = signed_nym(pool.trustee, _user("kill-2"), 2)
+    pool.submit(last)
+
+    def answered():             # one REPLY a write a node
+        return [n for n in pool.names if len(pool.replies(n, Reply)) == 2]
+    for _ in range(200):
+        for node in list(pool.nodes.values()):
+            node.prod()
+            if len(answered()) >= 2:
+                break
+        if len(answered()) >= 2:
+            break
+        pool.timer.advance(0.1)
+    acked = answered()
+    assert len(acked) >= 2
+    roots = {n: pool.nodes[n].c.db.get_state(
+        DOMAIN_LEDGER_ID).committed_head_hash for n in acked}
+    assert len(set(roots.values())) == 1
+    _crash_all(pool)
+    for name in pool.names:
+        pool.start_node(name)           # no prod, no rejoin: what is on disk
+    for name in acked:
+        node = pool.nodes[name]
+        assert node.c.db.get_state(
+            DOMAIN_LEDGER_ID).committed_head_hash == roots[name]
+        ms = node.c.bls_store.get(roots[name].hex())
+        assert ms is not None and len(ms.participants) >= 3, name
+        anchor = node.read_plane.anchor_for(DOMAIN_LEDGER_ID)
+        assert anchor is not None \
+            and anchor.state_root_hex == roots[name].hex(), name

@@ -426,7 +426,7 @@ def recertified(tmp_path_factory):
         _spy(node.c.bls_signer, "sign", signed[n])
         _spy(node.c.bls_store, "put", stored[n],
              key=lambda ms: ms.value.state_root_hash)
-        _spy(node.master_replica.bls, "process_order", aggregated[n],
+        _spy(node.master_replica.bls, "submit_order", aggregated[n],
              key=lambda key, pp: key)
     roots = {pp.pp_seq_no: pp.state_root for pp in pool.nodes[
         "Beta"].master_replica.ordering.prePrepares.values()}
@@ -604,7 +604,8 @@ def test_a_re_certified_batch_reaches_process_order_only_where_it_is_new(
     """A batch at or below a survivor's `last_ordered` has its new-view
     quorum parked by the in-order rule and never comes to `_order` again:
     no aggregate, no store `put`, nothing to skip. Only Delta, which had
-    not ordered batches 5 and 6, runs `process_order`, for those two."""
+    not ordered batches 5 and 6, runs the order-time check (`submit_order`,
+    which `_order` calls since PR 48), for those two."""
     pool, survivors, seen = recertified
     assert seen["aggregated"] == {"Beta": [], "Gamma": [],
                                   "Delta": [(1, 5), (1, 6)]}
