@@ -235,6 +235,17 @@ class MetricsName:
     VC_VOTE_TO_START = "consensus.vc_vote_to_start"
     VC_START_TO_NEW_VIEW = "consensus.vc_start_to_new_view"
     VC_NEW_VIEW_TO_ORDER = "consensus.vc_new_view_to_order"
+    # that last phase on the master's ordering service, unlatched
+    # (OrderingService.vc_episode; one event each when the phase closes
+    # on the first FRESH batch ordered): NEW_VIEW accepted -> the last
+    # cited batch re-sent / processed -> the first fresh PRE-PREPARE built
+    # / applied -> that batch ordered; fresh_order is their sum; and the
+    # seconds the node blocked landing BLS checks meanwhile
+    VC_RECERTIFY = "consensus.vc_recertify"
+    VC_FIRST_CUT = "consensus.vc_first_cut"
+    VC_FIRST_ROUND = "consensus.vc_first_round"
+    VC_FRESH_ORDER = "consensus.vc_fresh_order"
+    VC_BLS_JOIN_WAIT = "consensus.vc_bls_join_wait"
     # churn/WAN robustness (sampled -> p50/p95 in metrics_report):
     # whole-episode view-change duration (first stamp -> first post-VC
     # master order) and whole-round catchup duration (start -> complete),

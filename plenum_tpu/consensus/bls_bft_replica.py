@@ -158,11 +158,20 @@ class BlsBftReplica:
         # landings, `verify` the checks' own seconds, `verify_late`
         # those of them that a late COMMIT asked for (one fresh signature
         # where the quorum's check has three: the two modes of
-        # `commit_path.bls_verify_time`)
+        # `commit_path.bls_verify_time`). `ppr_multi_sig`: what became of
+        # the multi-signatures the PRE-PREPAREs this node validated
+        # carried: `known` were answered from `_verified_ms_keys` (this
+        # node aggregated or checked the same one before), `paired` went
+        # to the verifier (`paired_s` its seconds, a served verifier's
+        # round trip included), and `joined_s` the seconds
+        # `multi_sig_holds` first blocked landing this node's own check
+        # of the same root
         self.stats = {"offloaded": 0, "inline": 0,
                       "join_wait": {"count": 0, "sum_s": 0.0},
                       "verify": {"count": 0, "sum_s": 0.0},
-                      "verify_late": {"count": 0, "sum_s": 0.0}}
+                      "verify_late": {"count": 0, "sum_s": 0.0},
+                      "ppr_multi_sig": {"known": 0, "paired": 0,
+                                        "paired_s": 0.0, "joined_s": 0.0}}
 
     def set_quorums(self, quorums: Quorums) -> None:
         self._quorums = quorums
@@ -195,14 +204,18 @@ class BlsBftReplica:
             ms = MultiSignature.from_list(list(pre_prepare.bls_multi_sig))
         except (ValueError, TypeError, IndexError, KeyError):
             return self.PPR_BLS_MULTISIG_WRONG
-        return None if self.multi_sig_holds(ms, pre_prepare.pp_seq_no) \
+        return None if self.multi_sig_holds(
+            ms, pre_prepare.pp_seq_no, self.stats["ppr_multi_sig"]) \
             else self.PPR_BLS_MULTISIG_WRONG
 
-    def multi_sig_holds(self, ms: MultiSignature, seq: int = 0) -> bool:
+    def multi_sig_holds(self, ms: MultiSignature, seq: int = 0,
+                        tally: Optional[dict] = None) -> bool:
         """Whether `ms` is a quorum multi-signature over its value, by the
         keys and the quorum of the pool state it cites. `seq`: the
         pp_seq_no of the PRE-PREPARE that carries it; the verdict is
-        remembered until a checkpoint at or past it is stable."""
+        remembered until a checkpoint at or past it is stable. `tally`:
+        the `ppr_multi_sig` record of `stats`, where the caller's
+        multi-signatures are counted (a PRE-PREPARE's are)."""
         # Participants must be DISTINCT registered validators: aggregation is
         # plain point addition, so one colluding node's signature repeated
         # n-f times would otherwise verify as a quorum multi-sig (rogue
@@ -213,7 +226,10 @@ class BlsBftReplica:
         # worker, may be about to aggregate this very multi-signature
         for sub in self._submitted:
             if sub.pre_prepare.state_root == ms.value.state_root_hash:
+                t0 = time.perf_counter()
                 self.land(sub.key)
+                if tally is not None:
+                    tally["joined_s"] += time.perf_counter() - t0
                 break
         # A multi-sig we aggregated (or fully verified) OURSELVES passed the
         # quorum rules in force when it was created. This shortcut must come
@@ -225,6 +241,8 @@ class BlsBftReplica:
         ms_key = self._ms_key(ms)
         if ms_key in self._verified_ms_keys:
             self._remember_verified(ms_key, seq)
+            if tally is not None:
+                tally["known"] += 1
             return True
         # keys AND quorum AS OF the sig's cited pool state — the same
         # epoch resolution process_order aggregates under, so an honest
@@ -239,10 +257,14 @@ class BlsBftReplica:
             return False
         if not quorums.bls_signatures.is_reached(len(ms.participants)):
             return False
+        t0 = time.perf_counter()
         ok = self._verifier.verify_multi_sig(ms.signature,
                                              ms.value.as_single_value(),
                                              [vk_of[n] for n in
                                               ms.participants])
+        if tally is not None:
+            tally["paired"] += 1
+            tally["paired_s"] += time.perf_counter() - t0
         self._drop_stale_points(vk_of)
         if ok:
             self._remember_verified(ms_key, seq)
@@ -474,6 +496,25 @@ class BlsBftReplica:
     def _note(record: dict, seconds: float) -> None:
         record["count"] += 1
         record["sum_s"] += seconds
+
+    @property
+    def depth(self) -> int:
+        """Checks submitted and not landed."""
+        return len(self._submitted)
+
+    def tally(self) -> dict:
+        """`stats` as flat numbers whose differences say what this replica
+        did over an interval (a view change's last phase:
+        OrderingService.vc_episode `bls`)."""
+        st, ppr = self.stats, self.stats["ppr_multi_sig"]
+        return {"submitted": st["offloaded"] + st["inline"],
+                "offloaded": st["offloaded"], "inline": st["inline"],
+                "landings": st["join_wait"]["count"],
+                "join_wait_ms": st["join_wait"]["sum_s"] * 1e3,
+                "verify_ms": st["verify"]["sum_s"] * 1e3,
+                "ppr_known": ppr["known"], "ppr_paired": ppr["paired"],
+                "ppr_paired_ms": ppr["paired_s"] * 1e3,
+                "ppr_joined_ms": ppr["joined_s"] * 1e3}
 
     def _epoch_of(self, pool_root: str):
         """-> (key_of, reg, quorums) AS OF `pool_root` — the epoch a

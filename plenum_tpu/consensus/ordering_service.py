@@ -23,6 +23,7 @@ from plenum_tpu.common.event_bus import ExternalBus, InternalBus
 from plenum_tpu.common.metrics import MetricsName
 from plenum_tpu.common.internal_messages import (MissingMessage,
                                                  NeedMasterCatchup,
+                                                 NewViewAccepted,
                                                  NewViewCheckpointsApplied,
                                                  RaisedSuspicion, ReqKey,
                                                  RequestPropagates,
@@ -44,6 +45,23 @@ from .batch_executor import AppliedBatch, BatchExecutor
 from .batch_id import BatchID
 from .bls_bft_replica import BlsBftReplica
 from .consensus_shared_data import ConsensusSharedData
+
+
+# A view change's last phase on one node, each step in ms since the node
+# accepted the NEW_VIEW (OrderingService.vc_episode): the last cited batch
+# re-sent (primary) / processed (validator); the first FRESH PRE-PREPARE
+# cut / received, then built and sent / applied; prepare and commit quorum
+# of the first batch this node orders, of either kind; the first fresh
+# batch ordered, which closes the phase.
+VC_STEPS = ("recertified_ms", "first_cut_ms", "first_cut_apply_ms",
+            "first_prepared_ms", "first_ordered_ms", "fresh_ordered_ms")
+# the phase as three spans that add up to the fourth, on the metrics store
+# at its close: (event, from step or NEW_VIEW accepted, to step)
+VC_STEP_METRICS = (
+    (MetricsName.VC_RECERTIFY, None, "recertified_ms"),
+    (MetricsName.VC_FIRST_CUT, "recertified_ms", "first_cut_apply_ms"),
+    (MetricsName.VC_FIRST_ROUND, "first_cut_apply_ms", "fresh_ordered_ms"),
+    (MetricsName.VC_FRESH_ORDER, None, "fresh_ordered_ms"))
 
 
 def _orig_view(pp: PrePrepare) -> int:
@@ -142,6 +160,9 @@ class OrderingService:
 
         bus.subscribe(ReqKey, self.process_req_key)
         bus.subscribe(ViewChangeStarted, self.process_view_change_started)
+        # before the replica's own handler, which re-orders the cited
+        # batches from inside its dispatch: the episode's clock starts here
+        bus.subscribe(NewViewAccepted, self._vc_new_view_accepted)
         bus.subscribe(NewViewCheckpointsApplied,
                       self.process_new_view_checkpoints_applied)
 
@@ -166,9 +187,21 @@ class OrderingService:
         # was cut (primary) or accepted (the others). `span` is the
         # node's host-span helper (node.py _phase) where one was handed
         # in; both are touched by a view change and by nothing else.
+        # The episode also says what happened between NEW_VIEW accepted
+        # and the first FRESH batch ordered, step by step (`VC_STEPS`, ms
+        # since this node accepted the NEW_VIEW on `time.perf_counter()`:
+        # the node's timer is latched once a prod cycle), and what the BLS
+        # replica did meanwhile (`bls`); docs/consensus.md has each field.
         self.vc_episode: Optional[dict] = None
         self._first_cut_due = False
         self.span = tracing.unspanned
+        # perf_counter at NEW_VIEW accepted while that phase is open, None
+        # outside it: every site below is behind this one check
+        self._vc_t0: Optional[float] = None
+        self._vc_cycle_at = 0.0         # the last service() inside it
+        self._vc_last_cited: Optional[int] = None
+        self._vc_prepared_ms: dict[tuple[int, int], float] = {}
+        self._vc_bls0: Optional[dict] = None
 
     def stop(self) -> None:
         """Detach from the shared network bus (replica removal): a removed
@@ -216,6 +249,9 @@ class OrderingService:
 
     def service(self) -> None:
         """Called each prod cycle: primaries turn queued requests into batches."""
+        if self._vc_t0 is not None:
+            self.vc_episode["cycles"] += 1
+            self._vc_cycle_end()
         if self._bls is not None:
             # what an earlier cycle left with the BLS worker and is done
             # by now (a late COMMIT's re-run: nothing waits for it)
@@ -322,6 +358,7 @@ class OrderingService:
                     self._note_first_cut(len(digests) + len(queue))
                     self.span("vc.first_cut", lambda: self._send_one_batch(
                         lid, digests, queue_wait=queue_wait, reason=reason))
+                    self._vc_step("first_cut_apply_ms")
                 else:
                     self._send_one_batch(lid, digests, queue_wait=queue_wait,
                                          reason=reason)
@@ -333,6 +370,86 @@ class OrderingService:
     def _note_first_cut(self, waiting: int) -> None:
         self._first_cut_due = False
         self.vc_episode["waiting_at_first_cut"] = waiting
+        self._vc_step("first_cut_ms")
+
+    # --- the view change's last phase, step by step --------------------- #
+
+    def _vc_new_view_accepted(self, _msg: NewViewAccepted) -> None:
+        if not self._first_cut_due:
+            return      # no episode open (a backup instance, or a replay)
+        self._vc_t0 = self._vc_cycle_at = time.perf_counter()
+        self._vc_last_cited = None
+        self._vc_prepared_ms.clear()
+        if self._bls is not None:
+            self._vc_bls0 = self._bls.tally()
+            self.vc_episode["bls"]["depth_at_new_view"] = self._bls.depth
+
+    def _vc_ms(self) -> float:
+        return round((time.perf_counter() - self._vc_t0) * 1e3, 3)
+
+    def _vc_step(self, step: str) -> None:
+        """Stamp one of `VC_STEPS`, once an episode."""
+        if self._vc_t0 is not None and self.vc_episode[step] is None:
+            self.vc_episode[step] = self._vc_ms()
+
+    def _vc_cycle_end(self) -> None:
+        """A turn of the node's loop inside the phase ends here (at a
+        `service()` call, or with the phase): the span since the last one
+        is the timers, both stacks' drains (where the 3PC handlers run),
+        Node.prod and what an idle looper waited."""
+        now = time.perf_counter()
+        self.vc_episode["longest_cycle_ms"] = max(
+            self.vc_episode["longest_cycle_ms"],
+            round((now - self._vc_cycle_at) * 1e3, 3))
+        self._vc_cycle_at = now
+
+    def _vc_note_cited(self, reapplied: bool) -> None:
+        """A cited batch re-sent (primary) or processed (validator) inside
+        the phase: applied again, or voted on without executing it (this
+        node had ordered it: its new-view quorum is parked by the in-order
+        rule and never comes to `_order`)."""
+        if self._vc_t0 is not None:
+            self.vc_episode["cited_reapplied" if reapplied
+                            else "cited_recertified_only"] += 1
+
+    def _vc_note_ordered(self, key: tuple[int, int], pp: PrePrepare) -> None:
+        """An `Ordered` is about to leave `_order` inside the phase: stamp
+        the first batch this node orders, of either kind, and close the
+        phase on the first fresh one."""
+        episode = self.vc_episode
+        cited = _orig_view(pp) != pp.view_no
+        if cited:
+            episode["cited_ordered"] += 1
+        if episode["first_ordered_ms"] is None:
+            episode["first_prepared_ms"] = self._vc_prepared_ms.get(key)
+            self._vc_step("first_ordered_ms")
+            episode["first_ordered_kind"] = \
+                "recertified" if cited else "fresh"
+            episode["first_ordered_pp_seq_no"] = key[1]
+            episode["first_ordered_requests"] = len(pp.req_idr)
+        if cited:
+            return
+        # the first FRESH batch ordered: what a waiting client feels
+        episode["fresh_ordered_ms"] = \
+            episode["first_ordered_ms"] \
+            if episode["first_ordered_kind"] == "fresh" else self._vc_ms()
+        self._vc_cycle_end()
+        if self._vc_bls0 is not None:
+            after = self._bls.tally()
+            episode["bls"].update({k: round(after[k] - before, 3)
+                                   for k, before in self._vc_bls0.items()})
+        if self._metrics is not None:
+            for metric, frm, to in VC_STEP_METRICS:
+                ends = (episode[frm] if frm else 0.0, episode[to])
+                if None not in ends:
+                    self._metrics.add_event(metric,
+                                            (ends[1] - ends[0]) / 1e3)
+            if self._vc_bls0 is not None:
+                self._metrics.add_event(
+                    MetricsName.VC_BLS_JOIN_WAIT,
+                    episode["bls"]["join_wait_ms"] / 1e3)
+        self._vc_t0 = self._vc_bls0 = None
+        self._vc_prepared_ms.clear()
 
     def _cut_reason(self, queue: OrderedDict, now: float, max_size: int,
                     max_wait: float, force_empty: bool) -> Optional[str]:
@@ -672,6 +789,13 @@ class OrderingService:
                 else:
                     self._bls.process_commit(commit, voter)
         self._send_prepare(msg)
+        if self._vc_t0 is not None:
+            if _orig_view(msg) == msg.view_no:
+                self._vc_step("first_cut_apply_ms")
+            else:
+                self._vc_note_cited(reapplied=not rerun)
+                if msg.pp_seq_no == self._vc_last_cited:
+                    self._vc_step("recertified_ms")
         # A stashed future pre-prepare may now be consecutive.
         self._stasher.process_all_stashed(StashReason.FUTURE_3PC)
         self._try_prepare_quorum(key)
@@ -726,6 +850,8 @@ class OrderingService:
             return
         self._data.prepare_batch(BatchID(pp.view_no, _orig_view(pp),
                                          pp.pp_seq_no, pp.digest))
+        if self._vc_t0 is not None:
+            self._vc_prepared_ms[key] = self._vc_ms()
         ts = self._phase_ts.get(key)
         if ts is not None and ts[1] is None:
             ts[1] = self._timer.get_current_time()
@@ -1017,6 +1143,8 @@ class OrderingService:
             # already executed under its original view: this pass only
             # re-certified the batch into the new view's 3PC chain
             return
+        if self._vc_t0 is not None:
+            self._vc_note_ordered(key, pp)
         discarded_set = set(pp.discarded)
         ordered = Ordered(inst_id=pp.inst_id, view_no=key[0],
                           pp_seq_no=key[1], pp_time=pp.pp_time,
@@ -1193,10 +1321,13 @@ class OrderingService:
         pre-prepares for possible re-ordering (ref :2380)."""
         self._phase_ts.clear()      # timings don't span views
         self._cut_ts.clear()        # controller spans don't span views
+        t0 = time.perf_counter()
         if self._bls is not None:
             self._bls.land_all()
+        start_join_ms = round((time.perf_counter() - t0) * 1e3, 3)
         reverted = self.span("vc.revert_batches",
                              self.revert_unordered_batches)
+        self._vc_t0 = None
         if self._data.is_master:
             self.vc_episode = {"view_no": msg.view_no,
                                "reverted_batches": reverted,
@@ -1205,7 +1336,21 @@ class OrderingService:
                                # of the re-ordered batches: COMMITs sent
                                # with the signature kept from the earlier
                                # view / with a new one
-                               "bls_sigs_reused": 0, "bls_sigs_fresh": 0}
+                               "bls_sigs_reused": 0, "bls_sigs_fresh": 0,
+                               # and what ordering each meant on this
+                               # node: applied again (it was prepared and
+                               # not ordered here), `Ordered` emitted, or
+                               # only certified into the new view
+                               "cited_reapplied": 0, "cited_ordered": 0,
+                               "cited_recertified_only": 0,
+                               **dict.fromkeys(VC_STEPS),
+                               "first_ordered_kind": None,
+                               "first_ordered_pp_seq_no": None,
+                               "first_ordered_requests": None,
+                               "cycles": 0, "longest_cycle_ms": 0.0,
+                               # the checks still with the worker when
+                               # the view change started, landed above
+                               "bls": {"start_join_ms": start_join_ms}}
             self._first_cut_due = True
         # ALL pre-prepares (ordered ones too) become old-view material: a
         # NewView may cite an already-ordered batch, and both the re-sending
@@ -1297,6 +1442,8 @@ class OrderingService:
                     inst_id=self._data.inst_id, dst=None))
                 old_pp = None
             todo.append((orig_view, pp_seq_no, digest, old_pp))
+        if self._vc_t0 is not None and todo:
+            self._vc_last_cited = todo[-1][1]
         # Pass 2: re-send/apply STRICTLY in seq order, stopping at the first
         # still-missing batch — each reply re-enters this method, and
         # applying whatever happened to be available produced out-of-order
@@ -1370,11 +1517,17 @@ class OrderingService:
                          BatchID(self._data.view_no, orig_view, pp_seq_no, digest)))
                 self._data.preprepare_batch(
                     BatchID(self._data.view_no, orig_view, pp_seq_no, digest))
+                self._vc_note_cited(reapplied=not rerun)
                 self._network.send(new_pp)
             else:
                 # Non-primaries re-admit the batch through the normal path when
                 # the primary's re-sent PRE-PREPARE arrives; nothing to do now.
                 self._data.pp_seq_no = max(self._data.pp_seq_no, pp_seq_no)
+        else:
+            # the pass ran to its end: a primary has re-sent the last cited
+            # batch; a validator that was cited none has nothing to wait for
+            if self.is_primary or self._vc_last_cited is None:
+                self._vc_step("recertified_ms")
         self._stasher.process_all_stashed(StashReason.WAITING_FOR_NEW_VIEW)
         self._stasher.process_all_stashed(StashReason.FUTURE_VIEW)
 

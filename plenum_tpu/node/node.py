@@ -2631,12 +2631,17 @@ class Node:
             # a write's time on this node by stage (tracing.StageClock):
             # cumulative count and sum, quantiles since the last flush
             "stages": self.stages.report(),
+            # requests queued for ordering after this node had executed
+            # them (Propagator.stats), cumulative
+            "propagation": self.propagator.stats,
             # the order-time checks of COMMIT signatures, cumulative: how
             # many went to the native library's worker (`offloaded`) or
             # were settled at the submit (`inline`), the seconds this
             # node's thread blocked for the worker in the landings
             # (`join_wait`) and the checks' own (`verify`; `verify_late`
-            # those a late COMMIT asked for)
+            # those a late COMMIT asked for); `ppr_multi_sig`: the
+            # multi-signatures of the PRE-PREPAREs this node validated,
+            # `known` from memory or `paired` on (docs/performance.md)
             "bls": master.bls.stats if master.bls is not None else None,
             # when a message left and when a frame was seen: frames by
             # who flushed them, the holds in the outbox and in the
@@ -2650,8 +2655,10 @@ class Node:
             # master order, as the consensus.vc_* events), and what the
             # master's ordering service saw of the last one: batches
             # reverted at its start, finalised requests waiting at the
-            # new view's first fresh PRE-PREPARE; while one is in progress,
-            # what it waits on (ViewChangeService.progress)
+            # new view's first fresh PRE-PREPARE, and the steps from the
+            # NEW_VIEW accepted to the first fresh batch ordered, in ms on
+            # an unlatched clock (docs/consensus.md); while one is in
+            # progress, what it waits on (ViewChangeService.progress)
             "view_change": dict(
                 self._vc_counts, view_no=master.data.view_no,
                 in_progress=bool(master.data.waiting_for_new_view),

@@ -128,6 +128,13 @@ class Propagator:
         self.name = name
         self.quorums = quorums
         self.requests = Requests(now, stamp=self._stages.stamp)
+        # cumulative (VALIDATOR_INFO `propagation`): requests handed to
+        # the replicas' queues AFTER this node had executed them. Ordering
+        # needs a request's body, not its propagate quorum, so a node that
+        # lags its peers can order and commit a batch first and see the
+        # quorum later; what is forwarded then stays queued on every
+        # instance, and a node that becomes primary proposes it again
+        self.stats = {"forwarded_after_executed": 0}
         self._send = send_to_nodes
         self._forward = forward_to_replicas
         self._validators = validators or (lambda: [name])
@@ -258,4 +265,6 @@ class Propagator:
         self._stages.finalised(digest, state)
         if not state.forwarded:
             state.forwarded = True
+            if state.executed:
+                self.stats["forwarded_after_executed"] += 1
             self._forward(digest)

@@ -70,6 +70,9 @@ SNAPSHOT_SCHEMA: dict[str, frozenset] = {
         MetricsName.ORDERING_TIME,
         MetricsName.VC_DETECT_TO_VOTE, MetricsName.VC_VOTE_TO_START,
         MetricsName.VC_START_TO_NEW_VIEW, MetricsName.VC_NEW_VIEW_TO_ORDER,
+        MetricsName.VC_RECERTIFY, MetricsName.VC_FIRST_CUT,
+        MetricsName.VC_FIRST_ROUND, MetricsName.VC_FRESH_ORDER,
+        MetricsName.VC_BLS_JOIN_WAIT,
         MetricsName.REQUEST_QUEUE_DEPTH,
     }),
     "commit_path": frozenset({

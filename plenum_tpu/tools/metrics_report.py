@@ -593,6 +593,18 @@ def derive_summary(folds: dict[str, dict], span_s: float,
             f = folds.get(phase, {})
             if f.get("mean") is not None:
                 section[label] = round(f["mean"], 2)
+        # that last phase again, unlatched and closed on the first FRESH
+        # batch ordered (OrderingService.vc_episode): three steps that
+        # add up to the fourth, and the BLS landings' wait inside them
+        for step, label in (
+                ("consensus.vc_recertify", "recertify_ms"),
+                ("consensus.vc_first_cut", "first_cut_ms"),
+                ("consensus.vc_first_round", "first_round_ms"),
+                ("consensus.vc_fresh_order", "fresh_order_ms"),
+                ("consensus.vc_bls_join_wait", "bls_join_wait_ms")):
+            f = folds.get(step, {})
+            if f.get("mean") is not None:
+                section[label] = round(f["mean"] * 1e3, 1)
         out["view_change"] = section
     # catchup robustness: durations/rounds p50/p95 plus the watchdog's
     # provider switches and kicks, and the terminal degraded flag

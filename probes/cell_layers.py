@@ -31,14 +31,29 @@ nodes share a process and on a checkout older than PR 40) and
 `probe_round` (the window's batch cuts on the first validator, from
 VALIDATOR_INFO `batch_controller.cuts`: cuts a second and the ms one 3PC
 round takes while the pool orders one batch at a time) and `probe_bls`
-(the first validator's VALIDATOR_INFO `bls` over the window: order-time
+(EVERY validator's VALIDATOR_INFO `bls` over the window, by name: the
+primary validates none of its own PRE-PREPAREs, so the first alone says
+nothing of `ppr_multi_sig`; a validator that is down at either end of the
+window reads null. Order-time
 checks of COMMIT signatures that went to the BLS library's worker thread,
 `offloaded`, or were settled at the submit, `inline`; `join_wait`, what
 the node's loop blocked for the worker in a landing, `verify`, the
 checks' own duration, and `verify_late`, those of them a late COMMIT asked
 for, each with count and mean; `hidden_share` = 1 - the
 summed join wait over the summed check time; null on a checkout older
-than PR 48). With `--trace 1`
+than PR 48; since PR 49 `ppr_multi_sig`: of the multi-signatures the
+PRE-PREPAREs it validated carried, those it `known` and those it `paired`
+on, `paired_share`, the pairings' mean ms and the ms it first blocked
+landing its own check, `joined_ms`) and, in the failover cell,
+`probe_failover_steps` (each survivor's `view_change.ordering` episode as
+the steps between NEW_VIEW accepted and the first fresh order, ms:
+`recertify` + `first_cut` + `first_round` = `fresh_order`, the kind of
+its first order, its cycles and BLS landings, and the medians over the
+survivors: what six per-layer metrics would read; null on a checkout
+older than PR 49) and `probe_propagation` (every validator's
+`propagation.forwarded_after_executed` over the window: requests it
+queued for ordering after it had executed them, which a node that becomes
+primary proposes again). With `--trace 1`
 it keeps the run's xplane under
 `chiprun_out/probe_trace/` (where it is under 24 MiB) and prints
 `probe_gaps`: each device idle gap of the sample with the host spans
@@ -107,31 +122,44 @@ def window_lanes(seen: list) -> dict | None:
             if total else None}      # a host plane dispatches no program
 
 
-def first_validator_info(topo) -> dict:
-    """The first validator's VALIDATOR_INFO as it stands now (of nodes in
-    this process: the parts of it the probe reads)."""
+def validator_infos(topo) -> dict:
+    """{name: VALIDATOR_INFO as it stands now} of every validator that
+    answers (of nodes in this process: the parts of it the probe reads),
+    the one the cell reads from first."""
     if hasattr(topo, "last_infos"):             # four owners: just fetched
-        return topo.last_infos[0]
+        return dict(zip(topo.names, topo.last_infos))
     pool = getattr(topo, "pool", None)
     if pool is not None:                        # nodes in this process
-        node = pool.nodes[topo.names[0]]
-        clock = getattr(node, "stages", None)
-        ctl = node.batch_controller
-        bls = getattr(node.master_replica, "bls", None)
-        return {"stages": clock.report() if clock is not None else None,
+        out = {}
+        for name in topo.names:
+            node = pool.nodes[name]
+            clock = getattr(node, "stages", None)
+            ctl = node.batch_controller
+            bls = getattr(node.master_replica, "bls", None)
+            out[name] = {
+                "stages": clock.report() if clock is not None else None,
                 "batch_controller": ctl.trajectory() if ctl is not None
                 else None,
-                # a snapshot: the record keeps counting
-                "bls": copy.deepcopy(getattr(bls, "stats", None))}
+                # snapshots: the records keep counting
+                "bls": copy.deepcopy(getattr(bls, "stats", None)),
+                "propagation": dict(node.propagator.stats)
+                if hasattr(node.propagator, "stats") else None}
+        return out
     from benchmarks.tcp_client import ask
     from plenum_tpu.execution.action_manager import VALIDATOR_INFO_ACTION
     # the failover cell kills the first validator: it reads from the
     # first survivor, and so does the probe
-    name = getattr(topo, "reads_from", None) or topo.names[0]
-    msg = topo.loop.run_until_complete(ask(
-        topo.addrs[name],
-        topo._trustee_request({"type": VALIDATOR_INFO_ACTION})))
-    return (msg.get("result") or {}).get("data") or {}
+    first = getattr(topo, "reads_from", None) or topo.names[0]
+    out = {}
+    for name in [first] + [n for n in topo.names if n != first]:
+        try:
+            msg = topo.loop.run_until_complete(ask(
+                topo.addrs[name],
+                topo._trustee_request({"type": VALIDATOR_INFO_ACTION})))
+        except (OSError, EOFError):
+            continue                # down: a cell's victim
+        out[name] = (msg.get("result") or {}).get("data") or {}
+    return out
 
 
 def service_waits(topo) -> dict | None:
@@ -230,7 +258,72 @@ def window_bls(seen: list) -> dict | None:
                 offloaded=end["offloaded"] - start["offloaded"],
                 inline=end["inline"] - start["inline"],
                 hidden_share=round(1 - waited / checked, 4)
-                if checked else None)
+                if checked else None,
+                ppr_multi_sig=window_ppr_multi_sig(seen))
+
+
+def window_ppr_multi_sig(seen: list) -> dict | None:
+    """What became of the multi-signatures the PRE-PREPAREs this validator
+    validated over the window carried (`bls.ppr_multi_sig`, PR 49)."""
+    start, end = (r.get("ppr_multi_sig") for r in seen)
+    if not start or not end:
+        return None
+    grew = {k: end[k] - start[k] for k in end}
+    carried = grew["known"] + grew["paired"]
+    return {"known": grew["known"], "paired": grew["paired"],
+            "paired_share": round(grew["paired"] / carried, 4)
+            if carried else None,
+            "paired_mean_ms": round(
+                grew["paired_s"] / grew["paired"] * 1e3, 4)
+            if grew["paired"] else None,
+            "joined_ms": round(grew["joined_s"] * 1e3, 3)}
+
+
+def window_propagation(seen: list) -> dict | None:
+    """Requests a validator queued for ordering over the window after it
+    had executed them (`propagation`, PR 49)."""
+    if len(seen) < 2 or not seen[0] or not seen[1]:
+        return None
+    return {k: seen[1][k] - seen[0][k] for k in seen[1]}
+
+
+def failover_steps(infos: dict) -> dict | None:
+    """{survivor: its episode as steps} and the medians over them, from
+    the survivors' VALIDATOR_INFO read after the window."""
+    from statistics import median
+    rows = {}
+    for name, info in infos.items():
+        vc = info.get("view_change") or {}
+        e = vc.get("ordering") or {}
+        if "fresh_ordered_ms" not in e:
+            return None             # a checkout older than PR 49
+        ends = [e["recertified_ms"], e["first_cut_apply_ms"],
+                e["fresh_ordered_ms"]]
+        whole = None not in ends
+        rows[name] = {
+            "recertify_ms": e["recertified_ms"],
+            "first_cut_ms": round(ends[1] - ends[0], 3) if whole else None,
+            "first_round_ms": round(ends[2] - ends[1], 3) if whole else None,
+            "fresh_order_ms": e["fresh_ordered_ms"],
+            "bls_join_wait_ms": e["bls"].get("join_wait_ms"),
+            "first_ordered_kind": e["first_ordered_kind"],
+            # since its start, read after the drain
+            "forwarded_after_executed": (info.get("propagation") or {}).get(
+                "forwarded_after_executed"),
+            # the one stamp pair of before, on the node's latched timer
+            "new_view_to_order_ms": round(((vc.get("last") or {}).get(
+                "phases_s") or {}).get("new_view_to_order", 0) * 1e3, 3),
+            "episode": e}
+    if not rows:
+        return None
+    medians = {}
+    for key in ("recertify_ms", "first_cut_ms", "first_round_ms",
+                "fresh_order_ms", "bls_join_wait_ms"):
+        got = [r[key] for r in rows.values() if r[key] is not None]
+        medians["failover." + key] = median(got) if got else None
+    medians["failover.first_order_fresh_share"] = 100.0 * sum(
+        r["first_ordered_kind"] == "fresh" for r in rows.values()) / len(rows)
+    return {"survivors": rows, "medians": medians}
 
 
 HOST_SPANS = ("prod.", "ring.", "svc.")
@@ -293,6 +386,7 @@ def keep_xplane(xplane: str, tag: str) -> dict:
 def as_cell() -> int:
     from benchmarks import cell, manifest, readers, trace_reduce
     runs, lanes_seen, windows, infos_seen, waits_seen = [], [], [], [], []
+    survivors_seen: list = []
     init, metrics, window = cell.Run.__init__, cell.metrics, cell.Run.window
     collect_trace = cell.Run.collect_trace
 
@@ -311,10 +405,16 @@ def as_cell() -> int:
         def snapshot_and_lanes():
             got = snapshot()
             lanes_seen.append(by_lanes(topo))
-            infos_seen.append(first_validator_info(topo))
+            infos_seen.append(validator_infos(topo))
             waits_seen.append(service_waits(topo))
             return got
         topo.snapshot = snapshot_and_lanes
+        survivors_of = getattr(topo, "_survivors_view_changes", None)
+        if survivors_of is not None:    # the failover cell, after its drain
+            def remember_survivors():
+                survivors_seen.append(survivors_of())
+                return survivors_seen[-1]
+            topo._survivors_view_changes = remember_survivors
 
     def remember_window(self, *args, **kwargs):
         # a window takes its snapshots itself, before and after the drive;
@@ -340,7 +440,10 @@ def as_cell() -> int:
                     manifest.metric_spec("per_layer", m["name"]), obs)
             except Exception as e:      # a reader that wants the trace
                 layers[m["name"]] = f"not read: {e!r}"
-        lanes, infos, waits = windows[0] if windows else ([], [], [])
+        lanes, every, waits = windows[0] if windows else ([], [], [])
+        # the validator the cell reads from, and all of them by name
+        infos = [next(iter(seen.values()), {}) for seen in every]
+        names = list(every[0]) if every else []
         cell.say(probe_per_layer=layers, probe_cuts=cut_counts(runs[0].topo),
                  probe_lanes=window_lanes(lanes),
                  probe_stages=window_stages(
@@ -349,7 +452,14 @@ def as_cell() -> int:
                  probe_transport=window_transport(
                      [info.get("transport") for info in infos]),
                  probe_round=window_round(infos, obs["numbers"]),
-                 probe_bls=window_bls([info.get("bls") for info in infos]),
+                 probe_bls={name: window_bls(
+                     [seen.get(name, {}).get("bls") for seen in every])
+                     for name in names},
+                 probe_failover_steps=failover_steps(survivors_seen[-1])
+                 if survivors_seen else None,
+                 probe_propagation={name: window_propagation(
+                     [seen.get(name, {}).get("propagation")
+                      for seen in every]) for name in names},
                  probe_service_waits=dict(
                      grown(waits) or {}, since_pin=waits[1]) if len(
                      waits) > 1 and waits[1] else None)

@@ -453,3 +453,92 @@ def test_late_commit_behind_a_check_in_flight_checks_only_its_own_signature():
     assert [r[1] for r in rows] == [("A", "B", "C")]
     replica.land_all()
     assert [r[1] for r in rows] == [("A", "B", "C"), ("A", "B", "C", "D")]
+
+
+# --- what became of a PRE-PREPARE's multi-signature, counted (PR 49) --------
+
+@pairing_heavy
+@pytest.mark.parametrize("carried, counted", [
+    (("A", "B", "C"), "known"),         # the aggregate this node made itself
+    (("B", "C", "D"), "paired"),        # another quorum's, over the same value
+    (("A", "B", "C", "D"), "paired")])
+def test_a_pre_prepares_multi_signature_is_counted_known_or_paired(
+        carried, counted):
+    """`stats["ppr_multi_sig"]`: a PRE-PREPARE that carries the multi-
+    signature this node aggregated is answered from memory; one that
+    carries another signer subset's goes to the verifier, and its seconds
+    are kept. A caller that is no PRE-PREPARE counts nowhere."""
+    import dataclasses
+    from plenum_tpu.common.node_messages import Commit, PrePrepare
+    from plenum_tpu.crypto.multi_signature import MultiSignature
+    bls_mod._BLS_VERDICTS.clear()
+    replica, signers = _order_time_replica([], [])
+    pp = PrePrepare(inst_id=0, view_no=0, pp_seq_no=1, pp_time=1.0,
+                    req_idr=(), discarded=(), digest="d", ledger_id=1,
+                    state_root="a-ppr-" + "".join(carried), txn_root="c-ppr",
+                    pool_state_root="b-ppr")
+    value = replica._signed_value(pp).as_single_value()
+    for n in "ABC":
+        replica.process_commit(Commit(inst_id=0, view_no=0, pp_seq_no=1,
+                                      bls_sig=signers[n].sign(value)), n)
+    own = replica.process_order((0, 1), pp)
+    assert own.participants == ("A", "B", "C")
+    ms = MultiSignature(
+        signature=BlsCryptoVerifier().create_multi_sig(
+            [signers[n].sign(value) for n in carried]),
+        participants=carried, value=replica._signed_value(pp))
+    assert (ms == own) == (counted == "known")
+    nxt = dataclasses.replace(pp, pp_seq_no=2, state_root="a-next",
+                              bls_multi_sig=tuple(ms.to_list()))
+    tally = replica.stats["ppr_multi_sig"]
+    assert tally == {"known": 0, "paired": 0, "paired_s": 0.0,
+                     "joined_s": 0.0}
+    assert replica.validate_pre_prepare(nxt, "B") is None
+    other = {"known": "paired", "paired": "known"}[counted]
+    assert (tally[counted], tally[other]) == (1, 0)
+    assert (tally["paired_s"] > 0) == (counted == "paired")
+    # the verdict is remembered: the same PRE-PREPARE again is `known`
+    assert replica.validate_pre_prepare(nxt, "B") is None
+    assert tally["known"] + tally["paired"] == 2
+    assert tally["paired"] == (1 if counted == "paired" else 0)
+    # a catch-up's adopted multi-signature passes the same check uncounted
+    assert replica.multi_sig_holds(ms)
+    assert tally["known"] + tally["paired"] == 2
+
+
+@pairing_heavy
+def test_a_pre_prepare_that_lands_this_nodes_own_check_counts_the_join():
+    """The PRE-PREPARE after a batch can arrive while this node's check of
+    that batch is still with the worker: `multi_sig_holds` lands it first
+    (`joined_s`), and finds the multi-signature it then knows."""
+    import dataclasses
+    from plenum_tpu.common.node_messages import Commit, PrePrepare
+    bls_mod._BLS_VERDICTS.clear()
+    replica, signers = _order_time_replica([], [])
+    pp = PrePrepare(inst_id=0, view_no=0, pp_seq_no=1, pp_time=1.0,
+                    req_idr=(), discarded=(), digest="d", ledger_id=1,
+                    state_root="a-join", txn_root="c-join",
+                    pool_state_root="b-join")
+    value = replica._signed_value(pp).as_single_value()
+    for n in "ABC":
+        replica.process_commit(Commit(inst_id=0, view_no=0, pp_seq_no=1,
+                                      bls_sig=signers[n].sign(value)), n)
+    twin, _ = _order_time_replica([], [])
+    twin._sigs = {k: dict(v) for k, v in replica._sigs.items()}
+    ms = twin.process_order((0, 1), pp)     # what the primary will send
+    bls_mod._BLS_VERDICTS.clear()
+    replica.submit_order((0, 1), pp)
+    assert replica.depth == 1
+    nxt = dataclasses.replace(pp, pp_seq_no=2, state_root="a-join-next",
+                              bls_multi_sig=tuple(ms.to_list()))
+    assert replica.validate_pre_prepare(nxt, "B") is None
+    tally = replica.stats["ppr_multi_sig"]
+    assert replica.depth == 0 and tally["joined_s"] > 0
+    assert (tally["known"], tally["paired"]) == (1, 0)
+    assert replica.tally() == {
+        "submitted": 1, "offloaded": replica.stats["offloaded"],
+        "inline": replica.stats["inline"], "landings": 1,
+        "join_wait_ms": replica.stats["join_wait"]["sum_s"] * 1e3,
+        "verify_ms": replica.stats["verify"]["sum_s"] * 1e3,
+        "ppr_known": 1, "ppr_paired": 0, "ppr_paired_ms": 0.0,
+        "ppr_joined_ms": tally["joined_s"] * 1e3}

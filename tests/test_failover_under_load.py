@@ -15,6 +15,7 @@ import sys
 
 import pytest
 
+from plenum_tpu.common.internal_messages import NewViewAccepted
 from plenum_tpu.common.node_messages import (Commit, DOMAIN_LEDGER_ID,
                                               Ordered)
 from plenum_tpu.config import Config
@@ -428,6 +429,17 @@ def recertified(tmp_path_factory):
              key=lambda ms: ms.value.state_root_hash)
         _spy(node.master_replica.bls, "submit_order", aggregated[n],
              key=lambda key, pp: key)
+    # the master's bus on each survivor: the NEW_VIEW accepted and every
+    # batch ordered after it, each with the node timer's reading and the
+    # BLS replica's counters as they were at that send
+    bus_seen = {n: [] for n in survivors}
+    for n in survivors:
+        replica = pool.nodes[n].master_replica
+        for kind in (NewViewAccepted, Ordered):
+            replica.internal_bus.subscribe(
+                kind, lambda m, n=n, replica=replica: bus_seen[n].append(
+                    (m, pool.nodes[n].timer.get_current_time(),
+                     replica.bls.tally())), first=True)
     roots = {pp.pp_seq_no: pp.state_root for pp in pool.nodes[
         "Beta"].master_replica.ordering.prePrepares.values()}
 
@@ -454,6 +466,9 @@ def recertified(tmp_path_factory):
     seen["fresh"] = [m for m, frm in wire if isinstance(m, PrePrepare)
                      and m.inst_id == 0 and m.view_no == 1
                      and m.pp_seq_no == 7]
+    seen["bus"] = {n: list(v) for n, v in bus_seen.items()}
+    seen["view_change_closed"] = {
+        n: pool.nodes[n].validator_info()["view_change"] for n in survivors}
     seen["kept_before_gc"] = {
         n: len(pool.nodes[n].master_replica.bls._own_sigs)
         for n in survivors}
@@ -516,6 +531,92 @@ def test_the_episode_counts_the_bls_work_of_the_re_certified_batches(
         assert e["bls_sigs_reused"] + e["bls_sigs_fresh"] \
             == e["reordered_batches"] == 6, (n, e)
         assert (e["bls_sigs_reused"], e["bls_sigs_fresh"]) == (6, 0)
+
+
+# --- the last phase, step by step and by BLS landing (PR 49) ----------------
+
+def _steps_in_order(e: dict) -> None:
+    from plenum_tpu.consensus.ordering_service import VC_STEPS
+    assert all(e[k] is not None and e[k] >= 0 for k in VC_STEPS), e
+    assert e["recertified_ms"] <= e["first_cut_ms"] \
+        <= e["first_cut_apply_ms"] <= e["fresh_ordered_ms"], e
+    assert e["first_prepared_ms"] <= e["first_ordered_ms"] \
+        <= e["fresh_ordered_ms"], e
+    assert e["cycles"] >= 1
+    assert 0 < e["longest_cycle_ms"] <= e["fresh_ordered_ms"], e
+    if e["first_ordered_kind"] == "fresh":
+        assert e["fresh_ordered_ms"] == e["first_ordered_ms"]
+        assert e["first_cut_apply_ms"] <= e["first_prepared_ms"], e
+
+
+@pytest.mark.parametrize("name, kind, first_seq, cited", [
+    # had ordered all six: nothing cited is ordered again, the first
+    # `Ordered` of view 1 is the fresh batch
+    ("Beta", "fresh", 7, {"cited_reapplied": 0, "cited_ordered": 0,
+                          "cited_recertified_only": 6}),
+    ("Gamma", "fresh", 7, {"cited_reapplied": 0, "cited_ordered": 0,
+                           "cited_recertified_only": 6}),
+    # had prepared batches 5 and 6 and not ordered them: they are
+    # applied again and ordered first, the fresh batch after them
+    ("Delta", "recertified", 5, {"cited_reapplied": 2, "cited_ordered": 2,
+                                 "cited_recertified_only": 4})])
+def test_the_episode_splits_new_view_to_first_order_by_step(
+        recertified, name, kind, first_seq, cited):
+    pool, survivors, seen = recertified
+    vc = seen["view_change_closed"][name]
+    e = vc["ordering"]
+    _steps_in_order(e)
+    assert (e["first_ordered_kind"], e["first_ordered_pp_seq_no"],
+            e["first_ordered_requests"]) == (kind, first_seq, 1)
+    assert {k: e[k] for k in cited} == cited
+    assert e["cited_reapplied"] + e["cited_recertified_only"] \
+        == e["reordered_batches"]
+    # the one stamp pair of before is what it was: NEW_VIEW accepted ->
+    # the first master `Ordered` of EITHER kind, on the node's timer
+    accepted, *ordered = seen["bus"][name]
+    assert isinstance(accepted[0], NewViewAccepted)
+    assert [m.pp_seq_no for m, _t, _b in ordered][0] == first_seq
+    assert vc["last"]["phases_s"]["new_view_to_order"] == pytest.approx(
+        ordered[0][1] - accepted[1], abs=1e-9)
+    # the BLS replica inside the phase: its own counters' growth from the
+    # NEW_VIEW accepted to the first FRESH batch ordered
+    fresh = next(b for m, _t, b in ordered if m.original_view_no is None)
+    bls = e["bls"]
+    assert {k: bls[k] for k in fresh} == {
+        k: pytest.approx(fresh[k] - accepted[2][k], abs=1e-3)
+        for k in fresh}
+    assert bls["submitted"] == bls["offloaded"] + bls["inline"] \
+        == len([m for m, _t, _b in ordered if m.pp_seq_no <= 7])
+    assert bls["depth_at_new_view"] == 0 and bls["start_join_ms"] >= 0
+    # and the phase's spans are on the metrics store, once each
+    folds = pool.nodes[name].metrics.summary()
+    for metric in ("recertify", "first_cut", "first_round", "fresh_order",
+                   "bls_join_wait"):
+        assert folds[f"consensus.vc_{metric}"]["count"] == 1, metric
+    assert folds["consensus.vc_fresh_order"]["sum"] == pytest.approx(
+        e["fresh_ordered_ms"] / 1e3)
+    assert sum(folds[f"consensus.vc_{m}"]["sum"] for m in (
+        "recertify", "first_cut", "first_round")) == pytest.approx(
+        e["fresh_ordered_ms"] / 1e3)
+
+
+def test_with_batches_in_flight_every_survivor_orders_a_cited_one_first(
+        episode):
+    """The backlog scenario: DEPTH batches were prepared and ordered
+    nowhere, so on every survivor the stamp pair of before closes on a
+    re-certified batch and the first fresh one, which takes the backlog,
+    is ordered later."""
+    pool, victim, survivors, at_stop = episode
+    for n in survivors:
+        e = pool.nodes[n].validator_info()["view_change"]["ordering"]
+        _steps_in_order(e)
+        assert e["first_ordered_kind"] == "recertified", (n, e)
+        assert e["first_ordered_requests"] == BATCH
+        assert e["cited_reapplied"] == e["cited_ordered"] == DEPTH
+        assert e["cited_reapplied"] + e["cited_recertified_only"] \
+            == e["reordered_batches"]
+        assert e["first_ordered_ms"] < e["fresh_ordered_ms"], (n, e)
+        assert e["bls"]["submitted"] == DEPTH + 1
 
 
 def test_the_first_fresh_pre_prepare_carries_the_multi_sig_before_it(
@@ -657,3 +758,37 @@ def test_a_view_changes_start_is_on_disk_when_the_view_change_starts(
     assert len(dumps) == 1
     events = json.loads(dumps[0].read_text())["events"]
     assert [e[1] for e in events].count("anomaly.view_change_start") == 1
+
+
+def test_a_request_finalised_after_it_was_executed_is_counted_and_queued():
+    """What separates the slow failover runs from the fast ones on the chip
+    (PERF.md section 6, PR 49): ordering needs a request's BODY, not its
+    propagate quorum. A node whose peers' PROPAGATEs reach it late orders
+    and commits the batch on the body it has, sees the quorum afterwards
+    and queues the request for ordering all the same. `propagation.
+    forwarded_after_executed` counts exactly what then sits in its queues
+    for a node that becomes primary to propose again."""
+    from plenum_tpu.common.node_messages import Propagate, PropagateBatch
+    from plenum_tpu.network import Stash
+    pool = Pool(tracing=False)
+    late = pool.net.add_rule(Stash(), lambda m, frm, dst: dst == "Delta"
+                             and isinstance(m, (Propagate, PropagateBatch)))
+    for i in range(3):
+        pool.submit(signed_nym(pool.trustee, _user(9300 + i), i + 1))
+    pool.run(3.0)
+    delta = pool.nodes["Delta"]
+    assert _domain(delta).size == 4         # ordered on the bodies it holds
+    assert delta.validator_info()["propagation"] == {
+        "forwarded_after_executed": 0}
+    pool.net.remove_rule(late)              # the quorum, after the commit
+    pool.run(1.0)
+    queued = [d for q in delta.master_replica.ordering.request_queues
+              .values() for d in q]
+    assert 1 <= len(queued) <= 3
+    assert all(delta.propagator.requests[d].executed for d in queued)
+    assert delta.validator_info()["propagation"] == {
+        "forwarded_after_executed": len(queued)}
+    for n in ("Alpha", "Beta", "Gamma"):    # on time: nothing left queued
+        assert pool.nodes[n].propagator.stats["forwarded_after_executed"] == 0
+        assert not any(pool.nodes[n].master_replica.ordering
+                       .request_queues.values())

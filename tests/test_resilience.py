@@ -414,6 +414,12 @@ def test_metrics_report_churn_sections():
     folds = {
         "view_change.duration": fold(3, 6.0, samples=[1.0, 2.0, 3.0]),
         "consensus.vc_detect_to_vote": fold(3, 1.5),
+        # the last phase's steps (PR 49), seconds on the store
+        "consensus.vc_recertify": fold(3, 0.03),
+        "consensus.vc_first_cut": fold(3, 0.33),
+        "consensus.vc_first_round": fold(3, 0.24),
+        "consensus.vc_fresh_order": fold(3, 0.6),
+        "consensus.vc_bls_join_wait": fold(3, 0.0063),
         "catchup.duration": fold(2, 9.0, samples=[4.0, 5.0]),
         "catchup.rounds": fold(2, 7.0, samples=[3.0, 4.0]),
         "catchup.provider_switches": fold(1, 2.0),
@@ -428,6 +434,9 @@ def test_metrics_report_churn_sections():
     assert vc["episodes"] == 3
     assert vc["duration_s_p50"] == 2.0 and vc["duration_s_p95"] == 3.0
     assert vc["detect_to_vote_s"] == 0.5
+    assert (vc["recertify_ms"], vc["first_cut_ms"], vc["first_round_ms"],
+            vc["fresh_order_ms"], vc["bls_join_wait_ms"]) \
+        == (10.0, 110.0, 80.0, 200.0, 2.1)
     cu = out["catchup"]
     assert cu["completed"] == 2 and cu["duration_s_p95"] == 5.0
     assert cu["provider_switches"] == 2 and cu["watchdog_kicks"] == 4
